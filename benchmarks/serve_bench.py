@@ -1270,6 +1270,9 @@ def main() -> int:
 
     from kubeflow_rm_tpu.controlplane.obs.runmeta import build_run_meta
     interleave = os.environ.get("KFRM_RUN_INTERLEAVE")
+    # every campaign's line names the device it ran on: a cpu row is a
+    # rehearsal, never a chip result
+    out.setdefault("device", _device_tag())
     out["run_meta"] = build_run_meta(
         "serve_bench",
         {
@@ -1307,4 +1310,6 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from kubeflow_rm_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -157,8 +157,10 @@ def main(argv=None) -> int:
                          batch_keys=batch_keys, callbacks=callbacks)
     if history:
         last = history[-1]
+        mfu = ("n/a (no TPU)" if last.mfu_pct is None
+               else f"{last.mfu_pct:.1f}%")
         print(f"final: step {last.step} loss {last.loss:.4f} "
-              f"{last.tokens_per_sec:.0f} tok/s mfu {last.mfu_pct:.1f}%")
+              f"{last.tokens_per_sec:.0f} tok/s mfu {mfu}")
 
     # 5. sample — decode applies adapters and int8 bases directly
     if args.sample and env.process_id == 0:
@@ -176,4 +178,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from kubeflow_rm_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

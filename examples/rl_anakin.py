@@ -242,4 +242,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from kubeflow_rm_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sys.exit(main())

@@ -26,7 +26,7 @@ step costs milliseconds on a laptop) and walks the jaxpr:
 
 Donation is read from two places: the ``donate_argnums`` /
 ``donate_argnames`` the caller passes here, and the
-``donated_invars`` recorded on every ``pjit`` equation (so estimating
+``donated_invars`` recorded on every ``jit`` equation (so estimating
 an already-jitted function honors the donation baked into it).
 
 Known approximations, all conservative (over-estimating peaks):
@@ -47,7 +47,8 @@ from dataclasses import dataclass, field
 import numpy as np
 
 import jax
-from jax import core as jax_core
+from jax.core import DropVar
+from jax.extend import core as jax_core
 
 
 # primitives that are pure data movement / bookkeeping: bytes, no flops
@@ -56,7 +57,7 @@ _MOVEMENT = frozenset({
     "slice", "dynamic_slice", "dynamic_update_slice", "concatenate",
     "pad", "gather", "scatter", "scatter-add", "iota", "copy",
     "convert_element_type", "bitcast_convert_type", "device_put",
-    "stop_gradient", "split", "expand_dims", "real", "imag",
+    "stop_gradient", "split", "expand_dims", "real", "imag", "reshard",
     "name",  # ad_checkpoint.checkpoint_name's identity marker
     "sharding_constraint", "optimization_barrier", "select_and_scatter_add",
 })
@@ -175,7 +176,7 @@ def _child_jaxprs(eqn):
     call-like equation; empty for leaf primitives."""
     name = eqn.primitive.name
     p = eqn.params
-    if name == "pjit":
+    if name == "jit":
         return [(p["jaxpr"], 1, p.get("donated_invars"))]
     if name == "scan":
         return [(p["jaxpr"], int(p.get("length", 1)), None)]
@@ -272,7 +273,7 @@ def _walk(closed: jax_core.ClosedJaxpr, donated, honor: bool,
                         donated_in += live[v]
                         freeable.add(v)
         out_bytes = sum(_aval_bytes(v.aval) for v in eqn.outvars
-                        if not isinstance(v, jax_core.DropVar))
+                        if not isinstance(v, DropVar))
         eqn_in_bytes = sum(
             _aval_bytes(v.aval) for v in set(
                 v for v in eqn.invars if isinstance(v, jax_core.Var)))
@@ -296,7 +297,7 @@ def _walk(closed: jax_core.ClosedJaxpr, donated, honor: bool,
         peak = max(peak, sum(live.values()) + out_extra + max(0, scratch))
 
         for v in eqn.outvars:
-            if isinstance(v, jax_core.DropVar):
+            if isinstance(v, DropVar):
                 continue
             live[v] = _aval_bytes(v.aval)
             freeable.add(v)  # temps are always reclaimable
@@ -313,7 +314,7 @@ def _walk(closed: jax_core.ClosedJaxpr, donated, honor: bool,
 def estimate_jaxpr(closed: jax_core.ClosedJaxpr,
                    donated_invars=None) -> CostEstimate:
     """Cost a ClosedJaxpr directly. ``donated_invars`` is a bool per
-    (flattened) invar; ``pjit`` sub-calls additionally contribute the
+    (flattened) invar; ``jit`` sub-calls additionally contribute the
     donation baked into them."""
     acc = _Walk()
     peak, in_b, out_b = _walk(closed, donated_invars, True, acc)
@@ -328,7 +329,7 @@ def estimate_jaxpr(closed: jax_core.ClosedJaxpr,
 
 
 _KNOWN = (_MOVEMENT | _ELEMENTWISE | _TWO_FLOP | _REDUCTION
-          | {"dot_general", "conv_general_dilated", "pjit", "scan",
+          | {"dot_general", "conv_general_dilated", "jit", "scan",
              "while", "cond", "remat2", "checkpoint", "custom_jvp_call",
              "custom_vjp_call", "custom_vjp_call_jaxpr", "closed_call",
              "core_call", "xla_call", "random_seed", "random_wrap",

@@ -42,6 +42,7 @@ API: ``POST /generate {"prompt": [ids...], "tenant"?: "name",
 from __future__ import annotations
 
 import json
+import logging
 import threading
 import time
 from dataclasses import dataclass
@@ -50,6 +51,8 @@ from kubeflow_rm_tpu.controlplane import metrics as cp_metrics
 from kubeflow_rm_tpu.controlplane import tracing
 from kubeflow_rm_tpu.controlplane.deploy.kubeclient import TokenBucket
 from kubeflow_rm_tpu.analysis.lockgraph import make_lock
+
+log = logging.getLogger(__name__)
 
 
 @dataclass(frozen=True)
@@ -78,12 +81,19 @@ class ReplicaUnavailable(Exception):
         self.tokens_so_far = list(tokens_so_far or [])
 
 
+class EngineFailed(RuntimeError):
+    """``engine.step()`` raised (compile error, device OOM, a bug): the
+    replica is out of service and every request it held fails with this
+    error, chained to the cause. Not retried elsewhere — a program that
+    does not compile here does not compile on the next replica."""
+
+
 class _Pending:
     """A request in flight: the HTTP thread parks on ``event`` while
     the drain thread decodes."""
 
     __slots__ = ("req", "tenant", "event", "t_submit", "t_done",
-                 "trace", "t_submit_epoch", "failed")
+                 "trace", "t_submit_epoch", "failed", "error")
 
     def __init__(self, req, tenant, trace=None):
         self.req = req
@@ -95,6 +105,9 @@ class _Pending:
         # before the engine finishes it — wait() then raises
         # ReplicaUnavailable instead of returning a torn result
         self.failed = False
+        # set when the engine itself failed under this request —
+        # wait() then raises EngineFailed from it
+        self.error = None
         # traceparent of the admitting request, if it carried one —
         # the drain thread stamps the decode span against it; epoch
         # twin of t_submit because spans use wall time
@@ -135,6 +148,9 @@ class ServingGateway:
         self._ema_ms: float | None = None
         self.shed_counts: dict[str, int] = {}
         self.draining = False
+        # the exception that took the engine down, if one did: the
+        # replica then sheds with reason "failed" and healthz says so
+        self.error: Exception | None = None
         self._stop = threading.Event()
         cp_metrics.SERVING_SLOT_CAPACITY.set(engine.slots)
         self._thread = threading.Thread(target=self._drain, daemon=True)
@@ -161,6 +177,13 @@ class ServingGateway:
         cp_metrics.SERVING_REQUESTS_TOTAL.labels(tenant, "shed").inc()
         self.shed_counts[reason] = self.shed_counts.get(reason, 0) + 1
 
+    def _shed_closed(self, tenant: str, sp) -> tuple[None, str]:
+        """Shed on a replica that is out of rotation, saying why."""
+        reason = "failed" if self.error else "draining"
+        self._shed(tenant, reason)
+        sp.set_attr("shed", reason)
+        return None, reason
+
     def try_submit(self, tenant: str, prompt: list[int], *,
                    max_new_tokens: int, eos_id: int | None = None,
                    slo_class: str | None = None,
@@ -168,7 +191,7 @@ class ServingGateway:
                    ) -> tuple[_Pending | None, str | None]:
         """Admit or shed. Returns (pending, None) on admit,
         (None, reason) on shed — reason in
-        rate|tokens|queue|slo|draining. ``slo_class`` overrides the
+        rate|tokens|queue|slo|draining|failed. ``slo_class`` overrides the
         tenant policy's default engine queue. ``chain`` is an exported
         prefix chain (from a prefill replica / the global store): the
         engine seats it directly via ``install_chain`` and skips
@@ -179,9 +202,7 @@ class ServingGateway:
         with tracing.start_span_if_active(
                 "serving.admit", attrs={"tenant": tenant}) as sp:
             if self.draining:
-                self._shed(tenant, "draining")
-                sp.set_attr("shed", "draining")
-                return None, "draining"
+                return self._shed_closed(tenant, sp)
             if self.admission:
                 rate, budget = self._buckets(tenant)
                 if not rate.try_acquire(1.0):
@@ -198,9 +219,7 @@ class ServingGateway:
                 # request enqueue onto a stopping replica (it would
                 # never be drained OR failed — a silent hang)
                 if self.draining:
-                    self._shed(tenant, "draining")
-                    sp.set_attr("shed", "draining")
-                    return None, "draining"
+                    return self._shed_closed(tenant, sp)
                 depth = self.engine.queue_depth
                 if depth >= self.max_queue:
                     self._shed(tenant, "queue")
@@ -270,6 +289,10 @@ class ServingGateway:
              ) -> list[int]:
         if not pending.event.wait(timeout_s):
             raise TimeoutError("generation timed out")
+        if pending.error is not None:
+            raise EngineFailed(
+                f"engine step failed: {pending.error!r}"
+            ) from pending.error
         if pending.failed and not pending.req.done:
             raise ReplicaUnavailable(
                 "replica gave up this request mid-flight "
@@ -291,7 +314,23 @@ class ServingGateway:
             with self._lock:
                 busy = (self.engine.queue_depth
                         or self.engine.active_slots)
-                finished = self.engine.step() if busy else []
+                try:
+                    finished = self.engine.step() if busy else []
+                except Exception as e:
+                    # the engine's state is unknown past this point:
+                    # take the replica out of service and wake every
+                    # waiter with the cause, instead of dying silently
+                    # and leaving them to their timeouts
+                    log.exception("engine step failed; replica out "
+                                  "of service")
+                    self.error = e
+                    self.draining = True
+                    orphans, self._pending = self._pending, []
+                    for p in orphans:
+                        p.error = e
+                        p.t_done = time.monotonic()
+                        p.event.set()
+                    return
                 if busy:
                     stats = self.engine.stats()
                     cp_metrics.SERVING_QUEUE_DEPTH.set(
@@ -407,6 +446,7 @@ class ServingGateway:
         return {
             "admission": self.admission,
             "draining": self.draining,
+            "error": repr(self.error) if self.error else None,
             "paged": stats.get("paged", False),
             "queue_depth_by_class": stats.get("queue_depth_by_class"),
             "prefix_hit_ratio": stats.get("prefix_hit_ratio"),
@@ -471,7 +511,8 @@ def make_serving_app(gateway: ServingGateway, cfg):
                 # its queue is severed, so routers/LBs stop sending new
                 # work while in-flight requests still finish here
                 if gateway.draining:
-                    return _json({"ok": False, "state": "draining"},
+                    state = "failed" if gateway.error else "draining"
+                    return _json({"ok": False, "state": state},
                                  status=503)(environ, start_response)
                 return _json({"ok": True, "state": "ready"})(
                     environ, start_response)

@@ -131,6 +131,11 @@ class AdmissionPricer:
         obj = fast_deepcopy(obj)
         try:
             self._price(op, obj, old, declared, topo)
+        except (AttributeError, ImportError):
+            # a broken pricer (a jax name that moved, a missing module)
+            # is our bug, not the tenant's declaration: degrading here
+            # would switch HBM admission off without a word
+            raise
         except Exception as e:
             # satellite bugfix contract: an unparseable (or untraceable)
             # declaration degrades to chip-count-only admission —

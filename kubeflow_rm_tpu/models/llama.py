@@ -280,6 +280,7 @@ def _attention_half(cfg: LlamaConfig, x, layer, cos, sin, positions,
             q, k, v, causal=True, positions_q=positions,
             positions_kv=positions,
             segment_ids_q=segments, segment_ids_kv=segments,
+            mesh=mesh,
         )
     attn = checkpoint_name(attn, "attn_out")
     return x + proj("wo", attn.reshape(B, T, H * hd))

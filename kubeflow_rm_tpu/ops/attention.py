@@ -17,10 +17,42 @@ contracts over the shared kv head axis so K/V stay at their true size in
 HBM.
 """
 
+import logging
+
 import jax
 import jax.numpy as jnp
 
+log = logging.getLogger(__name__)
+
 NEG_INF = -2.0**30  # large-but-finite: keeps fp32 softmax NaN-free on fully masked rows
+
+
+def computation_devices(x: jax.Array, mesh=None) -> tuple[str, int]:
+    """(platform, device count) of the computation ``x`` belongs to.
+
+    The caller's ``mesh`` decides when there is one. Otherwise the
+    operand's own type does: an array laid out over a mesh — concrete
+    or a tracer under ``jit`` — carries that mesh, a one-device array
+    carries none. A one-device tracer names no device at all, so its
+    platform is the default backend, which is where ``jit`` places an
+    uncommitted computation. Never ``jax.device_count()``: a host that
+    shows four chips still runs one-device programs.
+    """
+    if mesh is not None:
+        return mesh.devices.flat[0].platform, mesh.devices.size
+    laid_out = jax.typeof(x).sharding.mesh
+    n = 1 if laid_out.empty else laid_out.size
+    if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+        return next(iter(x.devices())).platform, n
+    return jax.default_backend(), n
+
+
+def _log_choice(kernel: str, interpret: bool, platform: str,
+                n_devices: int, why: str) -> None:
+    """Say which kernel a flash-eligible call got. Under ``jit`` this
+    runs while tracing, so a compiled program says it once."""
+    log.info("attention kernel=%s interpret=%s platform=%s devices=%d "
+             "(%s)", kernel, interpret, platform, n_devices, why)
 
 
 def attention_mask(
@@ -85,6 +117,7 @@ def dot_product_attention(
     segment_ids_kv: jax.Array | None = None,
     bias: jax.Array | None = None,
     impl: str = "auto",
+    mesh=None,
 ) -> jax.Array:
     """Scaled dot-product attention.
 
@@ -99,9 +132,13 @@ def dot_product_attention(
         packed sequences; attention is restricted to equal segments.
       bias: optional additive bias broadcastable to (B, H, Tq, Tk).
 
-      impl: "auto" (flash on TPU when exactly representable, else XLA),
-        "flash" (force the pallas kernel; interpreter off-TPU), or
-        "xla" (always the materialized-scores path).
+      impl: "auto" (the pallas flash kernel when the computation is a
+        one-device TPU program and the call is exactly representable,
+        else XLA), "flash" (force the pallas kernel; interpreter
+        off-TPU), or "xla" (always the materialized-scores path).
+      mesh: the mesh the enclosing computation is sharded over, when
+        the caller has one; "auto" reads the target devices from it
+        (see ``computation_devices``).
 
     Returns:
       (B, Tq, H, D) in q.dtype.
@@ -113,26 +150,30 @@ def dot_product_attention(
             "impl='flash' cannot represent an additive bias or explicit "
             "positions; use impl='xla' (packed sequences need only "
             "segment ids — see ops/flash_attention.py)")
-    use_flash = (
-        impl == "flash"
-        or (impl == "auto"
-            and jax.default_backend() == "tpu"
-            # single-device only: pallas_call has no GSPMD partitioning
-            # rule, so under a multi-chip jit the compiler would
-            # all-gather the FULL global q/k/v onto every device —
-            # silently defeating dp/fsdp/sp sharding. Multi-chip meshes
-            # keep the einsum path (partitions cleanly) or use the ring
-            # schedules; shard_map-wrapping the kernel is the follow-up
-            # that lifts this gate.
-            and jax.device_count() == 1
-            and flash_eligible(q, k, causal=causal,
-                               positions_q=positions_q, bias=bias))
-    )
+    platform, n_devices = computation_devices(q, mesh)
+    use_flash = impl == "flash"
+    if impl == "auto" and flash_eligible(
+            q, k, causal=causal, positions_q=positions_q, bias=bias):
+        # one device only: pallas_call has no GSPMD partitioning rule,
+        # so under a multi-chip jit the compiler would all-gather the
+        # FULL global q/k/v onto every device — silently defeating
+        # dp/fsdp/sp sharding. Multi-chip meshes keep the einsum path
+        # (partitions cleanly) or use the ring schedules;
+        # shard_map-wrapping the kernel is the follow-up that lifts
+        # this gate.
+        use_flash = platform == "tpu" and n_devices == 1
+        if not use_flash:
+            _log_choice("xla", False, platform, n_devices,
+                        "flash-eligible, but not a one-device tpu "
+                        "program")
     if use_flash:
         from kubeflow_rm_tpu.ops.flash_attention import flash_attention
+        interpret = platform != "tpu"
+        _log_choice("flash", interpret, platform, n_devices, f"impl={impl}")
         return flash_attention(
             q, k, v, causal=causal,
-            segment_ids_q=segment_ids_q, segment_ids_kv=segment_ids_kv)
+            segment_ids_q=segment_ids_q, segment_ids_kv=segment_ids_kv,
+            interpret=interpret)
 
     B, Tq, H, D = q.shape
     _, Tk, KVH, _ = k.shape

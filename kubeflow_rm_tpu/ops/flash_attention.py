@@ -597,7 +597,9 @@ def flash_attention(
 
     Causality is over local indices; combined with segment ids this is
     exact for packed documents (module docstring). ``interpret=None``
-    auto-selects the pallas interpreter off-TPU so tests run on CPU.
+    selects the pallas interpreter when the operands' computation is
+    not for a TPU (``ops.attention.computation_devices``), so tests run
+    on CPU; the interpreter is refused for a TPU computation.
     """
     B, T, H, D = q.shape
     KVH = k.shape[2]
@@ -608,8 +610,14 @@ def flash_attention(
     if not block_q or not block_k:
         raise ValueError(
             f"T={T} has no 128-multiple block divisor; use the XLA path")
+    from kubeflow_rm_tpu.ops.attention import computation_devices
+    on_tpu = computation_devices(q)[0] == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu
+    elif interpret and on_tpu:
+        raise ValueError(
+            "interpret=True on a TPU computation: the pallas interpreter "
+            "is the CPU test path, the chip runs the compiled kernel")
 
     qh = jnp.swapaxes(q, 1, 2)   # (B, H, T, D)
     kh = jnp.swapaxes(k, 1, 2)

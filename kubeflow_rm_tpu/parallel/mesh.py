@@ -32,54 +32,7 @@ and the controller renders the slice topology into the pod.
 from dataclasses import dataclass
 
 import jax
-
-try:  # jax >= 0.5: sharding-in-types axis kinds
-    from jax.sharding import AxisType, Mesh
-except ImportError:  # older jax: every axis is implicitly Auto
-    from jax.sharding import Mesh
-    AxisType = None
-
-if not hasattr(jax, "shard_map"):  # older jax: pre-promotion spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs,
-                          axis_names=None, **kw):
-        # new-API ``axis_names={...}`` (manual axes) maps to the old
-        # ``auto=`` complement; partial-auto needs check_rep off there
-        if axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-            if auto:
-                kw.setdefault("auto", auto)
-                kw.setdefault("check_rep", False)
-
-        def body(*args):
-            # new jax propagates the mesh into the body; old
-            # with_sharding_constraint(PartitionSpec) needs the context
-            with mesh:
-                return f(*args)
-
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    jax.shard_map = _compat_shard_map
-
-if not hasattr(jax.lax, "pcast"):  # older jax: no varying-type casts
-    # value-identity; only the (inactive, check_rep=False) replication
-    # tracker ever consumed the annotation
-    jax.lax.pcast = lambda x, axes, to=None: x
-
-
-def _axis_types_kwargs() -> dict:
-    """``axis_types=Auto`` where the installed jax supports it.
-
-    Older jax has no AxisType and no Explicit mode — Auto is the only
-    (implicit) behaviour, so omitting the kwarg is semantically
-    identical there.
-    """
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * len(AXES)}
-
+from jax.sharding import AxisType, Mesh
 
 AXES = ("dp", "pp", "fsdp", "ep", "sp", "tp")
 
@@ -148,7 +101,8 @@ def make_hybrid_mesh(config: MeshConfig | None = None, *,
         process_is_granule=False,
         should_sort_granules_by_key=True,
     ) if _has_slice_index(devices) else _reshape_fallback(devices, shape)
-    return Mesh(dev_mesh.reshape(shape), AXES, **_axis_types_kwargs())
+    return Mesh(dev_mesh.reshape(shape), AXES,
+                axis_types=(AxisType.Auto,) * len(AXES))
 
 
 def _has_slice_index(devices) -> bool:
@@ -171,5 +125,5 @@ def make_mesh(config: MeshConfig | None = None, devices=None) -> Mesh:
     # partitioner propagates + inserts collectives (GSPMD), rather than
     # jax 0.9's default Explicit sharding-in-types mode.
     return jax.make_mesh(
-        shape, AXES, devices=devices, **_axis_types_kwargs()
-    )
+        shape, AXES, devices=devices,
+        axis_types=(AxisType.Auto,) * len(AXES))

@@ -59,7 +59,7 @@ class LoopMetrics:
     loss: float
     grad_norm: float
     tokens_per_sec: float
-    mfu_pct: float
+    mfu_pct: float | None   # None off-TPU: no chip peak, no MFU
     step_time_ms: float
     # offload arm only (0.0 on the on-chip arm): ms the stream spent
     # blocked on device->host transfers, and the fraction of the
@@ -131,7 +131,7 @@ def fit(
                               offload=loop.offload)
 
     n_dev = mesh.devices.size
-    peak = device_peak_flops(jax.tree_util.tree_leaves(mesh.devices)[0])
+    peak = device_peak_flops(mesh.devices.flat[0])
 
     history: list[LoopMetrics] = []
     start = int(jax.device_get(state.step))
@@ -176,7 +176,8 @@ def fit(
                     loss=float(m["loss"]),
                     grad_norm=float(m["grad_norm"]),
                     tokens_per_sec=tps,
-                    mfu_pct=100.0 * flops / (n_dev * peak) if peak else 0.0,
+                    mfu_pct=(100.0 * flops / (n_dev * peak) if peak
+                             else None),
                     step_time_ms=1e3 * dt / max(steps_done, 1),
                     offload_transfer_ms=float(
                         m.get("offload_transfer_ms", 0.0)),
@@ -184,8 +185,10 @@ def fit(
                         m.get("offload_overlap_frac", 0.0)),
                 )
                 history.append(rec)
-                log.info("step %d loss %.4f %.0f tok/s mfu %.1f%%",
-                         rec.step, rec.loss, rec.tokens_per_sec, rec.mfu_pct)
+                log.info("step %d loss %.4f %.0f tok/s mfu %s",
+                         rec.step, rec.loss, rec.tokens_per_sec,
+                         "n/a" if rec.mfu_pct is None
+                         else f"{rec.mfu_pct:.1f}%")
                 for cb in callbacks:
                     cb(rec)
                 t0 = time.perf_counter()

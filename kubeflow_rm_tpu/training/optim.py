@@ -23,10 +23,9 @@ design: :func:`make_offload_optimizer` decomposes the exact
 global-norm clip is leaf-local; the clip itself needs one scalar — the
 global norm — which the train step computes on device and threads
 through), so the streamed update is arithmetically identical to the
-on-chip one, leaf for leaf. Host placement uses ``pinned_host``
-memory-kind staging where the runtime supports it and plain CPU-backend
-arrays (which *are* host RAM) everywhere else, so the mechanism is
-testable on the CPU CI host.
+on-chip one, leaf for leaf. Host placement is plain CPU-backend arrays
+(which *are* host RAM), so the mechanism is testable on the CPU CI
+host.
 """
 
 from dataclasses import dataclass
@@ -117,46 +116,15 @@ def make_optimizer(cfg: OptimConfig) -> optax.GradientTransformation:
 # host-offload policy: per-leaf chains + host placement
 # ---------------------------------------------------------------------------
 
-_HOST_DEVICE = None
-
-
 def host_device():
     """The device whose memory is host RAM: the CPU backend's device
     (present alongside TPU/GPU backends, and the only device on the CI
     host). Optimizer state committed here is host-resident on every
-    platform."""
-    global _HOST_DEVICE
-    if _HOST_DEVICE is None:
-        import jax
-        try:
-            _HOST_DEVICE = jax.local_devices(backend="cpu")[0]
-        except RuntimeError:
-            _HOST_DEVICE = jax.devices()[0]
-    return _HOST_DEVICE
-
-
-_PINNED = None  # lazily resolved: SingleDeviceSharding | False
-
-
-def pinned_host_sharding():
-    """A ``pinned_host`` memory-kind sharding for transfer staging, or
-    None where the runtime has no such memory space (CPU backends
-    expose only ``unpinned_host``; the device_get path below is the
-    fallback and the mechanism the CI host tests)."""
-    global _PINNED
-    if _PINNED is None:
-        import jax
-        from jax.sharding import SingleDeviceSharding
-        try:
-            s = SingleDeviceSharding(jax.devices()[0],
-                                     memory_kind="pinned_host")
-            jax.device_put(jnp.zeros((1,)), s)
-            _PINNED = s
-        except (ValueError, RuntimeError):
-            # backend has no pinned_host memory space (CPU exposes
-            # only unpinned_host) — cache the miss, use device_get
-            _PINNED = False
-    return _PINNED or None
+    platform. Raises where the process has no CPU backend (e.g.
+    ``JAX_PLATFORMS=tpu``): the accelerator is not a place to offload
+    to."""
+    import jax
+    return jax.local_devices(backend="cpu")[0]
 
 
 def host_put(x):

@@ -29,12 +29,18 @@ _PEAK_BF16 = (
 
 
 def device_peak_flops(device) -> float | None:
-    """Peak dense bf16 FLOPs/sec for a jax device, or None if unknown."""
-    kind = getattr(device, "device_kind", "").lower()
+    """Peak dense bf16 FLOPs/sec for a jax device. None off-TPU, where
+    there is no MFU to report; a TPU kind missing from the table is an
+    error, not a default."""
+    if device.platform != "tpu":
+        return None
+    kind = device.device_kind.lower()
     for sub, peak in _PEAK_BF16:
         if sub in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no peak bf16 FLOP/s on record for TPU device_kind "
+        f"{device.device_kind!r}; add it to utils.flops._PEAK_BF16")
 
 
 def matmul_param_count(cfg: LlamaConfig) -> int:

@@ -32,7 +32,8 @@ class TensorboardCallback:
         self.writer.add_scalar("train/grad_norm", m.grad_norm, m.step)
         self.writer.add_scalar("perf/tokens_per_sec", m.tokens_per_sec,
                                m.step)
-        self.writer.add_scalar("perf/mfu_pct", m.mfu_pct, m.step)
+        if m.mfu_pct is not None:
+            self.writer.add_scalar("perf/mfu_pct", m.mfu_pct, m.step)
         self.writer.add_scalar("perf/step_time_ms", m.step_time_ms,
                                m.step)
         self.writer.flush()

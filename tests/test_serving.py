@@ -272,3 +272,65 @@ def test_gateway_concurrent_tenants_all_complete(model):
         assert 0 < snap["batch_occupancy"] <= 1.0
     finally:
         gw.close()
+
+
+# -- engine failure: waiters wake with the cause, replica leaves service ----
+
+
+class _BrokenEngine:
+    """Engine stub: admits like the real one, then ``step`` raises (a
+    compile error or device OOM, as the drain thread would see it)."""
+
+    slots = 2
+
+    def __init__(self):
+        self.queued = []
+
+    @property
+    def queue_depth(self):
+        return len(self.queued)
+
+    active_slots = 0
+
+    def submit(self, prompt, **kw):
+        req = type("Req", (), {"done": False, "tokens": []})()
+        self.queued.append(req)
+        return req
+
+    def step(self):
+        raise MemoryError("RESOURCE_EXHAUSTED: injected device OOM")
+
+    def stats(self):
+        return {"queue_depth": self.queue_depth, "active_slots": 0,
+                "slots": self.slots, "batch_occupancy": 0.0,
+                "decode_steps": 0, "finished_total": 0}
+
+
+def test_engine_step_failure_fails_waiters_and_marks_replica():
+    from werkzeug.test import Client
+
+    from kubeflow_rm_tpu.controlplane.serving_fleet import ServingFleet
+    from kubeflow_rm_tpu.controlplane.webapps.serving import EngineFailed
+
+    gw = ServingGateway(_BrokenEngine(), admission=False)
+    fleet = ServingFleet({"r0": gw}, prefix_tokens=4)
+    try:
+        # the waiter wakes at once with the engine's error as the
+        # cause — not after wait()'s 300 s timeout on a dead thread
+        with pytest.raises(EngineFailed) as exc:
+            fleet.submit_and_wait("t", [1, 2, 3], max_new_tokens=4,
+                                  timeout_s=30.0)
+        assert isinstance(exc.value.__cause__, MemoryError)
+        assert isinstance(gw.error, MemoryError)
+        assert gw.snapshot()["error"].startswith("MemoryError")
+        # out of service: later submits shed with the reason, and the
+        # health check a router polls says so
+        assert gw.try_submit("t", [1], max_new_tokens=1) == (None, "failed")
+        tokens, info = fleet.submit_and_wait("t", [1], max_new_tokens=1)
+        assert tokens is None and info["reason"] == "no_replica"
+        resp = Client(make_serving_app(gw, LlamaConfig.tiny())).get(
+            "/healthz")
+        assert resp.status_code == 503
+        assert resp.get_json()["state"] == "failed"
+    finally:
+        fleet.close()

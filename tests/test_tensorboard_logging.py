@@ -34,7 +34,9 @@ def test_fit_writes_tensorboard_events(tmp_path, devices8):
 
     # the written scalar tags survive in the event file
     raw = events[0].read_bytes()
-    assert b"train/loss" in raw and b"perf/mfu_pct" in raw
+    assert b"train/loss" in raw and b"perf/tokens_per_sec" in raw
+    # MFU is a chip metric: absent on the CPU mesh, not written as 0
+    assert b"perf/mfu_pct" not in raw
 
 
 def test_tensorboard_cr_serves_the_same_path(tmp_path):

@@ -230,7 +230,7 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
                 raise TimeoutError("a serving request never returned")
         wall = time.perf_counter() - t0
 
-        outputs = []
+        outputs, timelines = [], []
         for (prompt, new), res in zip(requests, results):
             if isinstance(res, BaseException):
                 raise res
@@ -241,6 +241,7 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
                 raise RuntimeError(
                     f"request returned {len(tokens)} of {new} tokens")
             outputs.append(tokens)
+            timelines.append(info["timeline"])
 
         stats = engine.stats()
         if stats["prefix_hit_tokens"] <= 0:
@@ -332,7 +333,29 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
         "token_identical_to_generate_fused": f"{identical}/{len(requests)}",
         "first_mismatch_at": first_diff,
         "wall_s_informational": round(wall, 2),
+        **_timeline_summary(timelines),
     }
+
+
+def _timeline_summary(timelines: list) -> dict:
+    """Median and 95th percentile, in ms, of what the engine's stamps
+    say of the requests: the wait for a slot, the time to the first
+    token and the gap between tokens. On a cold start the first two
+    hold the compiles. Reported, not gated."""
+    import numpy as np
+
+    series = {
+        "queue_wait": [t["t_admitted"] - t["t_submitted"]
+                       for t in timelines],
+        "time_to_first_token": [t["t_first_token"] - t["t_submitted"]
+                                for t in timelines],
+        "inter_token": [d for t in timelines
+                        for d in np.diff(t["t_tokens"])],
+    }
+    return {f"{name}_ms_informational": {
+                "p50": round(1e3 * float(np.percentile(v, 50)), 2),
+                "p95": round(1e3 * float(np.percentile(v, 95)), 2)}
+            for name, v in series.items()}
 
 
 # ---------------------------------------------------------------------

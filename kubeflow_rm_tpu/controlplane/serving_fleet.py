@@ -702,8 +702,11 @@ class ServingFleet:
                         timeout_s: float = 300.0):
         """Route, decode, and — if the replica goes away mid-flight —
         migrate and resume. Returns ``(tokens, info)`` on success or
-        ``(None, info)`` on shed; ``info`` carries the replica path and
-        shed reason. A migrated request resumes from the tokens it
+        ``(None, info)`` on shed; ``info`` carries the replica path,
+        the shed reason and, under ``"timeline"``, the engine's stamps
+        (``time.perf_counter()``: ``t_submitted``, ``t_admitted``,
+        ``t_first_token``, ``t_finished``, ``t_tokens``) of the attempt
+        that answered. A migrated request resumes from the tokens it
         already produced (greedy continuation is bit-identical to an
         uninterrupted run), so a kill costs latency, never correctness.
 
@@ -770,7 +773,8 @@ class ServingFleet:
                 got = gw.wait(pending, timeout_s)
                 tokens.extend(got)
                 return tokens, {"replicas": path,
-                                "migrations": len(path) - 1}
+                                "migrations": len(path) - 1,
+                                "timeline": pending.req.timeline()}
             except ReplicaUnavailable as e:
                 tokens.extend(e.tokens_so_far)
                 self.migrations += 1
@@ -926,6 +930,9 @@ def make_fleet_app(fleet: ServingFleet, cfg):
                              status=status)
                 resp.headers["Retry-After"] = "1"
             else:
+                # the timeline's stamps are this process's clock:
+                # nothing to a client on the other side of HTTP
+                info.pop("timeline", None)
                 resp = _json({"tokens": tokens, **info})
         except HTTPException as e:
             resp = e

@@ -51,6 +51,7 @@ from kubeflow_rm_tpu.controlplane import metrics as cp_metrics
 from kubeflow_rm_tpu.controlplane import tracing
 from kubeflow_rm_tpu.controlplane.deploy.kubeclient import TokenBucket
 from kubeflow_rm_tpu.analysis.lockgraph import make_lock
+from kubeflow_rm_tpu.utils.profiling import annotate
 
 log = logging.getLogger(__name__)
 
@@ -92,14 +93,15 @@ class _Pending:
     """A request in flight: the HTTP thread parks on ``event`` while
     the drain thread decodes."""
 
-    __slots__ = ("req", "tenant", "event", "t_submit", "t_done",
-                 "trace", "t_submit_epoch", "failed", "error")
+    __slots__ = ("req", "tenant", "event", "t_done", "trace", "failed",
+                 "error")
 
     def __init__(self, req, tenant, trace=None):
         self.req = req
         self.tenant = tenant
         self.event = threading.Event()
-        self.t_submit = time.monotonic()
+        # when the drain thread let go of the request, on the clock of
+        # the engine's own stamps (``req.t_submitted`` is the start)
         self.t_done = None
         # set when the replica abandons the request (drain/close)
         # before the engine finishes it — wait() then raises
@@ -109,10 +111,8 @@ class _Pending:
         # wait() then raises EngineFailed from it
         self.error = None
         # traceparent of the admitting request, if it carried one —
-        # the drain thread stamps the decode span against it; epoch
-        # twin of t_submit because spans use wall time
+        # the drain thread records the request's spans against it
         self.trace = trace
-        self.t_submit_epoch = time.time()
 
 
 class ServingGateway:
@@ -134,6 +134,8 @@ class ServingGateway:
         self.max_queue = max_queue
         self.admission = admission
         self._clock = clock or time.monotonic
+        # turns a perf_counter stamp into the span collector's epoch
+        self._epoch_offset = time.time() - time.perf_counter()
         self._lock = make_lock("serving.gateway")  # engine + pending
         self._rate_buckets: dict[str, TokenBucket] = {}
         self._token_buckets: dict[str, TokenBucket] = {}
@@ -298,7 +300,7 @@ class ServingGateway:
                 "replica gave up this request mid-flight "
                 "(drain or shutdown) — resubmit elsewhere",
                 tokens_so_far=pending.req.tokens)
-        lat_s = pending.t_done - pending.t_submit
+        lat_s = pending.t_done - pending.req.t_submitted
         tenant = pending.tenant
         cp_metrics.SERVING_REQUESTS_TOTAL.labels(tenant, "ok").inc()
         cp_metrics.SERVING_REQUEST_LATENCY_SECONDS.labels(
@@ -311,84 +313,95 @@ class ServingGateway:
 
     def _drain(self) -> None:
         while not self._stop.is_set():
+            ready = []
             with self._lock:
                 busy = (self.engine.queue_depth
                         or self.engine.active_slots)
-                try:
-                    finished = self.engine.step() if busy else []
-                except Exception as e:
-                    # the engine's state is unknown past this point:
-                    # take the replica out of service and wake every
-                    # waiter with the cause, instead of dying silently
-                    # and leaving them to their timeouts
-                    log.exception("engine step failed; replica out "
-                                  "of service")
-                    self.error = e
-                    self.draining = True
-                    orphans, self._pending = self._pending, []
-                    for p in orphans:
-                        p.error = e
-                        p.t_done = time.monotonic()
-                        p.event.set()
-                    return
                 if busy:
-                    stats = self.engine.stats()
-                    cp_metrics.SERVING_QUEUE_DEPTH.set(
-                        stats["queue_depth"])
-                    cp_metrics.SERVING_ACTIVE_SLOTS.set(
-                        stats["active_slots"])
-                    cp_metrics.SERVING_BATCH_OCCUPANCY.set(
-                        stats["batch_occupancy"])
-                    for c, d in stats.get("queue_depth_by_class",
-                                          {}).items():
-                        cp_metrics.SERVING_CLASS_QUEUE_DEPTH.labels(
-                            c).set(d)
-                    if stats.get("paged"):
-                        cp_metrics.SERVING_FREE_BLOCK_FRACTION.set(
-                            stats["free_block_fraction"])
-                        if stats.get("prompt_tokens"):
-                            hr = stats["prefix_hit_ratio"]
-                            cp_metrics.SERVING_PREFIX_HIT_RATIO.set(hr)
-                            cp_metrics.SERVING_PREFIX_MISS_RATIO.set(
-                                1.0 - hr)
-                if finished:
-                    done_ids = {id(p.req) for p in self._pending
-                                if p.req.done}
-                    now = time.monotonic()
-                    ready = [p for p in self._pending
-                             if id(p.req) in done_ids]
-                    self._pending = [p for p in self._pending
-                                     if id(p.req) not in done_ids]
-                else:
-                    ready = []
+                    with annotate("gateway.drain"):
+                        ready = self._step_locked()
+            if ready is None:       # the engine failed: out of service
+                return
+            now = time.perf_counter()
             for p in ready:
-                p.t_done = now
-                lat_ms = (p.t_done - p.t_submit) * 1e3
-                window = self._lat_windows.setdefault(p.tenant, [])
-                window.append(lat_ms)
-                del window[:-256]
-                self._ema_ms = (lat_ms if self._ema_ms is None else
-                                0.8 * self._ema_ms + 0.2 * lat_ms)
-                if p.trace is not None:
-                    # retroactive span: the interval was measured here
-                    # on the drain thread, parented on the admitting
-                    # request so prefill+decode joins its trace
-                    tracing.record_span(
-                        "serving.decode",
-                        start=p.t_submit_epoch, end=time.time(),
-                        parent=p.trace,
-                        attrs={"tenant": p.tenant,
-                               "tokens": len(p.req.tokens)})
-                    ctx = tracing.parse_traceparent(p.trace)
-                    ex = self._exemplars.get(p.tenant)
-                    if ctx is not None and (ex is None
-                                            or lat_ms > ex["latency_ms"]):
-                        self._exemplars[p.tenant] = {
-                            "trace_id": ctx.trace_id,
-                            "latency_ms": round(lat_ms, 3)}
-                p.event.set()
+                self._complete(p, now)
             if not busy:
                 self._stop.wait(0.001)
+
+    def _step_locked(self) -> list[_Pending] | None:
+        """One engine step and what follows it, under the lock: the
+        gauges, and the pendings whose requests are done. None where
+        the step raised."""
+        try:
+            finished = self.engine.step()
+        except Exception as e:
+            # the engine's state is unknown past this point: take the
+            # replica out of service and wake every waiter with the
+            # cause, instead of dying silently and leaving them to
+            # their timeouts
+            log.exception("engine step failed; replica out of service")
+            self.error = e
+            self.draining = True
+            orphans, self._pending = self._pending, []
+            for p in orphans:
+                p.error = e
+                p.t_done = time.perf_counter()
+                p.event.set()
+            return None
+        with annotate("gateway.publish"):
+            stats = self.engine.stats()
+            cp_metrics.SERVING_QUEUE_DEPTH.set(stats["queue_depth"])
+            cp_metrics.SERVING_ACTIVE_SLOTS.set(stats["active_slots"])
+            cp_metrics.SERVING_BATCH_OCCUPANCY.set(
+                stats["batch_occupancy"])
+            for c, d in stats.get("queue_depth_by_class", {}).items():
+                cp_metrics.SERVING_CLASS_QUEUE_DEPTH.labels(c).set(d)
+            if stats.get("paged"):
+                cp_metrics.SERVING_FREE_BLOCK_FRACTION.set(
+                    stats["free_block_fraction"])
+                if stats.get("prompt_tokens"):
+                    hr = stats["prefix_hit_ratio"]
+                    cp_metrics.SERVING_PREFIX_HIT_RATIO.set(hr)
+                    cp_metrics.SERVING_PREFIX_MISS_RATIO.set(1.0 - hr)
+        if not finished:
+            return []
+        ready = [p for p in self._pending if p.req.done]
+        self._pending = [p for p in self._pending if not p.req.done]
+        return ready
+
+    def _complete(self, p: _Pending, now: float) -> None:
+        """Off the lock: the request's latency into the tenant's
+        window and the SLO's EMA, its spans where it was traced, then
+        wake its waiter."""
+        p.t_done = now
+        lat_ms = (p.t_done - p.req.t_submitted) * 1e3
+        window = self._lat_windows.setdefault(p.tenant, [])
+        window.append(lat_ms)
+        del window[:-256]
+        self._ema_ms = (lat_ms if self._ema_ms is None else
+                        0.8 * self._ema_ms + 0.2 * lat_ms)
+        if p.trace is not None:
+            # retroactive spans from the engine's stamps, parented on
+            # the admitting request so they join its trace: together
+            # they partition submit -> done
+            req, off = p.req, self._epoch_offset
+            attrs = {"tenant": p.tenant, "tokens": len(req.tokens)}
+            for name, start, end in (
+                    ("serving.queue", req.t_submitted, req.t_admitted),
+                    ("serving.prefill", req.t_admitted, req.t_first_token),
+                    ("serving.decode", req.t_first_token, req.t_finished)):
+                if start is not None and end is not None:   # no token
+                    tracing.record_span(name, start=start + off,
+                                        end=end + off, parent=p.trace,
+                                        attrs=attrs)
+            ctx = tracing.parse_traceparent(p.trace)
+            ex = self._exemplars.get(p.tenant)
+            if ctx is not None and (ex is None
+                                    or lat_ms > ex["latency_ms"]):
+                self._exemplars[p.tenant] = {
+                    "trace_id": ctx.trace_id,
+                    "latency_ms": round(lat_ms, 3)}
+        p.event.set()
 
     def start_drain(self) -> list[_Pending]:
         """Begin pulling this replica out of rotation: new submits
@@ -407,7 +420,7 @@ class ServingGateway:
             cp_metrics.SERVING_QUEUE_DEPTH.set(self.engine.queue_depth)
         for p in evicted:
             p.failed = True
-            p.t_done = time.monotonic()
+            p.t_done = time.perf_counter()
             p.event.set()
         return evicted
 
@@ -422,7 +435,7 @@ class ServingGateway:
         self._thread.join(timeout=5)
         for p in orphans:         # fail any orphans; a request the
             p.failed = True       # engine DID finish stays ok (wait
-            p.t_done = time.monotonic()   # checks req.done first)
+            p.t_done = time.perf_counter()   # checks req.done first)
             p.event.set()
 
     # -- observability -----------------------------------------------------
@@ -566,7 +579,7 @@ def make_serving_app(gateway: ServingGateway, cfg):
                 resp.headers["Retry-After"] = "1"
                 return resp(environ, start_response)
             tokens = gateway.wait(pending)
-            lat_ms = (pending.t_done - pending.t_submit) * 1e3
+            lat_ms = (pending.t_done - pending.req.t_submitted) * 1e3
             resp = _json({"tokens": tokens, "latency_ms": lat_ms})
         except HTTPException as e:
             resp = e

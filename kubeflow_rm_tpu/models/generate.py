@@ -27,6 +27,7 @@ capability the jupyter-jax image adds on top (SURVEY.md §2.6).
 from __future__ import annotations
 
 import contextlib
+import time
 from dataclasses import dataclass
 from functools import partial
 
@@ -34,7 +35,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_rm_tpu.analysis.jaxcheck import hostsync as _hostsync
 from kubeflow_rm_tpu.analysis.jaxcheck import recompile as _jit_sentinel
 from kubeflow_rm_tpu.models.llama import LlamaConfig
 from kubeflow_rm_tpu.models.lora import lora_proj
@@ -45,6 +45,7 @@ from kubeflow_rm_tpu.ops import (
     rms_norm,
     rope_angles,
 )
+from kubeflow_rm_tpu.utils.profiling import annotate as _span
 
 _UNFILLED = jnp.iinfo(jnp.int32).max
 
@@ -764,10 +765,27 @@ class EngineRequest:
         self.done = False
         self.rid = EngineRequest._next_id
         EngineRequest._next_id += 1
-        # filled by the engine for latency accounting
-        self.submitted_step = None
+        # filled by the engine: the decode-step ordinal at seating,
+        # and the request's timeline on ``time.perf_counter()`` —
+        # queued, taken off the queue for a slot (before its prefill
+        # is dispatched), each token picked, retired
         self.admitted_step = None
-        self.finished_step = None
+        self.t_submitted = None
+        self.t_admitted = None
+        self.t_tokens: list[float] = []
+        self.t_finished = None
+
+    @property
+    def t_first_token(self):
+        return self.t_tokens[0] if self.t_tokens else None
+
+    def timeline(self) -> dict:
+        """The stamps as one dict, for whoever hands them on."""
+        return {"t_submitted": self.t_submitted,
+                "t_admitted": self.t_admitted,
+                "t_first_token": self.t_first_token,
+                "t_finished": self.t_finished,
+                "t_tokens": list(self.t_tokens)}
 
 
 class ContinuousBatchingEngine:
@@ -934,7 +952,7 @@ class ContinuousBatchingEngine:
                             eos_id=eos_id, temperature=temperature,
                             top_k=top_k, key=key, slo_class=slo_class,
                             speculative=speculative)
-        req.submitted_step = self.decode_steps
+        req.t_submitted = time.perf_counter()
         self._queues[slo_class].append(req)
         return req
 
@@ -1047,9 +1065,10 @@ class ContinuousBatchingEngine:
                 req = self._next_queued()
                 if req is None:
                     return
+                t_taken = time.perf_counter()
                 if req.speculative:
                     # runs whole at this boundary, never holds a slot
-                    self._run_speculative(req)
+                    self._run_speculative(req, t_taken)
                     continue
                 break
             if self.paged and req.chain is not None:
@@ -1079,10 +1098,12 @@ class ContinuousBatchingEngine:
             self._last[i] = last
             self._slot_req[i] = req
             req.admitted_step = self.decode_steps
+            req.t_admitted = t_taken
             self.admitted_total += 1
             self.admitted_by_class[req.slo_class] += 1
 
-    def _run_speculative(self, req: EngineRequest) -> None:
+    def _run_speculative(self, req: EngineRequest,
+                         t_taken: float) -> None:
         """Execute a speculative request whole: one fused prompt-lookup
         program (``generate_speculative_fused``), greedy, exactness-
         matched to ``generate_fused`` for the same prompt. The request
@@ -1100,7 +1121,10 @@ class ContinuousBatchingEngine:
         req.tokens = toks
         req.done = True
         req.admitted_step = self.decode_steps
-        req.finished_step = self.decode_steps
+        # the fused program hands every token over at once
+        req.t_admitted = t_taken
+        req.t_finished = time.perf_counter()
+        req.t_tokens = [req.t_finished] * len(toks)
         self.admitted_total += 1
         self.admitted_by_class[req.slo_class] += 1
         self.finished_total += 1
@@ -1115,7 +1139,7 @@ class ContinuousBatchingEngine:
         pads = jnp.asarray([Tb - Tp], jnp.int32)
         tmp = init_cache(self.cfg, 1, self.slot_len)
         _jit_sentinel.note("engine.prefill", padded)
-        with _hostsync.region("engine.prefill"):
+        with _span("engine.prefill", hot=True):
             logits, tmp = _decode_step(self.params, self.cfg, tmp,
                                        padded, pads)
         self.cache = _install_row(
@@ -1187,7 +1211,7 @@ class ContinuousBatchingEngine:
         padded = jnp.asarray([suffix + [0] * (Tc - len(suffix))],
                              jnp.int32)
         _jit_sentinel.note("engine.prefill", padded)
-        with _hostsync.region("engine.prefill"):
+        with _span("engine.prefill", hot=True):
             last, tk, tv, tpos = paging.paged_prefill(
                 self.params, self.cfg, self.cache,
                 jnp.asarray(load_row, jnp.int32),
@@ -1322,7 +1346,7 @@ class ContinuousBatchingEngine:
         padded = jnp.asarray([suffix + [0] * (Tc - len(suffix))],
                              jnp.int32)
         _jit_sentinel.note("engine.prefill", padded)
-        with _hostsync.region("engine.prefill"):
+        with _span("engine.prefill", hot=True):
             last, tk, tv, tpos = paging.paged_prefill(
                 self.params, self.cfg, self.cache,
                 jnp.asarray(load_row, jnp.int32),
@@ -1376,8 +1400,30 @@ class ContinuousBatchingEngine:
 
     def step(self) -> list[EngineRequest]:
         """Admit, sample, retire, decode — one token boundary. Returns
-        the requests that finished at this boundary."""
-        self._admit()
+        the requests that finished at this boundary. Four spans
+        partition it (recorded while a profiler session is open):
+        admit, pick, dispatch, scatter."""
+        with _span("engine.step"):
+            with _span("engine.admit"):
+                self._admit()
+            with _span("engine.pick"):
+                finished, tokens, active = self._pick()
+            if any(active):
+                with _span("engine.dispatch", hot=True):
+                    last = self._dispatch(tokens, active)
+                with _span("engine.scatter"):
+                    for i in range(self.slots):
+                        if active[i]:
+                            self._last[i] = last[i]
+                    self.decode_steps += 1
+                    self.occupancy_sum += sum(active)
+        return finished
+
+    def _pick(self):
+        """Sample every live slot's next token from its last logits,
+        retire the slots that are through. Returns the requests that
+        finished and, a slot each, the token to feed and whether the
+        slot stays live."""
         finished: list[EngineRequest] = []
         if self._spec_finished:
             # speculative requests ran whole inside _admit
@@ -1394,44 +1440,38 @@ class ContinuousBatchingEngine:
                 sub = None
             # the ONE deliberate sync per token boundary: the sampled
             # token drives host-side scheduling (EOS retirement,
-            # admission) and cannot stay on device.  hostsync.region
-            # in callers documents the same budget dynamically.
+            # admission) and cannot stay on device.
             nxt = int(_pick_row(self._last[i], sub,  # kfrm: disable=KFRM006
                                 temperature=req.temperature,
                                 top_k=req.top_k))
+            now = time.perf_counter()
             req.tokens.append(nxt)
+            req.t_tokens.append(now)
             hit_eos = req.eos_id is not None and nxt == req.eos_id
             if hit_eos or len(req.tokens) >= req.max_new_tokens:
                 req.done = True
-                req.finished_step = self.decode_steps
+                req.t_finished = now
                 finished.append(req)
                 self._retire(i)
                 self.finished_total += 1
             else:
                 tokens[i] = nxt
                 active[i] = True
-        n_active = sum(active)
-        if n_active:
-            tok_arr = jnp.asarray(tokens, jnp.int32)
-            act_arr = jnp.asarray(active)
-            _jit_sentinel.note("engine.decode_step", tok_arr, act_arr)
-            if self.paged:
-                from kubeflow_rm_tpu.models import paging
-                with _hostsync.region("engine.decode"):
-                    last, self.cache = paging.paged_decode_step(
-                        self.params, self.cfg, self.cache,
-                        tok_arr, act_arr)
-            else:
-                with _hostsync.region("engine.decode"):
-                    last, self.cache = slot_decode_step(
-                        self.params, self.cfg, self.cache,
-                        tok_arr, act_arr)
-            for i in range(self.slots):
-                if active[i]:
-                    self._last[i] = last[i]
-            self.decode_steps += 1
-            self.occupancy_sum += n_active
-        return finished
+        return finished, tokens, active
+
+    def _dispatch(self, tokens, active):
+        """One decode step for all slots; returns the logits rows."""
+        tok_arr = jnp.asarray(tokens, jnp.int32)
+        act_arr = jnp.asarray(active)
+        _jit_sentinel.note("engine.decode_step", tok_arr, act_arr)
+        if self.paged:
+            from kubeflow_rm_tpu.models import paging
+            last, self.cache = paging.paged_decode_step(
+                self.params, self.cfg, self.cache, tok_arr, act_arr)
+        else:
+            last, self.cache = slot_decode_step(
+                self.params, self.cfg, self.cache, tok_arr, act_arr)
+        return last
 
     def run(self) -> list[EngineRequest]:
         """Drive ``step`` until every queued/live request retires."""

@@ -26,12 +26,12 @@ from typing import Any, Callable, Iterable
 
 import jax
 
-from kubeflow_rm_tpu.analysis.jaxcheck import hostsync as _hostsync
 from kubeflow_rm_tpu.training.checkpoint import Checkpointer
 from kubeflow_rm_tpu.training.train import (
     TrainConfig, TrainState, init_train_state, make_train_step, shard_batch,
 )
 from kubeflow_rm_tpu.utils.flops import device_peak_flops, train_flops_per_token
+from kubeflow_rm_tpu.utils.profiling import annotate
 
 log = logging.getLogger("kubeflow_rm_tpu.train")
 
@@ -141,11 +141,13 @@ def fit(
     batch = first
     try:
         for i in range(start, total):
-            dev_batch = shard_batch({k: batch[k] for k in batch_keys}, mesh)
+            with annotate("train.shard_batch"):
+                dev_batch = shard_batch({k: batch[k] for k in batch_keys},
+                                        mesh)
             # hot region: dispatch must stay async — the deliberate
             # metric syncs below run OUTSIDE it (KFRM_HOSTSYNC_PROBE
             # records any implicit sync in here as a witness)
-            with _hostsync.region("train.step"):
+            with annotate("train.step", hot=True):
                 state, metrics = step_fn(state, dev_batch)
 
             now = i + 1
@@ -163,36 +165,38 @@ def fit(
                                 "%d); stopping", now, total)
                     total = now
             if now % loop.log_every == 0 or now == total:
-                m = jax.device_get(metrics)  # blocks: one sync per interval
-                dt = time.perf_counter() - t0
-                steps_done = now - interval_start
-                tokens = steps_done * dev_batch["tokens"].size
-                tps = tokens / dt if dt > 0 else 0.0
-                flops = tps * train_flops_per_token(
-                    cfg.model, dev_batch["tokens"].shape[-1],
-                    frozen_base=cfg.optim.train_only is not None)
-                rec = LoopMetrics(
-                    step=now,
-                    loss=float(m["loss"]),
-                    grad_norm=float(m["grad_norm"]),
-                    tokens_per_sec=tps,
-                    mfu_pct=(100.0 * flops / (n_dev * peak) if peak
-                             else None),
-                    step_time_ms=1e3 * dt / max(steps_done, 1),
-                    offload_transfer_ms=float(
-                        m.get("offload_transfer_ms", 0.0)),
-                    offload_overlap_frac=float(
-                        m.get("offload_overlap_frac", 0.0)),
-                )
-                history.append(rec)
-                log.info("step %d loss %.4f %.0f tok/s mfu %s",
-                         rec.step, rec.loss, rec.tokens_per_sec,
-                         "n/a" if rec.mfu_pct is None
-                         else f"{rec.mfu_pct:.1f}%")
-                for cb in callbacks:
-                    cb(rec)
-                t0 = time.perf_counter()
-                interval_start = now
+                with annotate("train.log"):
+                    # blocks: one sync per interval
+                    m = jax.device_get(metrics)
+                    dt = time.perf_counter() - t0
+                    steps_done = now - interval_start
+                    tokens = steps_done * dev_batch["tokens"].size
+                    tps = tokens / dt if dt > 0 else 0.0
+                    flops = tps * train_flops_per_token(
+                        cfg.model, dev_batch["tokens"].shape[-1],
+                        frozen_base=cfg.optim.train_only is not None)
+                    rec = LoopMetrics(
+                        step=now,
+                        loss=float(m["loss"]),
+                        grad_norm=float(m["grad_norm"]),
+                        tokens_per_sec=tps,
+                        mfu_pct=(100.0 * flops / (n_dev * peak) if peak
+                                 else None),
+                        step_time_ms=1e3 * dt / max(steps_done, 1),
+                        offload_transfer_ms=float(
+                            m.get("offload_transfer_ms", 0.0)),
+                        offload_overlap_frac=float(
+                            m.get("offload_overlap_frac", 0.0)),
+                    )
+                    history.append(rec)
+                    log.info("step %d loss %.4f %.0f tok/s mfu %s",
+                             rec.step, rec.loss, rec.tokens_per_sec,
+                             "n/a" if rec.mfu_pct is None
+                             else f"{rec.mfu_pct:.1f}%")
+                    for cb in callbacks:
+                        cb(rec)
+                    t0 = time.perf_counter()
+                    interval_start = now
             if (ckpt and loop.checkpoint_every
                     and now % loop.checkpoint_every == 0):
                 ckpt.save(state)

@@ -93,10 +93,39 @@ def trace(logdir: str, *, create_perfetto_link: bool = False):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named host region inside a device trace (TraceAnnotation)."""
+def annotate(name: str, *, hot: bool = False):
+    """Named host region inside a device trace: a
+    ``jax.profiler.TraceAnnotation``, recorded only while a profiler
+    session is open (``trace()`` above, or any ``start_trace``) and on
+    the clock the device planes use; with no session it is an object
+    and two calls. ``hot=True`` also opens the hostsync probe's hot
+    region of the same name where that probe is on, so a boundary
+    takes one ``with``.
+
+    The program's own spans, all through here (PERF.md section 3 sets
+    each beside the metric that reads it):
+
+    - ``engine.step``, and the four that partition it:
+      ``engine.admit`` (with an ``engine.prefill`` around each prefill
+      dispatch), ``engine.pick``, ``engine.dispatch``,
+      ``engine.scatter`` — ``ContinuousBatchingEngine.step()``
+    - ``gateway.drain`` around a working iteration of the gateway's
+      drain thread, ``gateway.publish`` around its gauge block
+    - ``train.shard_batch``, ``train.step``, ``train.log`` in ``fit()``
+    """
     import jax
-    return jax.profiler.TraceAnnotation(name)
+    span = jax.profiler.TraceAnnotation(name)
+    if hot:
+        from kubeflow_rm_tpu.analysis.jaxcheck import hostsync
+        if hostsync.enabled():
+            return _both(hostsync.region(name), span)
+    return span
+
+
+@contextlib.contextmanager
+def _both(outer, inner):
+    with outer, inner:
+        yield
 
 
 @contextlib.contextmanager

@@ -10,9 +10,10 @@ params, random weights from a seed), in ONE process that owns the chip:
   buckets, one pair sharing a prefix (block adoption) and one exact
   repeat (copy-on-write fork). Checked in the run: every request gets
   its full token budget, the prefix cache hit, a paged prefill's
-  last-token logits agree with plain ``models.forward``, and every
+  last-token logits agree with plain ``models.forward``, every
   generated token, teacher-forced through ``forward``, sits within
-  rounding of the reference argmax. Whether each output is
+  rounding of the reference argmax, and the lowered decode step holds
+  the paged-attention pallas kernel. Whether each output is
   token-identical to solo ``generate_fused`` is reported, not gated:
   on the chip bf16 argmax at random init flips within a few tokens.
 - **train**: ``training.loop.fit()`` on a one-device mesh over packed
@@ -143,17 +144,22 @@ def _memory(device) -> dict | None:
 
 
 @contextlib.contextmanager
-def _flash_interpreted():
-    """Rehearsal only: send the model's attention calls to the pallas
-    kernels (which interpret off-TPU) instead of the XLA path "auto"
-    picks on a CPU, so the CPU run walks the code the chip will."""
+def _kernels_interpreted():
+    """Rehearsal only: send the model's attention calls — training's
+    and the paged decode step's — to the pallas kernels (which
+    interpret off-TPU) instead of the XLA paths "auto" picks on a CPU,
+    so the CPU run walks the code the chip will."""
     from functools import partial
     from unittest import mock
 
-    from kubeflow_rm_tpu.models import llama
+    from kubeflow_rm_tpu.models import llama, paging
     from kubeflow_rm_tpu.ops import dot_product_attention
+    from kubeflow_rm_tpu.ops.paged_attention import paged_decode_attention
     with mock.patch.object(llama, "dot_product_attention",
-                           partial(dot_product_attention, impl="flash")):
+                           partial(dot_product_attention, impl="flash")), \
+            mock.patch.object(paging, "paged_decode_attention",
+                              partial(paged_decode_attention,
+                                      impl="pallas")):
         yield
 
 
@@ -208,6 +214,18 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
         {"r0": ServingGateway(engine, policies={"smoke": policy})})
     requests = _requests(sz, cfg.vocab_size)
     results: list = [None] * len(requests)
+
+    # the decode step the engine will dispatch, lowered: on the chip
+    # it must read the pool through the pallas kernel, not fall to the
+    # XLA gather (nothing runs here, so the cache is not donated yet)
+    from kubeflow_rm_tpu.models import paging
+    kernels = paging.paged_decode_step.lower(
+        params, cfg, engine.cache, jnp.zeros((sz.slots,), jnp.int32),
+        jnp.zeros((sz.slots,), bool)).as_text().count("tpu_custom_call")
+    if device.platform == "tpu" and not kernels:
+        raise RuntimeError("the lowered paged_decode_step holds no "
+                           "pallas kernel: decode attention fell to "
+                           "the XLA path")
 
     def client(i):
         prompt, new = requests[i]
@@ -323,6 +341,8 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
                                    for p, _ in requests}),
         "prefills": stats["prefills"],
         "decode_steps": stats["decode_steps"],
+        "kv_blocks_read_total": stats["kv_blocks_read_total"],
+        "pallas_kernels_in_lowered_decode_step": kernels,
         "batch_occupancy": round(stats["batch_occupancy"], 3),
         "prefix_hit_tokens": stats["prefix_hit_tokens"],
         "cow_forks": stats["cow_forks"],
@@ -495,7 +515,7 @@ def main(argv=None) -> int:
 
     report, failed = {}, []
     try:
-        with _flash_interpreted() if dry else contextlib.nullcontext():
+        with _kernels_interpreted() if dry else contextlib.nullcontext():
             for name, phase in (("serve", serve_phase),
                                 ("train", train_phase)):
                 t0 = time.perf_counter()
@@ -526,11 +546,13 @@ def main(argv=None) -> int:
         failed.append("interpret")
         print(f"--- pallas ran interpreted on the chip: {interpreted}",
               file=sys.stderr)
-    if dry and not failed and not any(
-            c["kernel"] == "flash" for c in report["train"]["attention"]):
-        failed.append("rehearsal")
-        print("--- the rehearsal's train step never reached the flash "
-              "kernel", file=sys.stderr)
+    for phase, kernel in (("train", "flash"), ("serve", "paged_decode")):
+        if dry and not failed and not any(
+                c["kernel"] == kernel
+                for c in report[phase]["attention"]):
+            failed.append("rehearsal")
+            print(f"--- the rehearsal's {phase} phase never reached "
+                  f"the {kernel} kernel", file=sys.stderr)
     if failed:
         print(f"chip_smoke: FAILED {failed}", file=sys.stderr)
         return 1

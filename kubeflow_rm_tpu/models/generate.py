@@ -18,7 +18,9 @@ TPU-first choices:
   keep in sync with training.
 - **Layer scan**: the cache rides ``lax.scan`` as scanned xs/ys over
   the same stacked-parameter layout training uses, so compile time
-  stays depth-independent.
+  stays depth-independent. (The paged decode step is the exception:
+  its pool stays outside the scan and is read through the block
+  table, ``ops/paged_attention.py``.)
 
 The reference platform ships no model runtime at all; this module is
 capability the jupyter-jax image adds on top (SURVEY.md §2.6).
@@ -101,25 +103,48 @@ def decode_chunk(params: dict, cfg: LlamaConfig, cache: KVCache,
     def write_kv(c, val):
         return jax.lax.dynamic_update_slice(c, val, (0, cache.offset, 0, 0))
 
-    logits, new_k, new_v = _run_blocks(
-        params, cfg, cache.k, cache.v, tokens, positions, kv_positions,
-        write_kv)
+    logits, (new_k, new_v) = _run_blocks(
+        params, cfg, tokens, positions, (cache.k, cache.v),
+        _cache_attend(write_kv, positions, kv_positions))
     new_cache = KVCache(k=new_k, v=new_v, positions=kv_positions,
                        offset=cache.offset + Tc)
     return logits, new_cache
 
 
-def _run_blocks(params, cfg, cache_k, cache_v, tokens, positions,
-                kv_positions, write_kv):
-    """Transformer trunk shared by the shared-offset ``decode_chunk``
-    and the per-slot-offset ``slot_decode_step``: embed, layer scan
-    (attention against the KV cache + FFN), final norm, lm head. The
-    two callers differ ONLY in how positions are assigned and how this
-    chunk's K/V lands in the cache (``write_kv``: contiguous
-    ``dynamic_update_slice`` at one shared offset vs a per-row scatter
-    at each slot's own offset) — the math here is identical, which is
-    what makes the continuous-batching engine bit-identical to
-    ``generate_fused``."""
+def _cache_attend(write_kv, positions, kv_positions):
+    """The ``attend`` of every caller whose cache rides the layer scan
+    (``decode_chunk``, ``slot_decode_step``, ``paged_prefill``): the
+    layer's ``(ck, cv)`` strips come in as the scanned value, this
+    chunk's K/V lands in them through ``write_kv`` (a contiguous
+    ``dynamic_update_slice`` at one shared offset, or a per-row
+    scatter at each slot's own), the chunk attends over the whole
+    strip under the position mask, and the written strips go out as
+    the layer's scan output."""
+    def attend(q, k, v, strips):
+        ck, cv = strips
+        ck = write_kv(ck, k)
+        cv = write_kv(cv, v)
+        attn = dot_product_attention(
+            q, ck, cv, causal=True,
+            positions_q=positions, positions_kv=kv_positions,
+        )
+        return attn, (ck, cv)
+    return attend
+
+
+def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
+    """Transformer trunk shared by every cached decode path: embed,
+    layer scan (attention against the KV cache + FFN), final norm, lm
+    head. The callers differ ONLY in how positions are assigned and in
+    ``attend(q, k, v, xs) -> (attn, ys)``, which lands this chunk's
+    K/V (B, Tc, KVH, hd) in the layer's cache and attends ``q``
+    (B, Tc, H, hd) over it. ``layer_xs`` is scanned beside the layer
+    weights and handed to ``attend`` a layer at a time: the cache
+    strips themselves for the callers of ``_cache_attend``, the
+    layer's index for ``paged_decode_step``, which reads the pool
+    through the block table. The math around it is identical, which
+    is what makes the continuous-batching engine bit-identical to
+    ``generate_fused``. Returns (logits, the stacked ``ys``)."""
     B, Tc = tokens.shape
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.dtype
@@ -152,7 +177,7 @@ def _run_blocks(params, cfg, cache_k, cache_v, tokens, positions,
             return proj("w_down", jax.nn.silu(gate) * up)
 
     def body(x, scanned):
-        layer, ck, cv = scanned
+        layer, xs = scanned
         proj = partial(lora_proj, layer, alpha=cfg.lora_alpha, dtype=cdt)
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = proj("wq", h).reshape(B, Tc, H, hd)
@@ -160,22 +185,16 @@ def _run_blocks(params, cfg, cache_k, cache_v, tokens, positions,
         v = proj("wv", h).reshape(B, Tc, KVH, hd)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
-        ck = write_kv(ck, k)
-        cv = write_kv(cv, v)
-        attn = dot_product_attention(
-            q, ck, cv, causal=True,
-            positions_q=positions, positions_kv=kv_positions,
-        )
+        attn, ys = attend(q, k, v, xs)
         x = x + proj("wo", attn.reshape(B, Tc, H * hd))
         x = x + ffn(layer, rms_norm(x, layer["mlp_norm"], cfg.norm_eps))
-        return x, (ck, cv)
+        return x, ys
 
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["blocks"], cache_k, cache_v))
+    x, ys = jax.lax.scan(body, x, (params["blocks"], layer_xs))
     x = rms_norm(x, params["out_norm"], cfg.norm_eps)
     logits = (x @ maybe_dequant(params["lm_head"], cdt)
               ).astype(jnp.float32)
-    return logits, new_k, new_v
+    return logits, ys
 
 
 def cache_shardings(cfg: LlamaConfig, mesh) -> KVCache:
@@ -703,9 +722,9 @@ def slot_decode_step(params, cfg, cache: SlotCache, tokens, active):
         # its own slot at its own offset
         return c.at[rows, cache.write_idx].set(val[:, 0])
 
-    logits, new_k, new_v = _run_blocks(
-        params, cfg, cache.k, cache.v, tokens[:, None], positions,
-        kv_positions, write_kv)
+    logits, (new_k, new_v) = _run_blocks(
+        params, cfg, tokens[:, None], positions, (cache.k, cache.v),
+        _cache_attend(write_kv, positions, kv_positions))
     inc = active.astype(jnp.int32)
     new_cache = SlotCache(k=new_k, v=new_v, positions=kv_positions,
                           write_idx=cache.write_idx + inc,
@@ -876,6 +895,7 @@ class ContinuousBatchingEngine:
         self._credits = {c: 0.0 for c in SLO_CLASSES}
         # counters surfaced by stats()
         self.decode_steps = 0
+        self.kv_blocks_read_total = 0
         self.prefills = 0
         self.occupancy_sum = 0
         self.admitted_total = 0
@@ -1466,6 +1486,13 @@ class ContinuousBatchingEngine:
         _jit_sentinel.note("engine.decode_step", tok_arr, act_arr)
         if self.paged:
             from kubeflow_rm_tpu.models import paging
+            # what the step touches of each live slot's table: the
+            # blocks up to the one this token lands in. The host knows
+            # every length (prompt + tokens picked so far, the one
+            # being fed among them) — no device sync
+            self.kv_blocks_read_total += sum(
+                -(-(len(r.prompt) + len(r.tokens)) // self.block_size)
+                for r in self._slot_req if r is not None)
             last, self.cache = paging.paged_decode_step(
                 self.params, self.cfg, self.cache, tok_arr, act_arr)
         else:
@@ -1524,4 +1551,7 @@ class ContinuousBatchingEngine:
             out["chain_installs"] = self.chain_installs
             out["chains_exported"] = self.chains_exported
             out["chains_adopted"] = self.chains_adopted
+            # against decode_steps x slots x (slot_len / block_size),
+            # the share of a whole-cache strip the steps still touch
+            out["kv_blocks_read_total"] = self.kv_blocks_read_total
         return out

@@ -32,14 +32,19 @@ one wide chunk under XLA (verified in ``tests/test_paging.py``). So
 per-request outputs stay bit-identical to solo ``generate_fused``,
 cached prefix or not.
 
-Layout notes (CPU/TPU-portable XLA, no custom kernel): the decode step
-gathers each slot's blocks into a contiguous (B, slot_len) view, runs
-the same ``_run_blocks`` trunk as the contiguous engine, and scatters
-back only the one written column. Two blocks are reserved: block 0 is
-NULL (all-``_UNFILLED`` positions, the gather target of unassigned
-table entries — never written) and block 1 is SINK (the redirect
-target for writes that must go nowhere: inactive rows' decode writes
-and install chunks that belong to shared blocks).
+Layout notes: the decode step runs the same ``_run_blocks`` trunk as
+the contiguous engine but never builds a strip of the whole cache.
+Each layer's attention reads that layer's blocks of the pool through
+the slots' block tables (``ops/paged_attention.py``: on a one-device
+TPU program a pallas kernel that copies only the blocks a live slot
+has filled, elsewhere one layer's gather and the position-masked XLA
+attention — the same values, the same math), the layer scan returns
+only this token's new K/V column, and one scatter lands it in the
+pool. Prefill still gathers its one request's strip. Two blocks are
+reserved: block 0 is NULL (all-``_UNFILLED`` positions, the gather
+target of unassigned table entries — never written) and block 1 is
+SINK (the redirect target for writes that must go nowhere: inactive
+rows' decode writes and install chunks that belong to shared blocks).
 """
 
 from __future__ import annotations
@@ -53,8 +58,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_rm_tpu.models.generate import _UNFILLED, _run_blocks
+from kubeflow_rm_tpu.models.generate import (
+    _UNFILLED, _cache_attend, _run_blocks,
+)
 from kubeflow_rm_tpu.models.llama import LlamaConfig
+from kubeflow_rm_tpu.ops.paged_attention import paged_decode_attention
 
 #: reserved block ids (see module docstring)
 NULL_BLOCK = 0
@@ -110,16 +118,22 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, slot_len: int,
 
 @partial(jax.jit, static_argnames=("cfg",), donate_argnames=("cache",))
 def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
-    """One decode step over every slot, against gathered block views.
+    """One decode step over every slot, the pool read in place.
 
     Mirrors ``slot_decode_step`` exactly: each active row attends at
-    its own ``pos_next`` over its gathered (slot_len-long) strip and
+    its own ``pos_next`` over its logical (slot_len-long) strip and
     writes K/V at its own ``write_idx``; inactive rows flow through
     with query position ``_UNFILLED`` and their (garbage) pool write
     redirected to SINK_BLOCK — their table may reference blocks that
     other slots now own, so unlike the contiguous engine their write
-    target is NOT private and must be diverted. Only the one written
-    column per row is scattered back to the pool.
+    target is NOT private and must be diverted.
+
+    The pool never rides the layer scan: the scan carries ``x``, scans
+    (layer weights, layer index) and each layer's attention reads that
+    layer's blocks of the pool through the block table
+    (``ops.paged_attention``), this token's K/V joining as the strip's
+    newest column. The scan's output is only that column, a layer
+    each; one scatter after the scan lands it in the pool.
     """
     B, MAXB = cache.block_tables.shape
     BS = cache.positions.shape[1]
@@ -132,26 +146,25 @@ def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
                     SINK_BLOCK)
     off = wi % BS
 
-    # gathered per-slot contiguous views: bit-identical to the strip a
-    # contiguous SlotCache would hold for the same request
-    gk = cache.k[:, cache.block_tables].reshape(
-        cache.k.shape[0], B, S, *cache.k.shape[3:])
-    gv = cache.v[:, cache.block_tables].reshape(
-        cache.v.shape[0], B, S, *cache.v.shape[3:])
+    # the strips' positions, this token's among them: what the mask of
+    # a contiguous SlotCache would hold for the same requests
     gpos = cache.positions[cache.block_tables].reshape(B, S)
     kv_positions = gpos.at[rows, wi].set(positions[:, 0])
 
-    def write_kv(c, val):
-        return c.at[rows, wi].set(val[:, 0])
+    def attend(q, k, v, layer):
+        attn = paged_decode_attention(
+            q[:, 0], k[:, 0], v[:, 0], cache.k, cache.v, layer,
+            cache.block_tables, wi, active,
+            positions_q=positions[:, 0], kv_positions=kv_positions)
+        return attn[:, None], (k[:, 0], v[:, 0])
 
-    logits, new_k, new_v = _run_blocks(
-        params, cfg, gk, gv, tokens[:, None], positions, kv_positions,
-        write_kv)
+    logits, (col_k, col_v) = _run_blocks(
+        params, cfg, tokens[:, None], positions,
+        jnp.arange(cache.k.shape[0], dtype=jnp.int32), attend)
 
-    # scatter ONLY the written column back to the pool (inactive rows
-    # land in SINK); duplicate sink hits are garbage-on-garbage
-    col_k = new_k[:, rows, wi]          # (L, B, KVH, hd)
-    col_v = new_v[:, rows, wi]
+    # scatter the written column, (L, B, KVH, hd), into the pool
+    # (inactive rows land in SINK); duplicate sink hits are
+    # garbage-on-garbage
     inc = active.astype(jnp.int32)
     new_cache = PagedKVCache(
         k=cache.k.at[:, blk, off].set(col_k),
@@ -210,8 +223,9 @@ def paged_prefill(params, cfg, cache: PagedKVCache,  # kfrm: disable=KFRM008
     def write_kv(c, val):
         return jax.lax.dynamic_update_slice(c, val, (0, n_hit, 0, 0))
 
-    logits, new_k, new_v = _run_blocks(
-        params, cfg, gk, gv, tokens, positions, kv_positions, write_kv)
+    logits, (new_k, new_v) = _run_blocks(
+        params, cfg, tokens, positions, (gk, gv),
+        _cache_attend(write_kv, positions, kv_positions))
     last = logits[0, n_real - 1, :]
     return last, new_k, new_v, kv_positions
 

@@ -54,6 +54,13 @@ def test_cpu_dry_run_drives_both_phases(capsys):
     # the rehearsal walked the pallas kernels (interpreted), and says so
     assert {"flash"} == {c["kernel"] for c in train["attention"]}
     assert all(c["interpret"] for c in train["attention"])
+    decode = [c for c in serve["attention"]
+              if c["kernel"].startswith("paged_decode")]
+    assert decode and all(c["kernel"] == "paged_decode"
+                          and c["interpret"] for c in decode)
+    # interpreted, the kernel lowers to plain HLO: no custom call here
+    assert serve["pallas_kernels_in_lowered_decode_step"] == 0
+    assert serve["kv_blocks_read_total"] > serve["decode_steps"]
 
 
 def test_a_failure_in_either_phase_exits_nonzero(monkeypatch, capsys):

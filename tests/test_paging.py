@@ -261,3 +261,52 @@ def test_evict_queued_returns_unadmitted_only(model):
     eng.run()                               # r1 still finishes here
     assert r1.done and not r2.done
     assert r1.tokens == _solo(params, cfg, [3, 5, 7], 4, slot_len=16)
+
+
+# -- the decode step's structure and its counter ---------------------------
+
+
+def test_decode_step_never_builds_a_strip_of_the_whole_cache():
+    """Compile ``paged_decode_step`` at eight layers and hold its
+    temporaries under three layers' worth of K and V strips. A step
+    that gathers every slot's whole strip for every layer ahead of the
+    scan (as it did until PR 31) holds all eight at least once — it
+    read eighteen layers' worth here; a step that reads the pool
+    through the block table holds one layer's. The pool is kept small
+    against the strips so that its own copies, which the CPU's scatter
+    makes, do not blur the line."""
+    from kubeflow_rm_tpu.models import paging
+
+    cfg = LlamaConfig.tiny(n_layers=8)
+    slots, slot_len, block = 4, 256, 4
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    cache = jax.eval_shape(lambda: paging.init_paged_cache(
+        cfg, slots, slot_len, RESERVED_BLOCKS + 8, block))
+    compiled = paging.paged_decode_step.lower(
+        params, cfg, cache, jax.ShapeDtypeStruct((slots,), jnp.int32),
+        jax.ShapeDtypeStruct((slots,), jnp.bool_)).compile()
+    one_layer = (2 * slots * slot_len * cfg.n_kv_heads * cfg.head_dim
+                 * jnp.dtype(cfg.dtype).itemsize)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * one_layer
+
+
+def test_kv_blocks_read_total_counts_what_the_lengths_say(model):
+    """Every decode step adds, for each live slot, the blocks its
+    strip reaches with the token being fed: ceil((prompt + tokens so
+    far) / block_size)."""
+    cfg, params = model
+    block = 4
+    eng = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=32,
+                                   block_size=block)
+    assert eng.stats()["kv_blocks_read_total"] == 0
+    budgets = {3: 6, 7: 2}                    # prompt length: new tokens
+    for n, new in budgets.items():
+        eng.submit(list(range(1, n + 1)), max_new_tokens=new)
+    eng.run()
+    # a request's last token is picked, never fed: new - 1 steps, the
+    # i-th of them with prompt + i tokens in the strip
+    want = sum(-(-(n + i) // block)
+               for n, new in budgets.items() for i in range(1, new))
+    st = eng.stats()
+    assert st["kv_blocks_read_total"] == want
+    assert st["decode_steps"] == max(budgets.values()) - 1

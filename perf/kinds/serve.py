@@ -19,7 +19,7 @@ import time
 
 import numpy as np
 
-from perf import flops, harness, reference, traffic_gen
+from perf import flops, harness, reference, stamps, traffic_gen
 
 
 def llama_config(config: dict):
@@ -40,35 +40,6 @@ def llama_config(config: dict):
         param_dtype=jnp.dtype(prec["params"]))
 
 
-class AdmitPoller(threading.Thread):
-    """Samples the engine's ``admitted_total`` (a few hundred times a
-    second, traced runs only) so that the time a request waited in the
-    gateway's and the engine's queues can be read from outside: the
-    n-th request submitted is seated when the count passes n."""
-
-    def __init__(self, engine, every_s=0.002):
-        super().__init__(daemon=True)
-        self.engine, self.every_s = engine, every_s
-        self.samples = []          # (time, admitted_total) at each change
-        self._halt = threading.Event()
-
-    def run(self):
-        last = None
-        while not self._halt.is_set():
-            n = self.engine.admitted_total
-            if n != last:
-                self.samples.append((time.perf_counter(), n))
-                last = n
-            self._halt.wait(self.every_s)
-
-    def stop(self):
-        self._halt.set()
-        self.join()
-        # the engine's weights and cache have to go before the
-        # reference runs: hold nothing of it
-        self.engine = None
-
-
 def _counters(engine) -> dict:
     s = engine.stats()
     return {"decode_steps": s["decode_steps"], "prefills": s["prefills"],
@@ -82,10 +53,12 @@ def _counters(engine) -> dict:
 def _offer(fleet, tenant, requests, t0, timeout_s, annotate):
     """Send each request at ``t0 + due_s`` on a thread of its own;
     return the records and the threads. A record's ``done_s`` is set
-    only where the whole answer came back."""
+    only where the whole answer came back, and its ``timeline`` is
+    then the engine's stamps of that answer (``perf/stamps.py``)."""
     records = [{"due_s": r["due_s"], "prompt_len": len(r["prompt"]),
                 "max_new_tokens": r["max_new_tokens"], "tokens": None,
-                "error": None, "done_s": None, "sent_s": None}
+                "error": None, "done_s": None, "sent_s": None,
+                "timeline": None}
                for r in requests]
 
     def client(i):
@@ -104,6 +77,7 @@ def _offer(fleet, tenant, requests, t0, timeout_s, annotate):
                                 f"{req['max_new_tokens']} tokens")
             else:
                 rec["tokens"] = [int(t) for t in tokens]
+                rec["timeline"] = info.get("timeline")
                 rec["done_s"] = time.perf_counter() - t0
         except Exception as e:          # counted as failed, and shown
             rec["error"] = repr(e)
@@ -162,7 +136,6 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
         bad = [r["error"] for r in records if r["done_s"] is None]
         if bad:
             raise RuntimeError(f"warm-up requests failed: {bad[:3]}")
-        poller = AdmitPoller(engine) if args.trace else None
         tracer = harness.TraceSlice() if args.trace else None
         gc.collect()
 
@@ -172,8 +145,6 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
 
         # ---- the window ----------------------------------------------
         compiled_before = clock.programs
-        if poller:
-            poller.start()
         t0 = time.perf_counter()
         setup_s = t0 - t_start
         timer = None
@@ -190,14 +161,11 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
         compiles = clock.programs - compiled_before
         if timer:
             timer.join()
-        if poller:
-            poller.stop()
         memory_peak = harness.memory_peak(devices)
     finally:
         fleet.close()
 
     # ---- free the program, then the reference reads a sample ---------
-    admitted_base = before["admitted_total"]
     del fleet, engine, make
     gc.collect()
     done = [r for r in records if r["done_s"] is not None]
@@ -211,12 +179,17 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
 
     # ---- metrics -----------------------------------------------------
     lat_ms = [1e3 * (r["done_s"] - r["due_s"]) for r in done]
-    in_window = [r for r in done if r["done_s"] <= window_s]
+    stamped = stamps.in_window(records, t0, window_s)
+    for why in stamped["malformed"]:
+        print(f"malformed timeline: {why}", file=sys.stderr)
+    # a whole answer whose stamps cannot place its tokens fails the run
+    compared["malformed_timelines"] = {
+        "value": len(stamped["malformed"]), "limit": 0}
     counters = {k: after[k] - before[k] for k in after}
     counters["slots"] = sv["slots"]
     metrics = {
         "req_latency_p95_ms": traffic_gen.percentile(lat_ms, 0.95),
-        "serve_tok_s": sum(len(r["tokens"]) for r in in_window) / window_s,
+        "serve_tok_s": stamped["tokens"] / window_s,
         "setup_s": setup_s,
     }
     t_reduce = time.perf_counter()
@@ -225,27 +198,38 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
         run_ctx = {
             "cell": cell, "dims": d, "window_s": window_s,
             "counters": counters, "requests": records,
-            "in_window": in_window, "trace": trace,
+            "stamped": stamped, "trace": trace,
             "peaks": None if dry else flops.peaks(devices[0].device_kind),
-            "admits": poller.samples, "admitted_base": admitted_base,
             "t0": t0,
         }
         metrics.update(harness.read_per_layer(cell, run_ctx))
         info["notes"] = run_ctx["notes"]
     late = [r["sent_s"] - r["due_s"] for r in records
             if r["sent_s"] is not None]
+    felt = stamps.waits(records, t0)
+    in_window = [r for r in done if r["done_s"] <= window_s]
     info.update({
         "trace_reduce_s": time.perf_counter() - t_reduce,
-        "requests_due": len(records), "completed_in_window": len(in_window),
+        "requests_due": len(records),
+        "tokens_stamped_in_window": stamped["tokens"],
+        "answers_with_tokens_in_window": len(stamped["spans"]),
+        # the reading before PR 34, whole answers that ended before the
+        # close, to be followed beside the stamped count
+        "completed_in_window": len(in_window),
+        "tokens_completed_in_window": sum(len(r["tokens"])
+                                          for r in in_window),
+        "done_near_close_s": sorted(round(r["done_s"] - window_s, 2)
+                                    for r in done
+                                    if abs(r["done_s"] - window_s) < 2.0),
+        # what a streaming tenant would feel (p50, p95, samples): in
+        # info, not metrics, until a benchmark PR has seen their spread
+        "ttft_ms": _felt(felt["ttft_ms"], dry),
+        "itl_ms": _felt(felt["itl_ms"], dry),
         "generator_late_ms_max": 1e3 * max(late) if late else None,
         "decode_steps": counters["decode_steps"],
         "prefills": counters["prefills"],
         "latency_p50_ms": traffic_gen.percentile(lat_ms, 0.5),
-        # answers within 2 s of the close: serve_tok_s steps by one
-        # request where one of them changes sides from run to run
-        "done_near_close_s": sorted(round(r["done_s"] - window_s, 2)
-                                    for r in done
-                                    if abs(r["done_s"] - window_s) < 2.0),
+        "latency_tail_ms": stamps.latency_tail(records, t0),
         "compile_programs_total": clock.programs,
         "compile_s_total": clock.seconds,
     })
@@ -253,6 +237,12 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
             "attempted": len(records), "failed": failed,
             "metrics": metrics, "memory_peak_bytes": memory_peak,
             "trace": trace, "info": info}
+
+
+def _felt(values_ms, dry):
+    """p50, p95 and the sample count; the rehearsal keeps the count."""
+    s = stamps.summary(values_ms)
+    return {"p50": None, "p95": None, "n": s["n"]} if dry else s
 
 
 def _window(fleet, engine, tenant, requests, t0, seconds, timeout_s,
@@ -295,18 +285,27 @@ def _sweep(fleet, engine, tenant, sweep, args, timeout_s, annotate, clock):
     rows = []
     for rate, requests in sweep:
         programs = clock.programs
+        t0 = time.perf_counter()
         records, before, after, window_s = _window(
-            fleet, engine, tenant, requests, time.perf_counter(),
-            args.seconds, timeout_s, annotate)
+            fleet, engine, tenant, requests, t0, args.seconds, timeout_s,
+            annotate)
         done = [r for r in records if r["done_s"] is not None]
         lat = [1e3 * (r["done_s"] - r["due_s"]) for r in done]
         inside = [r for r in done if r["done_s"] <= window_s]
         steps = after["decode_steps"] - before["decode_steps"]
+        stamped = stamps.in_window(records, t0, window_s)
+        felt = stamps.waits(records, t0)
         rows.append({
             "rate_per_s": rate, "due": len(records),
             "failed": len(records) - len(done),
+            "malformed": len(stamped["malformed"]),
+            "serve_tok_s": stamped["tokens"] / window_s,
             "done_in_window": len(inside),
-            "serve_tok_s": sum(len(r["tokens"]) for r in inside) / window_s,
+            "whole_answers_tok_s": (sum(len(r["tokens"]) for r in inside)
+                                    / window_s),
+            "ttft_ms": stamps.summary(felt["ttft_ms"]),
+            "itl_ms": stamps.summary(felt["itl_ms"]),
+            "queue_ms": stamps.summary(felt["queue_ms"]),
             "latency_p50_ms": traffic_gen.percentile(lat, 0.5),
             "latency_p95_ms": traffic_gen.percentile(lat, 0.95),
             "last_done_s": max((r["done_s"] for r in done), default=None),

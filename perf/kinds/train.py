@@ -5,15 +5,20 @@ running in the loop.
 Grown from ``chip_smoke.py``'s ``train_phase`` (copied, not imported).
 One ``fit()`` call does everything: the generator that feeds it is the
 run's clock. Its first batches are the steps the reference follows
-and the warm-up; then it marks the window's start, feeds batches for
-``--seconds`` while keeping at most ``in_flight`` steps ahead of the
-device (it waits for step ``i - in_flight`` before it hands out batch
-``i``, which is also how each step's end is clocked), and stops; fit()
-ends on the exhausted stream. The step that fit() builds is wrapped,
-from here, by a span that keeps each step's metrics (on the device,
-unread) and, after the first and the third step, per-leaf norms of
-Adam's first moment and of the parameters' change: what the
-comparison with the reference needs of the very object the window
+and the warm-up; then it marks the window's start and feeds batches
+for ``--seconds`` while keeping ``in_flight`` steps ahead of the
+device: it waits for step ``i - in_flight`` before it hands out batch
+``i``, so that a host that stands still for some seconds finds the
+chip fed when it comes back. When the time is up it hands out nothing
+more, waits for every step it sent and reads the clock after that
+wait: ``train_tok_s`` is all of that work over all of that time, so
+nothing unfinished is counted and a stall that runs to the window's
+end still counts as time. fit() ends on the exhausted stream. The
+step that fit() builds is wrapped, from here, by a span that keeps
+each step's metrics (on the device, unread) and, after the first and
+the third step, per-leaf norms of Adam's first moment and of the
+parameters' change: what the comparison with the reference needs of
+the very object the window
 then drives.
 """
 
@@ -29,6 +34,10 @@ import numpy as np
 
 from perf import flops, harness, reference, traffic_gen
 from perf.kinds.serve import llama_config
+
+
+#: a wait for a step's end that returns this soon found it ended
+LATE_S = 1e-3
 
 
 def _leaf_names(tree) -> list[str]:
@@ -126,21 +135,33 @@ class Feed:
         self.tokens = {}            # step -> its non-padding tokens
         self.pairs = {}             # step -> (query, key) pairs a row
         self.t0 = None
+        self.t_closed = None        # the time was found up: no more sent
+        self.t_end = None           # read once every step sent has ended
         self.first_window_step = None
+        self.last_window_step = None
         self.compiles_at_t0 = None
-        self.traced = None          # (first, last + 1) batch of the slice
+        # the slice opens once the first of these steps has ended and
+        # closes once the second has
+        self.traced = None
+        # waits of the window that found their step already ended, the
+        # longest run of them: the host came late that many steps running
+        self.behind = self.behind_max = 0
 
     def _wait(self, step):
         """Block until ``step`` has ended; note when."""
         import jax
         if step >= 1 and step not in self.done_at:
+            t = time.perf_counter()
             jax.block_until_ready(self.span.metrics[step - 1]["loss"])
             self.done_at[step] = time.perf_counter()
+            late = self.done_at[step] - t < LATE_S
+            self.behind = self.behind + 1 if late else 0
+            self.behind_max = max(self.behind_max, self.behind)
 
     def __call__(self, clock):
         mix = self.mix
         warm, ahead = int(mix["warmup_steps"]), int(mix["in_flight"])
-        trace_from = trace_to = None
+        opens = closes = None       # the slice, by the last step ended
         i = 0                       # the step this batch is for, from 1
         while True:
             i += 1
@@ -155,17 +176,22 @@ class Feed:
                 self.t0 = self.done_at[warm]
                 self.first_window_step = i
                 self.compiles_at_t0 = clock.programs
+                self.behind = self.behind_max = 0
                 if self.tracer:
-                    trace_from = i + int(mix["trace"]["after_steps"])
-                    trace_to = trace_from + int(mix["trace"]["steps"])
-                    self.traced = (trace_from, trace_to)
+                    # by steps ended, past the filling of the queue:
+                    # the slice is of the steady state whatever
+                    # in_flight is
+                    opens = warm + max(1, int(mix["trace"]["after_steps"]))
+                    closes = opens + int(mix["trace"]["steps"])
+                    self.traced = (opens, closes)
             if self.t0 is not None and now - self.t0 >= self.seconds:
+                self.t_closed = now
                 break
-            if self.tracer and i == trace_from:
+            if self.tracer and i - ahead == opens:
                 self.tracer.start()
-            if self.tracer and i == trace_to:
+            if self.tracer and i - ahead == closes:
                 # steps up to i - ahead have ended: the slice holds
-                # whole steps and the start of two more
+                # whole steps and the start of one more
                 self.tracer.stop()
             with self.annotate("perf.make_batch"):
                 batch = next(self.batches)
@@ -176,6 +202,12 @@ class Feed:
             yield batch
         if self.tracer:
             self.tracer.stop()
+        # the window closes: nothing more is sent, every step sent is
+        # waited for, in its order, and the clock is read after the last
+        self.last_window_step = i - 1
+        for step in range(max(1, i - ahead), i):
+            self._wait(step)
+        self.t_end = self.done_at[self.last_window_step]
 
 
 def run(*, cell, args, devices, clock, t_start, dry) -> dict:
@@ -222,23 +254,21 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
     memory_peak = harness.memory_peak(devices)
 
     # ---- what the window did -------------------------------------------
+    # every step handed out before the time was up, over window start ->
+    # the clock read once the last of them had ended
     steps_run = len(span.metrics)
-    deadline = feed.t0 + args.seconds
-    counted = [s for s in range(feed.first_window_step, steps_run + 1)
-               if s in feed.done_at and feed.done_at[s] <= deadline]
-    # a step handed out before the window closed that ends after it
-    # is late, not failed; one whose loss is not finite has failed
-    attempted = steps_run - feed.first_window_step + 1
+    counted = list(range(feed.first_window_step, feed.last_window_step + 1))
+    attempted = len(counted)
     tokens = sum(feed.tokens[s] for s in counted)
-    span_s = (feed.done_at[counted[-1]] - feed.t0) if counted else math.nan
+    span_s = feed.t_end - feed.t0
     train_tok_s = tokens / span_s if counted else math.nan
     setup_s = feed.t0 - t_start
-    # the slice opens once step traced[0] - in_flight has ended and
-    # closes once step traced[1] - in_flight has: between the step it
-    # cuts at its start and the one it cuts at its end lie these
     ahead = int(mix["in_flight"])
-    whole = [feed.pairs[s] for s in range(feed.traced[0] - ahead + 2,
-                                          feed.traced[1] - ahead + 1)
+    # the slice opens once step traced[0] has ended and closes once
+    # step traced[1] has: between the step it cuts at its start and
+    # the one it cuts at its end lie these
+    whole = [feed.pairs[s] for s in range(feed.traced[0] + 2,
+                                          feed.traced[1] + 1)
              if s in feed.pairs] if feed.traced else []
     losses = [float(x) for x in jax.device_get(
         [m["loss"] for m in span.metrics])]
@@ -258,6 +288,8 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
     ends = [feed.t0] + [feed.done_at[s] for s in counted]
     step_ms_max = 1e3 * max(np.diff(ends)) if counted else None
     batches, tokens_a_step = feed.kept, feed.tokens.get(1)
+    behind_max, window_s = feed.behind_max, feed.t_end - feed.t0
+    drain_s = feed.t_end - feed.t_closed
     del state, span, feed, _history
     gc.collect()
     compared, info = _check(program, batches, config, mix, d, args)
@@ -279,6 +311,11 @@ def run(*, cell, args, devices, clock, t_start, dry) -> dict:
                  "tokens_a_step": tokens_a_step,
                  "step_ms": 1e3 * span_s / len(counted) if counted else None,
                  "step_ms_max": step_ms_max,
+                 "window_s": window_s,
+                 "drain_s": drain_s,
+                 "in_flight": ahead,
+                 # in_flight of them in a row: the chip ran dry
+                 "host_behind_steps_max": behind_max,
                  "last_loss": losses[-1],
                  "compile_programs_total": clock.programs,
                  "compile_s_total": clock.seconds})

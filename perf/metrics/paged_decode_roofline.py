@@ -1,4 +1,4 @@
-from perf import flops, trace_reduce
+from perf import flops, stamps, trace_reduce
 
 
 def read(run, params):
@@ -11,12 +11,10 @@ def read(run, params):
     if not runs:
         return None
     step_s = sum(e[2] for e in runs) / len(runs) / 1e9
-    # positions attended, summed over the decode steps of the requests
-    # the window finished, a step: token j of an answer attends the
-    # prompt and the j before it
-    attended = sum(n * r["prompt_len"] + n * (n - 1) / 2.0
-                   for r in run["in_window"]
-                   for n in [len(r["tokens"])])
+    # positions attended by the decode tokens stamped inside the
+    # window (answers in flight at the close among them), over the
+    # window's decode steps: the positions a step's live slots attend
+    attended, _tokens = stamps.positions_attended(run["stamped"]["spans"])
     live = attended / c["decode_steps"]
     least_s = (flops.decode_step_bytes(run["dims"], live)
                / run["peaks"]["hbm_bytes_per_s"])

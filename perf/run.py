@@ -124,8 +124,10 @@ def main(argv=None) -> int:
         device.pop("busy_s", None)
         device.pop("window_s", None)
         line.pop("breakdown", None)
+        # (a dict under such a name is kept: its kind left only counts)
         out["info"] = {k: v for k, v in out["info"].items()
-                       if not k.endswith(("_ms", "_s", "_ms_max"))}
+                       if isinstance(v, dict)
+                       or not k.endswith(("_ms", "_s", "_ms_max"))}
     line["info"] = out["info"]
     line["compared"] = compared
     print(json.dumps(line), flush=True)

@@ -69,6 +69,22 @@ def serve_requests(mix: dict, seed: int, seconds: float,
             for t, p, a in zip(due, prompts, answers)]
 
 
+def in_flight_at_close(requests: list[dict], seconds: float,
+                       close: dict) -> list[dict]:
+    """The requests whose answers a window of ``seconds`` would cut,
+    by the mix's own plain rule (its ``close`` block): an answer's
+    first token comes ``first_token_s`` after it is due and each
+    further one ``ms_per_token`` later. A token count rides on when
+    each such answer was let in, so a mix that is to hold
+    ``serve_tok_s`` steady states how many its order leaves
+    (``in_flight_max``) and a test holds it to that. A shorter step
+    can only take answers out of this list; a longer one, another rate
+    or another order has to be looked at again."""
+    return [r for r in requests
+            if r["due_s"] + close["first_token_s"]
+            + r["max_new_tokens"] * close["ms_per_token"] / 1e3 > seconds]
+
+
 def warmup_requests(mix: dict, prompt_lens, slots: int, vocab: int,
                     bucket) -> list[dict]:
     """Requests that touch every program the window's requests reach

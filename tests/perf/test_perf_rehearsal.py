@@ -44,6 +44,16 @@ def test_serve_cell_rehearsal(capsys):
                                     "setup_s"}
     assert line["compared"]["compiles_in_window"] == {"value": 0, "limit": 0}
     assert line["info"]["tokens_compared"] > 0
+    assert line["compared"]["malformed_timelines"] == {"value": 0,
+                                                       "limit": 0}
+    # the stamps' readings ride in info: counts here, never a time
+    info = line["info"]
+    assert info["ttft_ms"] == {"p50": None, "p95": None,
+                               "n": line["attempted"]}
+    assert info["itl_ms"]["n"] > 0 and info["itl_ms"]["p95"] is None
+    assert 0 <= info["completed_in_window"] <= line["attempted"]
+    assert info["tokens_stamped_in_window"] >= info[
+        "tokens_completed_in_window"] > 0
     _no_time_rate_or_share(line)
 
 
@@ -52,7 +62,8 @@ def test_serve_cell_rehearsal_traced(capsys):
     assert line["correct"] is True
     # counters read on the CPU too; what needs a device trace or a
     # peak finds nothing to read and is left out, never reported as 0
-    assert {"batch_occupancy", "decode_step_ms"} <= set(line["metrics"])
+    assert {"batch_occupancy", "decode_step_ms",
+            "gateway_queue_ms_p95"} <= set(line["metrics"])
     assert not {"paged_decode_roofline", "serve_mfu_pct",
                 "device_idle_pct.serve"} & set(line["metrics"])
     _no_time_rate_or_share(line)
@@ -66,7 +77,8 @@ def test_train_cell_rehearsal(capsys):
     assert set(line["compared"]) == {"loss_gap", "first_grad_norm_gap",
                                      "param_change_gap",
                                      "compiles_in_window"}
-    assert line["info"]["steps_in_window"] > 0
+    # every step sent is waited for and counted
+    assert line["info"]["steps_in_window"] == line["attempted"]
     _no_time_rate_or_share(line)
 
 
@@ -85,6 +97,45 @@ def test_serve_fault_token_altered_where_it_is_produced(capsys):
     assert line["correct"] is False
     gap = line["compared"]["served_token_gap"]
     assert gap["value"] > gap["limit"]
+
+
+def _stamp_missing(tl):
+    tl["t_tokens"] = tl["t_tokens"][:-1]
+
+
+def _stamps_out_of_order(tl):
+    tl["t_tokens"] = tl["t_tokens"][::-1]
+
+
+def _stamps_after_the_return(tl):
+    # a stamp moved to where the client cannot have had the token yet
+    tl["t_tokens"] = [t + 3600.0 for t in tl["t_tokens"]]
+
+
+@pytest.mark.parametrize("spoil", [_stamp_missing, _stamps_out_of_order,
+                                   _stamps_after_the_return])
+def test_serve_fault_a_returned_answer_with_bad_stamps_is_malformed(
+        capsys, spoil):
+    """Every token came back and the served tokens are right, but one
+    answer in five cannot say when its tokens were made, or says a
+    time outside the client's own call: the run is malformed, not a
+    smaller ``serve_tok_s``."""
+    generate = importlib.import_module("kubeflow_rm_tpu.models.generate")
+    real = generate.EngineRequest.timeline
+
+    def timeline(self):
+        tl = real(self)
+        if self.rid % 5 == 0 and len(tl["t_tokens"]) > 1:
+            spoil(tl)
+        return tl
+
+    with mock.patch.object(generate.EngineRequest, "timeline", timeline):
+        line = _run(capsys, "serve-chat-steady", "--trace", "0")
+    assert line["correct"] is False and line["failed"] == 0
+    bad = line["compared"]["malformed_timelines"]
+    assert bad["value"] > 0 and bad["limit"] == 0
+    gap = line["compared"]["served_token_gap"]
+    assert gap["value"] <= gap["limit"]
 
 
 def test_train_fault_state_returned_unchanged(capsys):
@@ -126,20 +177,3 @@ def test_without_an_accelerator_there_is_no_result(capsys):
     out = capsys.readouterr()
     assert rc != 0 and out.out.strip() == ""
     assert "needs 1 tpu" in out.err
-
-
-def test_the_admit_poller_lets_go_of_the_engine():
-    """A traced serving run polls the engine from a thread; once it is
-    stopped it must hold nothing of the engine, or the weights and the
-    cache stay on the chip while the reference needs the room (three
-    traced runs ran out of memory there, my chip run, PR 29)."""
-    from perf.kinds.serve import AdmitPoller
-
-    class Engine:
-        admitted_total = 3
-
-    poller = AdmitPoller(Engine(), every_s=0.001)
-    poller.start()
-    poller.stop()
-    assert not poller.is_alive() and poller.engine is None
-    assert [n for _t, n in poller.samples] == [3]

@@ -2,6 +2,7 @@
 seed the same work with other token ids."""
 
 import json
+import re
 import sys
 from pathlib import Path
 
@@ -15,6 +16,7 @@ from perf import traffic_gen as tg  # noqa: E402
 
 CHAT = json.loads((ROOT / "perf/traffic/chat-steady.json").read_text())
 PACKED = json.loads((ROOT / "perf/traffic/packed-4k.json").read_text())
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 BIG = 2 ** 31 + 12345            # the driver's seeds are large
 
 
@@ -81,9 +83,46 @@ def test_sizes_keep_the_sources_mean(mix, key):
     prints; the sizes a window gets keep it within 3 %, clipping and
     rounding included."""
     spec = mix[key]
-    sizes = tg.quantile_sizes(spec, 66 if mix is CHAT else PACKED["doc_pool"])
+    sizes = tg.quantile_sizes(
+        spec, round(CHAT["arrivals"]["rate_per_s"] * BENCH["run_seconds"])
+        if mix is CHAT else PACKED["doc_pool"])
     assert abs(sizes.mean() - spec["source_mean"]) <= 0.03 * spec["source_mean"]
     assert mix["sources"]["lengths"]
+
+
+def test_the_cell_states_the_rate_its_mix_offers():
+    """The rate is the mix file's; the cell's ``why`` states the same
+    number. Nothing else of either text is held here: the next sweep
+    changes data, not this test."""
+    why = next(w["why"] for w in BENCH["workloads"]
+               if w["traffic"] == "chat-steady")
+    stated = re.search(r"(\d+(?:\.\d+)?) requests/s", why)
+    assert stated, why
+    assert float(stated.group(1)) == CHAT["arrivals"]["rate_per_s"]
+
+
+def test_answers_in_flight_at_the_close_by_the_plain_rule():
+    close = {"first_token_s": 0.5, "ms_per_token": 100.0}
+    requests = [
+        {"due_s": 1.0, "max_new_tokens": 80},    # ends at 9.5: inside
+        {"due_s": 1.0, "max_new_tokens": 90},    # 10.5: cut by the close
+        {"due_s": 9.0, "max_new_tokens": 5},     # exactly 10.0: inside
+        {"due_s": 9.8, "max_new_tokens": 1}]     # its first token is late
+    assert tg.in_flight_at_close(requests, 10.0, close) == [requests[1],
+                                                            requests[3]]
+    # a shorter step only takes answers out
+    faster = dict(close, ms_per_token=50.0)
+    assert tg.in_flight_at_close(requests, 10.0, faster) == [requests[3]]
+
+
+def test_the_mix_s_order_leaves_a_quiet_close():
+    """``serve_tok_s`` rides on when each answer in flight at the close
+    was let in: the mix states how many its order leaves by its own
+    plain rule, and the schedule the cell runs keeps to it."""
+    close = CHAT["close"]
+    requests = tg.serve_requests(CHAT, 1, float(BENCH["run_seconds"]), 32000)
+    cut = tg.in_flight_at_close(requests, float(BENCH["run_seconds"]), close)
+    assert len(cut) <= close["in_flight_max"]
 
 
 @pytest.mark.parametrize("spec,error", [

@@ -342,6 +342,10 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
         "prefills": stats["prefills"],
         "decode_steps": stats["decode_steps"],
         "kv_blocks_read_total": stats["kv_blocks_read_total"],
+        # a pick program and a blocking transfer a token boundary,
+        # whatever the live slots (PR 37; occupancy_sum before it)
+        "host_syncs_total": stats["host_syncs_total"],
+        "pick_programs_total": stats["pick_programs_total"],
         "pallas_kernels_in_lowered_decode_step": kernels,
         "batch_occupancy": round(stats["batch_occupancy"], 3),
         "prefix_hit_tokens": stats["prefix_hit_tokens"],

@@ -734,11 +734,18 @@ def slot_decode_step(params, cfg, cache: SlotCache, tokens, active):
 
 @partial(jax.jit, static_argnames=("temperature", "top_k"))
 def _pick_row(last, key, *, temperature, top_k):
-    """Jitted single-row ``_pick`` — the engine samples per slot (each
-    request has its own PRNG stream) but through the same sampling
-    source as both batch decode paths."""
+    """Jitted ``_pick`` through the same sampling source as both batch
+    decode paths: a sampling request's ``(V,)`` row under its own PRNG
+    stream, or the engine's ``(slots, V)`` array, greedy, whole."""
     return _pick(last[None, :], key, temperature=temperature,
                  top_k=top_k)[0]
+
+
+@partial(jax.jit, donate_argnames=("last",))
+def _seat_row(last, row, i):
+    """A seated request's logits ``row`` into slot ``i`` of the
+    engine's ``(slots, V)`` array; ``i`` is traced: one program."""
+    return last.at[i].set(row)
 
 
 def _bucket_len(n: int) -> int:
@@ -811,11 +818,12 @@ class ContinuousBatchingEngine:
     """Slot-based continuous-batching decode engine.
 
     ``submit`` queues a request into its SLO class; ``step`` admits
-    queued requests into free slots (one prefill each), runs ONE
-    decode step for all live slots, samples each slot's next token
-    host-side, and retires slots that hit eos or their token budget —
-    so short requests leave (and new ones enter) mid-flight instead of
-    waiting for the longest neighbour.
+    queued requests into free slots (one prefill each), picks every
+    live slot's next token (one program over the ``(slots, V)`` logits
+    and one device-to-host transfer, whatever the live slots), retires
+    slots that hit eos or their token budget and runs ONE decode step
+    for the rest — so short requests leave (and new ones enter)
+    mid-flight instead of waiting for the longest neighbour.
 
     Two cache arms:
 
@@ -859,7 +867,8 @@ class ContinuousBatchingEngine:
         # params were committed to its own chip must not allocate its
         # pool on the default device (every replica of a fleet on
         # device 0)
-        home = jax.tree_util.tree_leaves(self.params)[0].devices()
+        leaf = jax.tree_util.tree_leaves(self.params)[0]
+        home = leaf.devices()
         place = (jax.default_device(next(iter(home))) if len(home) == 1
                  else contextlib.nullcontext())
         if paged:
@@ -876,18 +885,22 @@ class ContinuousBatchingEngine:
                               + max(maxb, (slots * maxb) // 2))
             self.pool = paging.BlockPool(num_blocks, block_size)
             self.prefix_cache = prefix_cache
-            with place:
-                self.cache = paging.init_paged_cache(
-                    cfg, slots, slot_len, num_blocks, block_size)
         else:
             self.block_size = None
             self.pool = None
             self.prefix_cache = False
-            with place:
-                self.cache = init_slot_cache(cfg, slots, slot_len)
+        with place:
+            self.cache = (paging.init_paged_cache(
+                cfg, slots, slot_len, num_blocks, block_size) if paged
+                else init_slot_cache(cfg, slots, slot_len))
+            # every slot's last logits (a step replaces them, admission
+            # writes a row), committed where a step's output will be
+            self._last = jax.device_put(
+                np.zeros((slots, cfg.vocab_size), np.float32),
+                next(iter(home)) if leaf.committed and len(home) == 1
+                else None)
         self._slot_req: list[EngineRequest | None] = [None] * slots
         self._slot_blocks: list[list | None] = [None] * slots
-        self._last = [None] * slots   # (V,) logits per live slot
         self._queues = {c: [] for c in SLO_CLASSES}
         self.class_weights = dict(DEFAULT_CLASS_WEIGHTS)
         if class_weights:
@@ -895,6 +908,8 @@ class ContinuousBatchingEngine:
         self._credits = {c: 0.0 for c in SLO_CLASSES}
         # counters surfaced by stats()
         self.decode_steps = 0
+        self.host_syncs_total = 0      # blocking transfers in _pick
+        self.pick_programs_total = 0   # programs _pick dispatched
         self.kv_blocks_read_total = 0
         self.prefills = 0
         self.occupancy_sum = 0
@@ -1097,25 +1112,22 @@ class ContinuousBatchingEngine:
                     # full local hit: adopt the cached blocks instead
                     # of seating duplicate chunks from the payload
                     req.chain = None
-            if self.paged and req.chain is not None:
-                last = self._admit_chain(i, req)
-                if last is None:
-                    self._requeue_front(req)
-                    return
-            elif self.paged:
-                last = self._admit_paged(i, req)
-                if last is None:
-                    # transient block OOM: head waits at the front of
-                    # its class queue; blocks free as slots retire (or
-                    # as retained prefix blocks get evicted), so this
-                    # always makes progress eventually
-                    self._requeue_front(req)
-                    return
-                self.prefills += 1
-            else:
+            if not self.paged:
                 last = self._admit_contiguous(i, req)
+            elif req.chain is not None:
+                last = self._admit_chain(i, req)
+            else:
+                last = self._admit_paged(i, req)
+            if last is None:
+                # transient block OOM: head waits at the front of its
+                # class queue; blocks free as slots retire (or as
+                # retained prefix blocks get evicted), so this always
+                # makes progress eventually
+                self._requeue_front(req)
+                return
+            if req.chain is None:
                 self.prefills += 1
-            self._last[i] = last
+            self._last = _seat_row(self._last, last, i)
             self._slot_req[i] = req
             req.admitted_step = self.decode_steps
             req.t_admitted = t_taken
@@ -1167,10 +1179,12 @@ class ContinuousBatchingEngine:
             jnp.asarray(Tp, jnp.int32))
         return logits[0, -1, :]
 
-    def _admit_paged(self, i: int, req: EngineRequest):
-        """Plan blocks, prefill the un-cached suffix, install. Returns
-        the last real token's logits row, or ``None`` on transient
-        block OOM (pool state untouched — clean rejection).
+    def _prefill_suffix(self, prompt, keys, needed: int):
+        """Plan ``needed`` blocks for ``prompt`` and prefill what the
+        pool does not hold of it. ``None`` on transient block OOM (pool
+        state untouched), else ``(n_hit, shared, fresh, final_row,
+        fork_src, prefill)``: the caller installs ``prefill`` (last real
+        token's logits row, K, V, positions), then unpins ``fork_src``.
 
         Plan: the longest consecutive cached chain covers ``n_hit``
         prompt tokens (clamped to Tp-1: the last prompt token is
@@ -1185,9 +1199,7 @@ class ContinuousBatchingEngine:
 
         pool, BS = self.pool, self.block_size
         maxb = self.slot_len // BS
-        Tp, budget = len(req.prompt), req.max_new_tokens
-        keys = (paging.prefix_keys(req.prompt, BS)
-                if self.prefix_cache else [])
+        Tp = len(prompt)
         chain = pool.lookup_chain(keys)
         n_hit = min(keys[len(chain) - 1][0] if chain else 0, Tp - 1)
         # fit: cached tokens + the suffix's padding bucket must fit
@@ -1198,58 +1210,73 @@ class ContinuousBatchingEngine:
         shared_full = n_hit // BS
         fork = n_hit % BS != 0
         shared = chain[:shared_full]
-        needed = -(-(Tp + budget) // BS)
-        owned_n = needed - shared_full
-
         # pin sources before alloc: alloc may EVICT ref-0 retained
         # blocks, and evicting a block we are about to read from (or
         # re-handing it out as our own fresh block) would corrupt the
         # copy. On OOM the pins roll back — no torn state.
         pins = chain[:shared_full + 1] if fork else shared
         pool.incref(pins)
-        fresh = pool.alloc(owned_n)
+        fresh = pool.alloc(needed - shared_full)
         if fresh is None:
             pool.decref(pins)
             return None
         if fork:
             pool.cow_forks += 1
-
         load_row = [paging.NULL_BLOCK] * maxb
         load_row[:len(pins)] = pins
         final_row = [paging.NULL_BLOCK] * maxb
         final_row[:shared_full] = shared
         final_row[shared_full:needed] = fresh
-        # owned chunks land in their blocks; shared chunks and tail
-        # chunks past the allocation divert to SINK (never overwrite a
-        # shared block, never touch NULL)
-        dest_row = [c_blk if shared_full <= c < needed else
-                    paging.SINK_BLOCK
-                    for c, c_blk in enumerate(final_row)]
-
-        suffix = req.prompt[n_hit:]
+        suffix = prompt[n_hit:]
         Tc = _bucket_len(len(suffix))
         padded = jnp.asarray([suffix + [0] * (Tc - len(suffix))],
                              jnp.int32)
         _jit_sentinel.note("engine.prefill", padded)
         with _span("engine.prefill", hot=True):
-            last, tk, tv, tpos = paging.paged_prefill(
+            prefill = paging.paged_prefill(
                 self.params, self.cfg, self.cache,
                 jnp.asarray(load_row, jnp.int32),
                 jnp.asarray(n_hit, jnp.int32), padded,
                 jnp.asarray(len(suffix), jnp.int32))
+        return (n_hit, shared, fresh, final_row,
+                chain[shared_full] if fork else None, prefill)
+
+    def _register_chain(self, keys, final_row) -> None:
+        parent = None
+        for covered, key in keys:
+            self.pool.register(
+                key, final_row[(covered - 1) // self.block_size],
+                parent=parent, covered=covered)
+            parent = key
+
+    def _admit_paged(self, i: int, req: EngineRequest):
+        """Plan blocks, prefill the un-cached suffix, install. Returns
+        the last real token's logits row, or ``None`` on transient
+        block OOM."""
+        from kubeflow_rm_tpu.models import paging
+
+        Tp = len(req.prompt)
+        keys = (paging.prefix_keys(req.prompt, self.block_size)
+                if self.prefix_cache else [])
+        needed = -(-(Tp + req.max_new_tokens) // self.block_size)
+        plan = self._prefill_suffix(req.prompt, keys, needed)
+        if plan is None:
+            return None
+        n_hit, shared, fresh, final_row, fork_src, prefill = plan
+        last, tk, tv, tpos = prefill
+        # owned chunks land in their blocks; shared chunks and tail
+        # chunks past the allocation divert to SINK (never overwrite a
+        # shared block, never touch NULL)
+        dest_row = [b if len(shared) <= c < needed else paging.SINK_BLOCK
+                    for c, b in enumerate(final_row)]
         self.cache = paging.paged_install(
             self.cache, tk, tv, tpos, jnp.asarray(i, jnp.int32),
             jnp.asarray(final_row, jnp.int32),
             jnp.asarray(dest_row, jnp.int32),
             jnp.asarray(Tp, jnp.int32))
-        if fork:
-            pool.decref([chain[shared_full]])   # unpin the fork source
-        if self.prefix_cache:
-            parent = None
-            for covered, key in keys:
-                pool.register(key, final_row[(covered - 1) // BS],
-                              parent=parent, covered=covered)
-                parent = key
+        if fork_src is not None:
+            self.pool.decref([fork_src])   # unpin the fork source
+        self._register_chain(keys, final_row)
         self._slot_blocks[i] = shared + fresh
         self.prefix_hit_tokens += n_hit
         self.prompt_tokens += Tp
@@ -1313,7 +1340,7 @@ class ContinuousBatchingEngine:
         self.prefix_hit_tokens += Tp   # the whole prompt arrived cached
         self.prompt_tokens += Tp
         self.chain_installs += 1
-        return jnp.asarray(chain["last_logits"])
+        return np.asarray(chain["last_logits"])
 
     def prefill_chain(self, prompt) -> dict | None:
         """Prefill-replica entry point: compute the full prompt's KV
@@ -1339,39 +1366,13 @@ class ContinuousBatchingEngine:
         pool, BS = self.pool, self.block_size
         maxb = self.slot_len // BS
         keys = paging.prefix_keys(prompt, BS)
-        chain = pool.lookup_chain(keys)
-        n_hit = min(keys[len(chain) - 1][0] if chain else 0, Tp - 1)
-        while n_hit > 0 and n_hit + _bucket_len(Tp - n_hit) > self.slot_len:
-            n_hit = ((n_hit - 1) // BS) * BS
-        shared_full = n_hit // BS
-        fork = n_hit % BS != 0
-        shared = chain[:shared_full]
         needed = -(-Tp // BS)          # prompt only: no decode budget
-        owned_n = needed - shared_full
-        pins = chain[:shared_full + 1] if fork else shared
-        pool.incref(pins)
-        fresh = pool.alloc(owned_n)
-        if fresh is None:
-            pool.decref(pins)
+        plan = self._prefill_suffix(prompt, keys, needed)
+        if plan is None:
             return None
-        if fork:
-            pool.cow_forks += 1
-        load_row = [paging.NULL_BLOCK] * maxb
-        load_row[:len(pins)] = pins
-        final_row = [paging.NULL_BLOCK] * maxb
-        final_row[:shared_full] = shared
-        final_row[shared_full:needed] = fresh
-        suffix = prompt[n_hit:]
-        Tc = _bucket_len(len(suffix))
-        padded = jnp.asarray([suffix + [0] * (Tc - len(suffix))],
-                             jnp.int32)
-        _jit_sentinel.note("engine.prefill", padded)
-        with _span("engine.prefill", hot=True):
-            last, tk, tv, tpos = paging.paged_prefill(
-                self.params, self.cfg, self.cache,
-                jnp.asarray(load_row, jnp.int32),
-                jnp.asarray(n_hit, jnp.int32), padded,
-                jnp.asarray(len(suffix), jnp.int32))
+        n_hit, shared, fresh, final_row, fork_src, prefill = plan
+        last, tk, tv, tpos = prefill
+        own = slice(len(shared), needed)
         # carve owned chunks into their blocks WITHOUT seating any
         # slot table — prefill replicas never decode, the chain lives
         # purely in the pool + prefix index
@@ -1381,21 +1382,16 @@ class ContinuousBatchingEngine:
         cp = tpos[0].reshape(maxb, BS)
         idx = jnp.asarray(fresh, jnp.int32)
         self.cache = paging.PagedKVCache(
-            k=self.cache.k.at[:, idx].set(ck[:, shared_full:needed]),
-            v=self.cache.v.at[:, idx].set(cv[:, shared_full:needed]),
-            positions=self.cache.positions.at[idx].set(
-                cp[shared_full:needed]),
+            k=self.cache.k.at[:, idx].set(ck[:, own]),
+            v=self.cache.v.at[:, idx].set(cv[:, own]),
+            positions=self.cache.positions.at[idx].set(cp[own]),
             block_tables=self.cache.block_tables,
             write_idx=self.cache.write_idx,
             pos_next=self.cache.pos_next,
         )
-        if fork:
-            pool.decref([chain[shared_full]])
-        parent = None
-        for covered, key in keys:
-            pool.register(key, final_row[(covered - 1) // BS],
-                          parent=parent, covered=covered)
-            parent = key
+        if fork_src is not None:
+            pool.decref([fork_src])
+        self._register_chain(keys, final_row)
         out = paging.export_chain(self.cache, pool, prompt)
         # logits keep their compute dtype: install-side sampling must
         # see the exact values solo prefill would produce
@@ -1414,9 +1410,7 @@ class ContinuousBatchingEngine:
     def _retire(self, i: int) -> None:
         if self.paged and self._slot_blocks[i] is not None:
             self.pool.decref(self._slot_blocks[i])
-        self._slot_blocks[i] = None
-        self._slot_req[i] = None
-        self._last[i] = None
+        self._slot_blocks[i] = self._slot_req[i] = None
 
     def step(self) -> list[EngineRequest]:
         """Admit, sample, retire, decode — one token boundary. Returns
@@ -1432,9 +1426,7 @@ class ContinuousBatchingEngine:
                 with _span("engine.dispatch", hot=True):
                     last = self._dispatch(tokens, active)
                 with _span("engine.scatter"):
-                    for i in range(self.slots):
-                        if active[i]:
-                            self._last[i] = last[i]
+                    self._last = last
                     self.decode_steps += 1
                     self.occupancy_sum += sum(active)
         return finished
@@ -1444,26 +1436,33 @@ class ContinuousBatchingEngine:
         retire the slots that are through. Returns the requests that
         finished and, a slot each, the token to feed and whether the
         slot stays live."""
-        finished: list[EngineRequest] = []
-        if self._spec_finished:
-            # speculative requests ran whole inside _admit
-            finished.extend(self._spec_finished)
-            self._spec_finished = []
+        # speculative requests ran whole inside _admit
+        finished, self._spec_finished = self._spec_finished, []
         tokens = [0] * self.slots
         active = [False] * self.slots
-        for i, req in enumerate(self._slot_req):
-            if req is None:
-                continue
+        live = [(i, r) for i, r in enumerate(self._slot_req)
+                if r is not None]
+        # one program for all greedy rows, one for each sampling row
+        # (its request's own key stream)
+        greedy, sampled = None, {}
+        if any(r.temperature <= 0 for _, r in live):
+            greedy = _pick_row(self._last, None, temperature=0.0,
+                               top_k=None)
+        for i, req in live:
             if req.temperature > 0:
                 req.key, sub = jax.random.split(req.key)
-            else:
-                sub = None
-            # the ONE deliberate sync per token boundary: the sampled
-            # token drives host-side scheduling (EOS retirement,
-            # admission) and cannot stay on device.
-            nxt = int(_pick_row(self._last[i], sub,  # kfrm: disable=KFRM006
-                                temperature=req.temperature,
-                                top_k=req.top_k))
+                sampled[i] = _pick_row(self._last[i], sub,
+                                       temperature=req.temperature,
+                                       top_k=req.top_k)
+        if live:
+            # the ONE deliberate sync per token boundary, once all are
+            # dispatched: the tokens drive host-side scheduling (EOS
+            # retirement, admission) and cannot stay on device.
+            self.pick_programs_total += len(sampled) + (greedy is not None)
+            self.host_syncs_total += 1
+            greedy, sampled = jax.device_get((greedy, sampled))
+        for i, req in live:
+            nxt = int(sampled[i] if i in sampled else greedy[i])
             now = time.perf_counter()
             req.tokens.append(nxt)
             req.t_tokens.append(now)
@@ -1481,11 +1480,13 @@ class ContinuousBatchingEngine:
 
     def _dispatch(self, tokens, active):
         """One decode step for all slots; returns the logits rows."""
-        tok_arr = jnp.asarray(tokens, jnp.int32)
-        act_arr = jnp.asarray(active)
+        tok_arr = np.asarray(tokens, np.int32)
+        act_arr = np.asarray(active, bool)
         _jit_sentinel.note("engine.decode_step", tok_arr, act_arr)
+        decode = slot_decode_step
         if self.paged:
             from kubeflow_rm_tpu.models import paging
+            decode = paging.paged_decode_step
             # what the step touches of each live slot's table: the
             # blocks up to the one this token lands in. The host knows
             # every length (prompt + tokens picked so far, the one
@@ -1493,11 +1494,8 @@ class ContinuousBatchingEngine:
             self.kv_blocks_read_total += sum(
                 -(-(len(r.prompt) + len(r.tokens)) // self.block_size)
                 for r in self._slot_req if r is not None)
-            last, self.cache = paging.paged_decode_step(
-                self.params, self.cfg, self.cache, tok_arr, act_arr)
-        else:
-            last, self.cache = slot_decode_step(
-                self.params, self.cfg, self.cache, tok_arr, act_arr)
+        last, self.cache = decode(self.params, self.cfg, self.cache,
+                                  tok_arr, act_arr)
         return last
 
     def run(self) -> list[EngineRequest]:
@@ -1532,6 +1530,8 @@ class ContinuousBatchingEngine:
             "queue_depth": self.queue_depth,
             "queue_depth_by_class": self.queue_depth_by_class,
             "decode_steps": steps,
+            "host_syncs_total": self.host_syncs_total,
+            "pick_programs_total": self.pick_programs_total,
             "prefills": self.prefills,
             "admitted_total": self.admitted_total,
             "admitted_by_class": dict(self.admitted_by_class),

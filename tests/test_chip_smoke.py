@@ -61,6 +61,11 @@ def test_cpu_dry_run_drives_both_phases(capsys):
     # interpreted, the kernel lowers to plain HLO: no custom call here
     assert serve["pallas_kernels_in_lowered_decode_step"] == 0
     assert serve["kv_blocks_read_total"] > serve["decode_steps"]
+    # one pick program and one blocking transfer a token boundary: the
+    # decode steps and the boundaries that only retired
+    assert (serve["decode_steps"] <= serve["host_syncs_total"]
+            == serve["pick_programs_total"]
+            <= serve["decode_steps"] + serve["requests"])
 
 
 def test_a_failure_in_either_phase_exits_nonzero(monkeypatch, capsys):

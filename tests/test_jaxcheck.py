@@ -161,6 +161,7 @@ def test_sentinel_bucketed_storm_stays_bounded(sentinel, model):
     cfg, params = model
     slot_len = 32
     cache_before = paging.paged_prefill._cache_size()
+    decode_before = paging.paged_decode_step._cache_size()
     eng = ContinuousBatchingEngine(params, cfg, slots=2,
                                    slot_len=slot_len)
     rng = np.random.default_rng(7)
@@ -176,6 +177,11 @@ def test_sentinel_bucketed_storm_stays_bounded(sentinel, model):
     assert rep["engine.decode_step"]["signatures"] == 1
     assert sentinel.over_limit() == []
     assert paging.paged_prefill._cache_size() - cache_before <= limit
+    # the step hands the decode program two host arrays (tokens int32,
+    # live mask bool): one signature for the sentinel, one program
+    assert paging.paged_decode_step._cache_size() - decode_before <= 1
+    (sig,) = recompile._entries["engine.decode_step"]["signatures"]
+    assert sig == (((2,), "int32"), ((2,), "bool"))
 
 
 def test_sentinel_unbucketed_storm_grows_unbounded(sentinel):
@@ -232,6 +238,40 @@ def test_hostsync_witnesses_implicit_syncs_in_region(probe):
     w = probe.witnesses()[0]
     assert w["region"] == "decode-loop"
     assert "test_jaxcheck" in w["stack"]
+
+
+def test_engine_dispatch_region_holds_no_implicit_sync(probe, model):
+    """The step loop's hot regions launch programs and read nothing
+    back: the pick's one transfer a step is outside them, deliberate,
+    and counted by the engine itself. A sync planted inside the decode
+    call is witnessed under ``engine.dispatch``."""
+    from unittest import mock
+
+    from kubeflow_rm_tpu.models import paging
+    from kubeflow_rm_tpu.models.generate import ContinuousBatchingEngine
+
+    cfg, params = model
+    eng = ContinuousBatchingEngine(params, cfg, slots=4, slot_len=32)
+    rng = np.random.default_rng(9)
+    for n in (3, 5, 9, 4, 7):
+        eng.submit(rng.integers(1, cfg.vocab_size, size=n).tolist(),
+                   max_new_tokens=4)
+    eng.run()
+    assert eng.stats()["host_syncs_total"] >= 4
+    assert probe.witnesses() == []
+
+    real = paging.paged_decode_step
+
+    def leaky(params, cfg, cache, tokens, active):
+        np.asarray(cache.pos_next)
+        return real(params, cfg, cache, tokens, active)
+
+    with mock.patch.object(paging, "paged_decode_step", leaky):
+        eng.submit([1, 2, 3], max_new_tokens=3)
+        eng.run()
+    seen = probe.witnesses()
+    assert seen and {w["region"] for w in seen} == {"engine.dispatch"}
+    assert {w["kind"] for w in seen} == {"np.asarray"}
 
 
 def test_hostsync_ignores_syncs_outside_regions(probe):

@@ -203,6 +203,17 @@ def test_kill_resume_overflow_restarts_from_original_prompt(model):
         gw = fleet.gateways[victim]
         result = {}
 
+        # kill() needs the gateway's lock, which the drain thread
+        # drops between steps for microseconds only (ROADMAP S10): on
+        # a fast step loop the 16 tokens are through before the kill
+        # gets in. Hold the thread off the lock for a moment a step.
+        class _Dawdling(threading.Event):
+            def is_set(self):
+                time.sleep(0.003)
+                return super().is_set()
+
+        gw._stop = _Dawdling()
+
         def go():
             result["r"] = fleet.submit_and_wait("t", list(p),
                                                 max_new_tokens=16)

@@ -133,6 +133,10 @@ def test_engine_and_gateway_spans_on_the_profilers_clock(model, tmp_path):
         got = _spans(host, name)
         assert got and all(_inside(s, steps) for s in got), name
     assert all(_inside(s, drains) for s in steps)
+    # the decode step's output is kept whole: the scatter span is still
+    # there, once a dispatch, for step_idle_ms.scatter to read
+    assert len(_spans(host, "engine.scatter")) \
+        == len(_spans(host, "engine.dispatch"))
     prefills = _spans(host, "engine.prefill")
     assert prefills and all(
         _inside(s, _spans(host, "engine.admit")) for s in prefills)

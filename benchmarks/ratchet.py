@@ -90,30 +90,6 @@ def _phase_p50s(artifact: dict) -> dict[str, float]:
     return out
 
 
-def _serve_metrics(artifact: dict) -> dict[str, float]:
-    """Lower-is-better rows from a serve_bench artifact's arms.
-    Throughput inverts to ms per 1k useful tokens (1e6 / tok_s) so the
-    shared ``_compare`` direction (bigger = worse) applies; victim p95
-    passes through as-is."""
-    out: dict[str, float] = {}
-    for arm, sec in sorted((artifact.get("arms") or {}).items()):
-        if not isinstance(sec, dict):
-            continue
-        tok_s = sec.get("useful_tok_per_s")
-        if tok_s:
-            out[f"{arm}.ms_per_1k_useful_tok"] = 1e6 / float(tok_s)
-        p95 = sec.get("victim_p95_ms_worst")
-        if p95 is not None:
-            out[f"{arm}.victim_p95_ms"] = float(p95)
-        # disagg_storm arms carry an aggregate interactive p95 (the
-        # SLO-class latency the prefill/decode split is meant to
-        # protect) alongside the per-tenant worst
-        p95i = sec.get("interactive_p95_ms")
-        if p95i is not None:
-            out[f"{arm}.interactive_p95_ms"] = float(p95i)
-    return out
-
-
 def _top_level_p50(artifact: dict) -> float | None:
     v = artifact.get("provision_p50_ms")
     if v is not None:
@@ -173,16 +149,6 @@ def main(argv=None) -> int:
                          "(PROVISION_r11.json)")
     ap.add_argument("--provision", default="",
                     help="fresh storm's --out artifact")
-    ap.add_argument("--baseline-serve", default="",
-                    help="checked-in serve_bench artifact "
-                         "(SERVE_r02.json)")
-    ap.add_argument("--serve", default="",
-                    help="fresh serve_bench --out artifact")
-    ap.add_argument("--serve-gate", action="store_true",
-                    help="fail (exit 3) on serving regressions instead "
-                         "of warning — serving throughput on shared CI "
-                         "hosts is noisy, so the default only warns "
-                         "(the r12 convention for new sections)")
     ap.add_argument("--threshold", type=float, default=0.20,
                     help="relative regression gate (0.20 = 20%%)")
     ap.add_argument("--floor-ms", type=float, default=150.0,
@@ -203,17 +169,11 @@ def main(argv=None) -> int:
         print("ratchet: --baseline-provision and --provision go "
               "together", file=sys.stderr)
         return 2
-    if bool(args.baseline_serve) != bool(args.serve):
-        print("ratchet: --baseline-serve and --serve go together",
-              file=sys.stderr)
-        return 2
     if args.trace:
         pairs.append(("trace", args.baseline_trace, args.trace))
     if args.provision:
         pairs.append(("provision", args.baseline_provision,
                       args.provision))
-    if args.serve:
-        pairs.append(("serve", args.baseline_serve, args.serve))
     if not pairs:
         print("ratchet: nothing to compare (pass --trace/--provision)",
               file=sys.stderr)
@@ -236,9 +196,7 @@ def main(argv=None) -> int:
         report["warnings"].extend(f"{kind}: {w}" for w in warnings)
         if refusals:
             continue
-        if kind == "serve":
-            base_t, fresh_t = _serve_metrics(base), _serve_metrics(fresh)
-        elif kind == "trace":
+        if kind == "trace":
             base_t, fresh_t = _hop_sums(base), _hop_sums(fresh)
             # the whole-storm p50 rides the trace artifact: gate it as
             # a synthetic hop so a regression spread thinly over many
@@ -256,14 +214,6 @@ def main(argv=None) -> int:
                 fresh_t["(provision_p50_ms)"] = fp
         rows, warnings, regressions = _compare(
             kind, base_t, fresh_t, args.threshold, args.floor_ms)
-        if kind == "serve" and not args.serve_gate and regressions:
-            # warn-not-fail: serving throughput jitters with host load;
-            # the rows still land in the report for eyeballing
-            warnings.extend(
-                f"serve '{r['name']}' regressed {r['baseline_ms']}ms "
-                f"-> {r['fresh_ms']}ms (+{r['delta_pct']}%) — warn-only "
-                f"(pass --serve-gate to enforce)" for r in regressions)
-            regressions = []
         report["comparisons"].append(
             {"kind": kind, "baseline": base_path, "fresh": fresh_path,
              "rows": rows})

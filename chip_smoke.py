@@ -4,7 +4,7 @@ Drives the repo's two main paths once each, through the entry points a
 tenant calls, at the full width of ``LlamaConfig.bench_1b`` (bf16
 params, random weights from a seed), in ONE process that owns the chip:
 
-- **serve**: ``ContinuousBatchingEngine(paged=True)`` ->
+- **serve**: ``ContinuousBatchingEngine`` ->
   ``ServingGateway`` -> one-replica ``ServingFleet`` ->
   ``submit_and_wait`` for nine concurrent requests over three prefill
   buckets, one pair sharing a prefix (block adoption) and one exact
@@ -203,8 +203,8 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
     )
 
     engine = ContinuousBatchingEngine(
-        init_params(cfg, jax.random.key(SEED)), cfg, paged=True,
-        slots=sz.slots, slot_len=sz.slot_len, block_size=sz.block_size)
+        init_params(cfg, jax.random.key(SEED)), cfg, slots=sz.slots,
+        slot_len=sz.slot_len, block_size=sz.block_size)
     params = engine.params      # the engine's own copy is the only one
     # the tenant's latency promise has to admit a cold start: the
     # first requests wait out every compile, and the default 2 s SLO

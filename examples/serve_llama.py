@@ -234,7 +234,7 @@ def make_app(cfg, params, *, max_new_tokens: int = 64, mesh=None,
     step (``quantize.unpack_int4_params``, hoisted ahead of the scan),
     which removed the 612.77-vs-137.07 ms/tok regression that made PR 4
     route int4 to the per-token loop (``BENCH_SWEEP_r05.json``
-    ``decode_7b``; re-measured in ``SERVE_r01.json`` ``decode_int4``).
+    ``decode_7b``).
     ``fused_int4=False`` (``--loop-int4``) keeps the per-token loop as
     the measured A/B baseline arm."""
     import jax
@@ -400,8 +400,7 @@ def main(argv=None) -> int:
                          "generate loop instead of the fused program "
                          "(A/B baseline arm; fused is the default now "
                          "that the nibble unpack is hoisted out of "
-                         "the decode scan — SERVE_r01.json "
-                         "decode_int4)")
+                         "the decode scan)")
     ap.add_argument("--tp", type=int, default=1)
     ap.add_argument("--fsdp", type=int, default=0,
                     help="0 = all local devices (with --tp 1 ⇒ "

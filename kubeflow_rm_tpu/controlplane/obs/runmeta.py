@@ -3,10 +3,9 @@
 ``ratchet.py`` diffs timing artifacts across commits; a diff between a
 2-shard WAL run and a single-process run is garbage, and a diff across
 hosts is suspect. Every harness (``spawn_conformance.py``,
-``e2e_walk.py``, ``serve_bench.py``) stamps its output with this
-header so the ratchet can *refuse* mismatched-arm comparisons (hard)
-and *flag* cross-host ones (soft) instead of producing nonsense
-deltas.
+``e2e_walk.py``) stamps its output with this header so the ratchet
+can *refuse* mismatched-arm comparisons (hard) and *flag* cross-host
+ones (soft) instead of producing nonsense deltas.
 """
 
 from __future__ import annotations

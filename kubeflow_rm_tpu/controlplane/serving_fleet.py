@@ -448,13 +448,11 @@ class ServingFleet:
         self.roles = roles
         self.store = store
         if self.store is not None:
-            # promote-on-evict: a paged pool dropping a ref-0 block
-            # hands its bytes to the store on the way out, so a hot
-            # chain outlives the pool (and replica) that computed it
+            # promote-on-evict: a pool dropping a ref-0 block hands
+            # its bytes to the store on the way out, so a hot chain
+            # outlives the pool (and replica) that computed it
             for gw in self.gateways.values():
-                if getattr(gw.engine, "paged", False):
-                    gw.engine.pool.on_evict = self._promote_hook(
-                        gw.engine)
+                gw.engine.pool.on_evict = self._promote_hook(gw.engine)
         self._publish_states()
         self._publish_tiers()
 
@@ -501,8 +499,7 @@ class ServingFleet:
             self.gateways[name] = gateway
             self._state[name] = READY
             self._rebuild_ring_locked()
-        if self.store is not None and getattr(gateway.engine, "paged",
-                                              False):
+        if self.store is not None:
             gateway.engine.pool.on_evict = self._promote_hook(
                 gateway.engine)
         self._publish_tiers()
@@ -741,8 +738,7 @@ class ServingFleet:
                 tried.add(name)
                 continue
             chain = None
-            if (disagg and self.store is not None and not speculative
-                    and getattr(gw.engine, "paged", False)):
+            if disagg and self.store is not None and not speculative:
                 chain = self._stage_prefix(gw, full)
             try:
                 pending, reason = gw.try_submit(

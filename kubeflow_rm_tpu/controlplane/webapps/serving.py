@@ -4,8 +4,7 @@ The serving analogue of what the provision path grew in PRs 3-5: the
 decode engine (``models.generate.ContinuousBatchingEngine``) gives us
 slot-level admission/retirement at token boundaries; this module puts
 a tenant-aware front door on it so one tenant's storm cannot blow
-another's p95 — the failure mode ``benchmarks/serve_bench.py``'s
-static batches had no answer to.
+another's p95 — the failure mode static batches have no answer to.
 
 Admission control, in order (first failure sheds the request before it
 ever touches the engine):
@@ -261,11 +260,9 @@ class ServingGateway:
     def prefill_chain(self, prompt: list[int]):
         """Run prefill into cache blocks and export the serialized
         chain (see ``models.paging.export_chain``). Returns None when
-        draining, not paged, or the pool is too full to hold it."""
+        draining or the pool is too full to hold it."""
         with self._lock:
             if self.draining:
-                return None
-            if not getattr(self.engine, "paged", False):
                 return None
             return self.engine.prefill_chain(prompt)
 
@@ -274,7 +271,7 @@ class ServingGateway:
         request attached). Returns blocks imported (0 = already local,
         pool full, or draining)."""
         with self._lock:
-            if self.draining or not getattr(self.engine, "paged", False):
+            if self.draining:
                 return 0
             return self.engine.adopt_chain(chain)
 
@@ -283,8 +280,6 @@ class ServingGateway:
         prefix blocks — the fleet uses this to decide whether routing
         through the prefill tier would save anything."""
         with self._lock:
-            if not getattr(self.engine, "paged", False):
-                return 0
             return self.engine.chain_coverage(prompt)
 
     def wait(self, pending: _Pending, timeout_s: float = 300.0
@@ -356,13 +351,12 @@ class ServingGateway:
                 stats["batch_occupancy"])
             for c, d in stats.get("queue_depth_by_class", {}).items():
                 cp_metrics.SERVING_CLASS_QUEUE_DEPTH.labels(c).set(d)
-            if stats.get("paged"):
-                cp_metrics.SERVING_FREE_BLOCK_FRACTION.set(
-                    stats["free_block_fraction"])
-                if stats.get("prompt_tokens"):
-                    hr = stats["prefix_hit_ratio"]
-                    cp_metrics.SERVING_PREFIX_HIT_RATIO.set(hr)
-                    cp_metrics.SERVING_PREFIX_MISS_RATIO.set(1.0 - hr)
+            cp_metrics.SERVING_FREE_BLOCK_FRACTION.set(
+                stats["free_block_fraction"])
+            if stats["prompt_tokens"]:
+                hr = stats["prefix_hit_ratio"]
+                cp_metrics.SERVING_PREFIX_HIT_RATIO.set(hr)
+                cp_metrics.SERVING_PREFIX_MISS_RATIO.set(1.0 - hr)
         if not finished:
             return []
         ready = [p for p in self._pending if p.req.done]
@@ -460,7 +454,6 @@ class ServingGateway:
             "admission": self.admission,
             "draining": self.draining,
             "error": repr(self.error) if self.error else None,
-            "paged": stats.get("paged", False),
             "queue_depth_by_class": stats.get("queue_depth_by_class"),
             "prefix_hit_ratio": stats.get("prefix_hit_ratio"),
             "free_block_fraction": stats.get("free_block_fraction"),

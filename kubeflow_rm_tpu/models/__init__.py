@@ -17,17 +17,14 @@ from kubeflow_rm_tpu.models.generate import (
     ContinuousBatchingEngine,
     EngineRequest,
     KVCache,
-    SlotCache,
     cache_shardings,
     decode_chunk,
     generate,
     generate_fused,
     generate_speculative_fused,
     init_cache,
-    init_slot_cache,
     make_decode_step,
     make_generate_step,
-    slot_decode_step,
 )
 from kubeflow_rm_tpu.models.generate import (
     DEFAULT_CLASS_WEIGHTS,
@@ -68,11 +65,11 @@ __all__ = ["BlockPool", "ContinuousBatchingEngine",
            "PagedKVCache", "SLO_CLASSES",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
            "prefix_keys",
-           "LlamaConfig", "MixtralConfig", "SlotCache", "add_lora",
+           "LlamaConfig", "MixtralConfig", "add_lora",
            "config_from_hf",
            "cache_shardings", "decode_chunk", "forward", "forward_with_aux", "from_hf_llama",
            "generate", "generate_fused", "generate_speculative_fused",
-           "init_cache", "init_params", "init_slot_cache",
-           "make_decode_step", "make_generate_step", "slot_decode_step",
+           "init_cache", "init_params",
+           "make_decode_step", "make_generate_step",
            "lora_mask", "maybe_dequant", "merge_lora", "quantize_params",
            "unpack_int4_params"]

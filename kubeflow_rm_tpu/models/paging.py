@@ -32,8 +32,8 @@ one wide chunk under XLA (verified in ``tests/test_paging.py``). So
 per-request outputs stay bit-identical to solo ``generate_fused``,
 cached prefix or not.
 
-Layout notes: the decode step runs the same ``_run_blocks`` trunk as
-the contiguous engine but never builds a strip of the whole cache.
+Layout notes: the decode step runs the ``_run_blocks`` trunk
+(``models.decode``) but never builds a strip of the whole cache.
 Each layer's attention reads that layer's blocks of the pool through
 the slots' block tables (``ops/paged_attention.py``: on a one-device
 TPU program a pallas kernel that copies only the blocks a live slot
@@ -58,7 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kubeflow_rm_tpu.models.generate import (
+from kubeflow_rm_tpu.models.decode import (
     _UNFILLED, _cache_attend, _run_blocks,
 )
 from kubeflow_rm_tpu.models.llama import LlamaConfig
@@ -80,8 +80,8 @@ RESERVED_BLOCKS = 2
 class PagedKVCache:
     """Pool-of-blocks KV state. ``block_tables[i]`` concatenated is
     slot *i*'s logical strip of ``slot_len = MAXB * BS`` positions;
-    ``write_idx``/``pos_next`` are the same per-slot counters
-    ``SlotCache`` keeps, expressed in logical-strip offsets."""
+    ``write_idx``/``pos_next`` are per-slot counters in logical-strip
+    offsets, so each slot advances on its own."""
     k: jax.Array             # (L, NB, BS, KVH, hd) compute dtype
     v: jax.Array             # (L, NB, BS, KVH, hd)
     positions: jax.Array     # (NB, BS) int32; _UNFILLED marks empty
@@ -120,13 +120,16 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, slot_len: int,
 def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
     """One decode step over every slot, the pool read in place.
 
-    Mirrors ``slot_decode_step`` exactly: each active row attends at
+    ``tokens`` (B,) int32 is each slot's freshly-sampled token;
+    ``active`` (B,) bool masks live slots. Each active row attends at
     its own ``pos_next`` over its logical (slot_len-long) strip and
-    writes K/V at its own ``write_idx``; inactive rows flow through
-    with query position ``_UNFILLED`` and their (garbage) pool write
-    redirected to SINK_BLOCK — their table may reference blocks that
-    other slots now own, so unlike the contiguous engine their write
-    target is NOT private and must be diverted.
+    writes K/V at its own ``write_idx``; inactive rows still flow
+    through the matmuls (static shapes) with query position
+    ``_UNFILLED``, their counters do not advance, and their (garbage)
+    pool write is redirected to SINK_BLOCK — their table may reference
+    blocks that other slots now own, so their write target is NOT
+    private and must be diverted. Returns (last-position logits (B, V)
+    fp32, updated cache).
 
     The pool never rides the layer scan: the scan carries ``x``, scans
     (layer weights, layer index) and each layer's attention reads that
@@ -146,8 +149,8 @@ def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
                     SINK_BLOCK)
     off = wi % BS
 
-    # the strips' positions, this token's among them: what the mask of
-    # a contiguous SlotCache would hold for the same requests
+    # every slot's logical strip of positions, gathered through its
+    # block table, this token's among them: the attention mask
     gpos = cache.positions[cache.block_tables].reshape(B, S)
     kv_positions = gpos.at[rows, wi].set(positions[:, 0])
 

@@ -27,9 +27,9 @@ jax-native.
   loop (612.77 vs 137.07 ms/tok at B8/7B, BENCH_SWEEP_r05.json
   ``decode_7b``) and earned the docstring claim that int4 was "a
   capacity lever, not a speed lever". With the hoist that claim is
-  stale: fused int4 decodes at int8-like step cost (SERVE_r01.json
-  ``decode_int4`` re-measurement) while still storing a 7B in ~3.6 GB
-  packed + ~6.7 GB unpacked-resident during decode — both levers now.
+  stale: the fused scan no longer re-unpacks, while a 7B still stores
+  in ~3.6 GB packed + ~6.7 GB unpacked-resident during decode (its
+  step cost on the chip: not measured).
 - The dequant multiply fuses into the matmul epilogue; XLA reads the
   narrow weights from HBM and converts in VMEM, which is exactly where
   the bandwidth win comes from. Norms (tiny) and the embedding (a

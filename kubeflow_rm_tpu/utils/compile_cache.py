@@ -1,7 +1,7 @@
 """Where compiled programs are kept between processes.
 
-Every entry point (``chip_smoke.py``, ``bench.py``,
-``benchmarks/serve_bench.py``, the ``examples/`` mains) calls
+Every entry point (``chip_smoke.py``, ``bench.py``, the ``examples/``
+mains) calls
 ``enable_compile_cache()`` before its first jit, so a second process on
 the same machine finds the first one's programs instead of compiling
 them again — on the chip that is over a minute per run.

@@ -59,8 +59,7 @@ def _engine(params, cfg, **kw):
     kw.setdefault("slots", 2)
     kw.setdefault("slot_len", 64)
     kw.setdefault("block_size", 8)
-    return ContinuousBatchingEngine(params, cfg, paged=True,
-                                    prefix_cache=True, **kw)
+    return ContinuousBatchingEngine(params, cfg, **kw)
 
 
 def _drain(eng, req):

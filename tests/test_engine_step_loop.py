@@ -42,6 +42,19 @@ def _prompts(model, sizes, seed=11):
             for n in sizes]
 
 
+def _seated_prompts(model, seating, sizes, seed):
+    """Prompts of ``sizes`` tokens. Under ``prefix-hit`` they share a
+    prefix of two whole blocks and half of a third (``block_size`` 8),
+    and the first two are that prefix alone: the second admission
+    adopts the first one's two whole blocks and forks the third
+    copy-on-write, the later ones adopt the two."""
+    prompts = _prompts(model, sizes, seed)
+    if seating == "prefix-hit":
+        shared = _prompts(model, (20,), seed + 1)[0]
+        prompts = [shared, shared] + [shared + p for p in prompts[2:]]
+    return prompts
+
+
 def _greedy(model, prompt, budget):
     cfg, params = model
     out = generate.generate(params, cfg, jnp.asarray([prompt], jnp.int32),
@@ -102,13 +115,13 @@ def test_an_idle_step_picks_nothing(model):
 
 # ---- (b) ragged prompts, admitted and retired mid-flight ---------------
 
-@pytest.mark.parametrize("paged", [True, False],
-                         ids=["paged", "contiguous"])
-def test_ragged_requests_match_generate_greedy(model, paged):
+@pytest.mark.parametrize("seating", ["paged", "prefix-hit"])
+def test_ragged_requests_match_generate_greedy(model, seating):
     # three slots, eight requests: slots are recycled mid-flight and
     # the live set changes at almost every boundary
-    eng = _engine(model, slots=3, paged=paged)
-    prompts = _prompts(model, (3, 17, 5, 9, 1, 12, 7, 4), seed=5)
+    eng = _engine(model, slots=3)
+    prompts = _seated_prompts(model, seating,
+                              (3, 17, 5, 9, 1, 12, 7, 4), seed=5)
     budgets = [4, 9, 2, 7, 11, 1, 5, 8]
     reqs = [eng.submit(p, max_new_tokens=m)
             for p, m in zip(prompts, budgets)]
@@ -121,6 +134,10 @@ def test_ragged_requests_match_generate_greedy(model, paged):
     # far fewer picks than tokens: a boundary picks for all its slots
     assert s["host_syncs_total"] == s["pick_programs_total"]
     assert s["decode_steps"] <= s["host_syncs_total"] < sum(budgets)
+    if seating == "prefix-hit":
+        # the repeat forked its write block; it and a later prompt at
+        # the least found the prefix in the pool
+        assert s["cow_forks"] >= 1 and s["prefix_hit_tokens"] >= 19 + 16
 
 
 # ---- (c) a step that mixes greedy and sampling requests ----------------
@@ -185,11 +202,11 @@ def test_all_sampling_step_needs_no_greedy_program(model):
 
 # ---- (d) a dead row is never read ---------------------------------------
 
-@pytest.mark.parametrize("paged", [True, False],
-                         ids=["paged", "contiguous"])
-def test_a_reseated_slot_never_shows_the_dead_rows_token(model, paged):
-    eng = _engine(model, slots=2, paged=paged)
-    first, stays, *later = _prompts(model, (5, 8, 6, 7, 4, 9), seed=31)
+@pytest.mark.parametrize("seating", ["paged", "prefix-hit"])
+def test_a_reseated_slot_never_shows_the_dead_rows_token(model, seating):
+    eng = _engine(model, slots=2)
+    first, stays, *later = _seated_prompts(
+        model, seating, (5, 8, 6, 7, 4, 9), seed=31)
     a = eng.submit(first, max_new_tokens=2)
     b = eng.submit(stays, max_new_tokens=12)
     while not a.done:
@@ -210,6 +227,10 @@ def test_a_reseated_slot_never_shows_the_dead_rows_token(model, paged):
     assert c.tokens == _greedy(model, nxt, 4)
     assert b.tokens == _greedy(model, stays, 12)
     assert a.tokens == _greedy(model, first, 2)   # and grew no further
+    if seating == "prefix-hit":
+        # b forked a's third block, c adopted the two whole ones
+        s = eng.stats()
+        assert s["cow_forks"] == 1 and s["prefix_hit_tokens"] == 19 + 16
 
 
 # ---- the planted fault of the benchmark's tests still lands ------------

@@ -349,13 +349,10 @@ def test_sampling_requires_key(model):
 
 
 def test_fused_int4_matches_loop_tokenwise(model):
-    """The unpack-once fix must not change a single token: fused int4
+    """Unpacking once must not change a single token: fused int4
     decode (nibbles unpacked ahead of the scan) vs the per-token loop
-    (which dequants packed leaves in place), and vs the pre-fix trace
-    that re-unpacks inside the scan (``set_unpack_once(False)``)."""
-    from kubeflow_rm_tpu.models.generate import (
-        generate_fused, set_unpack_once,
-    )
+    (which dequants packed leaves in place)."""
+    from kubeflow_rm_tpu.models.generate import generate_fused
     from kubeflow_rm_tpu.models.quantize import quantize_params
 
     cfg, params = model
@@ -365,12 +362,6 @@ def test_fused_int4_matches_loop_tokenwise(model):
     loop = generate(q4, cfg, prompt, max_new_tokens=7)
     fused = generate_fused(q4, cfg, prompt, max_new_tokens=7)
     np.testing.assert_array_equal(np.asarray(fused), np.asarray(loop))
-    try:
-        set_unpack_once(False)
-        refused = generate_fused(q4, cfg, prompt, max_new_tokens=7)
-    finally:
-        set_unpack_once(True)
-    np.testing.assert_array_equal(np.asarray(refused), np.asarray(loop))
 
 
 def test_engine_matches_one_shot_fused(model):
@@ -441,3 +432,22 @@ def test_engine_validation_and_sampling(model):
         e.run()
         outs.append(r.tokens)
     assert outs[0] == outs[1] and len(outs[0]) == 6
+
+
+def test_engine_accepts_paged_true_only(model):
+    """``paged`` chooses nothing (the harness still passes it): True
+    builds the engine no keyword builds, anything else is refused."""
+    from kubeflow_rm_tpu.models.generate import ContinuousBatchingEngine
+
+    cfg, params = model
+    for bad in (False, None, 0):
+        with pytest.raises(ValueError, match="paged"):
+            ContinuousBatchingEngine(params, cfg, slots=1, slot_len=16,
+                                     paged=bad)
+    plain = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=32)
+    keyed = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=32,
+                                     paged=True)
+    assert keyed.stats() == plain.stats() and "paged" not in plain.stats()
+    assert not hasattr(plain, "paged")
+    assert (jax.tree.map(jnp.shape, keyed.cache)
+            == jax.tree.map(jnp.shape, plain.cache))

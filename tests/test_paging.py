@@ -310,3 +310,43 @@ def test_kv_blocks_read_total_counts_what_the_lengths_say(model):
     st = eng.stats()
     assert st["kv_blocks_read_total"] == want
     assert st["decode_steps"] == max(budgets.values()) - 1
+
+
+def test_models_import_one_way_trunk_paging_engine():
+    """``decode`` (the trunk) <- ``paging`` (the pool) <- ``generate``
+    (the engine): read from the sources, nothing executed. The two
+    lower modules name ``models.generate`` nowhere, and the engine
+    takes ``paging`` at the top of its file, never inside a function
+    (one of those ran every decode step)."""
+    import ast
+    import pathlib
+
+    import kubeflow_rm_tpu.models as models
+
+    def imported(node):
+        if isinstance(node, ast.Import):
+            return [a.name for a in node.names]
+        if isinstance(node, ast.ImportFrom):
+            mod = node.module or ""
+            return [mod] + [f"{mod}.{a.name}" for a in node.names]
+        return []
+
+    def tree(name):
+        path = pathlib.Path(models.__file__).with_name(name)
+        return ast.parse(path.read_text())
+
+    for lower in ("decode.py", "paging.py"):
+        names = [n for node in ast.walk(tree(lower))
+                 for n in imported(node)]
+        assert names and not [n for n in names
+                              if n.rsplit(".", 1)[-1] == "generate"
+                              or ".generate." in n], lower
+    engine = tree("generate.py")
+    lazy = [n for fn in ast.walk(engine)
+            if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef))
+            for node in ast.walk(fn) for n in imported(node)
+            if "paging" in n]
+    assert lazy == []
+    top = [n for node in engine.body for n in imported(node)]
+    assert "kubeflow_rm_tpu.models.paging" in top
+    assert "kubeflow_rm_tpu.models.decode" in top

@@ -149,7 +149,7 @@ def test_exit_2_on_harness_mismatch(tmp_path):
     base = _trace(1000.0, BASE_HOPS,
                   build_run_meta("spawn_conformance", {}))
     fresh = _trace(1000.0, BASE_HOPS,
-                   build_run_meta("serve_bench", {}))
+                   build_run_meta("e2e_walk", {}))
     rc = main(["--baseline-trace", _write(tmp_path, "b.json", base),
                "--trace", _write(tmp_path, "f.json", fresh)])
     assert rc == 2

@@ -25,6 +25,9 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
 from perf import trace_reduce  # noqa: E402
 
 PROMPT = [5, 9, 2, 7, 1, 8, 3, 4, 6]
+# two whole blocks and half of a third (``block_size`` 8): a repeat
+# adopts the two and forks the third copy-on-write
+SHARED = list(range(1, 21))
 
 
 @pytest.fixture(scope="module")
@@ -52,13 +55,14 @@ def _submit(model, eng, seating, prompt, budget=4):
 # ---- (a) the timeline, on all four ways a request is seated -----------
 
 @pytest.mark.parametrize("seating", ["paged", "chain-install",
-                                     "contiguous", "speculative"])
+                                     "prefix-hit", "speculative"])
 def test_every_finished_request_carries_an_ordered_timeline(model, seating):
-    eng = _engine(model, paged=seating != "contiguous")
+    eng = _engine(model)
     before = time.perf_counter()
     # one slot: the second request waits for the first one's
-    reqs = [_submit(model, eng, seating, PROMPT),
-            _submit(model, eng, seating, PROMPT[::-1])]
+    prompts = ([SHARED, SHARED] if seating == "prefix-hit"
+               else [PROMPT, PROMPT[::-1]])
+    reqs = [_submit(model, eng, seating, p) for p in prompts]
     assert all(r.t_submitted >= before and r.t_admitted is None
                for r in reqs)
     eng.run()
@@ -74,12 +78,15 @@ def test_every_finished_request_carries_an_ordered_timeline(model, seating):
     assert second.t_admitted >= first.t_finished
     assert not hasattr(first, "submitted_step")
     assert not hasattr(first, "finished_step")
+    if seating == "prefix-hit":
+        stats = eng.stats()
+        assert stats["cow_forks"] == 1 and stats["prefix_hit_tokens"] == 19
 
 
 def test_a_request_sent_back_to_the_queue_keeps_no_admission_stamp(model):
     # the pool holds one request's blocks: the second is taken off the
     # queue, finds no blocks and waits at the front
-    eng = _engine(model, paged=True, slots=2, num_blocks=4)
+    eng = _engine(model, slots=2, num_blocks=4)
     a = eng.submit(PROMPT, max_new_tokens=4)
     b = eng.submit(PROMPT[::-1], max_new_tokens=4)
     eng.step()
@@ -111,7 +118,7 @@ def _inside(child, parents):
 
 
 def test_engine_and_gateway_spans_on_the_profilers_clock(model, tmp_path):
-    eng = _engine(model, paged=True, slots=2)
+    eng = _engine(model, slots=2)
     gw = ServingGateway(eng, admission=False)
     try:
         warm = gw.try_submit("t", PROMPT, max_new_tokens=2)[0]
@@ -200,7 +207,7 @@ def traced():
 
 
 def test_submit_and_wait_hands_the_timeline_on(model):
-    gw = ServingGateway(_engine(model, paged=True), admission=False)
+    gw = ServingGateway(_engine(model), admission=False)
     fleet = ServingFleet({"r0": gw})
     try:
         before = time.perf_counter()
@@ -217,7 +224,7 @@ def test_submit_and_wait_hands_the_timeline_on(model):
 
 
 def test_three_request_spans_partition_submit_to_done(model, traced):
-    gw = ServingGateway(_engine(model, paged=True), admission=False)
+    gw = ServingGateway(_engine(model), admission=False)
     fleet = ServingFleet({"r0": gw})
     try:
         t0 = time.time()

@@ -17,12 +17,18 @@ contracts over the shared kv head axis so K/V stay at their true size in
 HBM.
 """
 
+import contextlib
+import contextvars
 import logging
 
 import jax
 import jax.numpy as jnp
 
 log = logging.getLogger(__name__)
+
+# the lists ``kernel_choices`` has open in this context (thread, task)
+_recording: contextvars.ContextVar[tuple[list, ...]] = \
+    contextvars.ContextVar("kernel_choices", default=())
 
 NEG_INF = -2.0**30  # large-but-finite: keeps fp32 softmax NaN-free on fully masked rows
 
@@ -53,6 +59,23 @@ def _log_choice(kernel: str, interpret: bool, platform: str,
     runs while tracing, so a compiled program says it once."""
     log.info("attention kernel=%s interpret=%s platform=%s devices=%d "
              "(%s)", kernel, interpret, platform, n_devices, why)
+    for chosen in _recording.get():
+        chosen.append(kernel)
+
+
+@contextlib.contextmanager
+def kernel_choices():
+    """The kernels (``_log_choice``'s names: "flash", "xla", ...) that
+    the attention calls traced inside the block were given, in order.
+    For a caller that has to know what its forward pass runs on — the
+    choice is made layers below it, under ``scan`` and ``checkpoint``,
+    where nothing can be handed back up."""
+    chosen: list[str] = []
+    token = _recording.set((*_recording.get(), chosen))
+    try:
+        yield chosen
+    finally:
+        _recording.reset(token)
 
 
 def attention_mask(
@@ -84,24 +107,22 @@ def attention_mask(
     return mask
 
 
-def flash_eligible(q, k, *, causal, positions_q, bias) -> bool:
+def flash_eligible(q, k, *, causal, positions_q, bias,
+                   segment_ids_q=None) -> bool:
     """Can the pallas flash kernel handle this call exactly?
 
     Requires: causal self-attention over local indices (no explicit
     positions — packed sequences are covered because local-causal ∧
     same-segment ≡ position-causal ∧ same-segment, see
     ``flash_attention`` docstring), no additive bias, and shapes that
-    tile the block sizes the kernel will actually pick.
+    tile the block sizes the kernel will actually pick (another tile
+    for a call with segment ids than for one without).
     """
-    from kubeflow_rm_tpu.ops.flash_attention import (
-        DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, pick_block,
-    )
+    from kubeflow_rm_tpu.ops.flash_attention import tile_for
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
-    bq = pick_block(DEFAULT_BLOCK_Q, Tq)
-    bk = pick_block(DEFAULT_BLOCK_K, Tk)
     return (causal and bias is None and positions_q is None
-            and Tq == Tk and bq > 0 and bk > 0
+            and Tq == Tk and all(tile_for(Tq, segment_ids_q is not None))
             and D % 8 == 0)
 
 
@@ -153,7 +174,8 @@ def dot_product_attention(
     platform, n_devices = computation_devices(q, mesh)
     use_flash = impl == "flash"
     if impl == "auto" and flash_eligible(
-            q, k, causal=causal, positions_q=positions_q, bias=bias):
+            q, k, causal=causal, positions_q=positions_q, bias=bias,
+            segment_ids_q=segment_ids_q):
         # one device only: pallas_call has no GSPMD partitioning rule,
         # so under a multi-chip jit the compiler would all-gather the
         # FULL global q/k/v onto every device — silently defeating

@@ -1,27 +1,38 @@
-"""Flash attention — pallas TPU kernel for the hot op.
+"""Flash attention — pallas TPU kernels for the hot op, forward and backward.
 
 Round 1 materialized a (B, H, T, T) score tensor per layer
 (``ops/attention.py``), which caps usable context and burns HBM
 bandwidth on the one tensor XLA cannot fuse away. This module is the
 promised slot-in (VERDICT "weak" #5): a blockwise online-softmax
-forward in pallas — scores never leave VMEM — plus a memory-efficient
-blockwise backward from saved logsumexp residuals.
+forward in pallas — scores never leave VMEM — plus a blockwise pallas
+backward from saved logsumexp residuals.
 
 Design (pallas_guide.md patterns):
 - grid = (batch·heads, q_blocks, kv_blocks), kv innermost and marked
   "arbitrary" so the (m, l, acc) VMEM scratch carries across kv steps;
   the output block writes once on the final kv step.
-- **Causal block skipping**: fully-future kv blocks are skipped with
-  ``pl.when`` — ~half the MXU work for causal training, the same
-  saving the zigzag ring schedule gets at the slice level.
+- **Tile skipping**: a tile in which no query attends any key is
+  neither multiplied nor fetched. Without segment ids that is the
+  causal test alone (fully-future kv blocks, ~half the MXU work). With
+  segment ids a small table, made from them in plain ``jax.numpy``
+  (``live_tiles``), says per batch row and tile whether the causal
+  test passes AND the q block's range of ids overlaps the kv block's:
+  disjoint ranges hold no equal ids, so the test is sound for any ids
+  and tight for rows whose ids rise (``pack_documents``). The table
+  reaches the kernels by scalar prefetch; ``pl.when`` reads it, and the
+  index maps send a tile that is not live to the block of the nearest
+  live one, so the pipeline sees a repeated index and issues no DMA.
+  A skipped tile is exactly a no-op of the arithmetic (``p`` zeroed
+  under the mask, ``m_new = m_prev``, ``corr = 1``).
 - GQA without repetition: q is laid out (B·KVH·G, T, D) while k/v stay
   (B·KVH, T, D); the kv index map divides by G, so repeated heads are
   a VMEM aliasing trick, not an HBM copy.
-- Backward is blockwise XLA (scan over kv blocks for dq; over q blocks
-  for dk/dv) using the softmax residual lse = m + log l — standard
-  flash-attention calculus, O(T·block) memory, MXU-shaped matmuls.
-  A hand-scheduled pallas backward can replace it behind the same
-  custom_vjp without touching callers.
+- Backward is two pallas kernels (``_flash_bwd_pallas``: dq over a
+  (BH, nq, nk) grid, dk/dv over (BH, nk, nq)) using the softmax
+  residual lse = m + log l — standard flash-attention calculus,
+  O(T·block) memory, MXU-shaped matmuls, the forward's tile skipping.
+  The blockwise XLA scan it replaced (``_flash_bwd_xla``) is kept as
+  the reference behind ``BACKWARD_IMPL``.
 
 Semantics: causal over LOCAL indices + optional segment ids. This is
 exactly the packed-documents contract (``training/data.pack_documents``):
@@ -35,6 +46,7 @@ ring schedule in ``parallel/ring_attention.py``.
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -44,19 +56,55 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.0**30
 
-# 1024 blocks measured best on v5e for the bench1b shapes (53.4% MFU
-# vs 51.1% at 512, 44.0% at 256, with the pallas backward): fewer,
-# bigger MXU panels beat finer-grained causal skipping. ``pick_block``
-# degrades to the largest divisor of T so sequence lengths that are
-# multiples of 128 but not 1024 (1280, 1536, ...) stay on the kernel.
-import os
-
-# KFRM_FLASH_BLOCK overrides both defaults (KFRM_FLASH_BLOCK_Q/_K win
+# Without segment ids: 1024 blocks measured best on v5e for the
+# bench1b shapes (53.4% MFU vs 51.1% at 512, 44.0% at 256, with the
+# pallas backward): fewer, bigger MXU panels beat finer-grained causal
+# skipping. With segment ids the tile also decides how much of the
+# triangle the documents leave, and 512 x 1024 (q x kv) measured best
+# at 1 x 32 heads x 4096 x 128, 8 kv heads, on rows of log-normal
+# documents of 427 tokens at the mean (forward + dq + dk/dv of one
+# call, ms on the v5e, two sets of 16 rows; PERF.md section 6, PR 39):
+#
+#     tile       1024x1024  512x1024  1024x512  512x512  256x512  256x256
+#     live share   0.73       0.61      0.62      0.49     0.43     0.34
+#     ms          4.65-4.72  4.39-4.65  4.69      4.56-4.71 5.66-5.88 8.64-8.85
+#
+# (the parent, every causal tile of 1024: 6.18-6.20). A finer tile
+# leaves less area and loses it again to what a grid step costs whatever
+# its size: about 0.7 us a live step and 0.25 us a skipped one in the
+# forward, beside 1.2 us for each 512 x 512 of area.
+# ``pick_block`` degrades to the largest divisor of T so sequence
+# lengths that are multiples of 128 but not of the block (1280, 1536,
+# ...) stay on the kernel.
+#
+# KFRM_FLASH_BLOCK overrides every default (KFRM_FLASH_BLOCK_Q/_K win
 # for asymmetric grids) — the bench sweep's knob; code callers pass
 # block_q/block_k explicitly.
-_BLOCK_ENV = os.environ.get("KFRM_FLASH_BLOCK", 1024)
-DEFAULT_BLOCK_Q = int(os.environ.get("KFRM_FLASH_BLOCK_Q", _BLOCK_ENV))
-DEFAULT_BLOCK_K = int(os.environ.get("KFRM_FLASH_BLOCK_K", _BLOCK_ENV))
+
+
+def _default_block(axis: str, measured: int) -> int:
+    return int(os.environ.get(
+        f"KFRM_FLASH_BLOCK_{axis}",
+        os.environ.get("KFRM_FLASH_BLOCK", measured)))
+
+
+DEFAULT_BLOCK_Q = _default_block("Q", 1024)
+DEFAULT_BLOCK_K = _default_block("K", 1024)
+PACKED_BLOCK_Q = _default_block("Q", 512)
+PACKED_BLOCK_K = _default_block("K", 1024)
+
+
+def tile_for(T: int, segmented: bool, block_q: int | None = None,
+             block_k: int | None = None) -> tuple[int, int]:
+    """The (q, kv) tile a length-T call runs at: what was asked for,
+    else the measured default for a call that carries segment ids or
+    for one that does not, each through ``pick_block`` (so 0 where
+    nothing divides T)."""
+    if block_q is None:
+        block_q = PACKED_BLOCK_Q if segmented else DEFAULT_BLOCK_Q
+    if block_k is None:
+        block_k = PACKED_BLOCK_K if segmented else DEFAULT_BLOCK_K
+    return pick_block(block_q, T), pick_block(block_k, T)
 
 
 def pick_block(preferred: int, T: int) -> int:
@@ -77,13 +125,109 @@ def pick_block(preferred: int, T: int) -> int:
 
 
 # ---------------------------------------------------------------------
+# which tiles the segment ids leave
+# ---------------------------------------------------------------------
+
+def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int):
+    """(nq, nk) bool, the kernels' causal test: a kv block strictly in
+    the future of every query row of the q block holds no work."""
+    i = jnp.arange(nq)[:, None]
+    j = jnp.arange(nk)[None, :]
+    return j * block_k <= i * block_q + (block_q - 1)
+
+
+def live_tiles(segq, segkv, block_q: int, block_k: int,
+               causal: bool = True) -> jax.Array:
+    """(B, nq, nk) bool: may any query of q block i attend any key of
+    kv block j? True iff the tile passes the causal test and the two
+    blocks' ranges of segment ids overlap. Disjoint ranges hold no
+    equal ids, so no tile with an attending pair is ever dropped,
+    whatever the ids (padding's 0, ids out of order); for ids that rise
+    along the row (``pack_documents``) overlapping ranges share an id,
+    so no tile is kept in vain either."""
+    B, T = segq.shape
+    q = segq.reshape(B, T // block_q, block_q)
+    kv = segkv.reshape(B, segkv.shape[1] // block_k, block_k)
+    live = ((q.min(-1)[:, :, None] <= kv.max(-1)[:, None, :])
+            & (kv.min(-1)[:, None, :] <= q.max(-1)[:, :, None]))
+    if causal:
+        live &= _causal_tiles(q.shape[1], kv.shape[1], block_q, block_k)
+    return live
+
+
+def flash_tile_counts(segment_ids_q, segment_ids_kv=None, *,
+                      causal: bool = True, block_q: int | None = None,
+                      block_k: int | None = None):
+    """(live, causal): how many tiles the kernels run for these (B, T)
+    segment ids — the table they are handed, counted — and how many
+    the causal test alone would leave, over all rows. The tile
+    defaults to the one ``flash_attention`` picks for a call with
+    segment ids."""
+    if segment_ids_kv is None:
+        segment_ids_kv = segment_ids_q
+    B, T = segment_ids_q.shape
+    block_q, block_k = tile_for(T, True, block_q, block_k)
+    live = live_tiles(segment_ids_q, segment_ids_kv, block_q, block_k,
+                      causal)
+    nq, nk = live.shape[1:]
+    return jnp.sum(live), B * (
+        jnp.sum(_causal_tiles(nq, nk, block_q, block_k)) if causal
+        else nq * nk)
+
+
+def _nearest_live(live, axis: int) -> jax.Array:
+    """int32, ``live``'s shape: along ``axis`` each tile's own index
+    where it is live, else the index of the last live tile before it,
+    else (none before) of the first live one. A tile is live iff the
+    entry equals its index; a block index map that reads the entry
+    repeats an index over every run of skipped tiles."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, live.shape, axis)
+    last = jax.lax.cummax(jnp.where(live, idx, -1), axis=axis)
+    first = jnp.argmax(live, axis=axis, keepdims=True).astype(jnp.int32)
+    return jnp.where(last < 0, first, last)
+
+
+def _fetch_entry(fetch_ref, heads: int, b, outer, inner, n_outer,
+                 n_inner):
+    """Grid step (b, outer, inner)'s entry of a flattened
+    (B, n_outer, n_inner) ``_nearest_live`` table."""
+    return fetch_ref[((b // heads) * n_outer + outer) * n_inner + inner]
+
+
+def _inner_block(heads: int, n_outer: int, n_inner: int):
+    """For index maps: the block a grid step (b, outer, inner) fetches
+    along its innermost axis. With a table (the maps' last argument
+    under scalar prefetch) a skipped tile repeats the nearest live
+    one's block, so its operands cost no DMA; without one, ``inner``."""
+    def block(b, outer, inner, *fetch):
+        return (_fetch_entry(fetch[0], heads, b, outer, inner, n_outer,
+                             n_inner) if fetch else inner)
+    return block
+
+
+def _tile_runs(fetch_ref, heads: int, causal: bool, i, j, block_q: int,
+               block_k: int):
+    """Does this grid step's tile hold any work? Without a table the
+    causal test: a kv block strictly in the future of every query row
+    of the q block contributes nothing. With one, what it says of the
+    step (whose innermost grid axis is the table's last)."""
+    if fetch_ref is None:
+        return (not causal) or (j * block_k <= i * block_q + (block_q - 1))
+    inner = pl.program_id(2)
+    return _fetch_entry(fetch_ref, heads, pl.program_id(0),
+                        pl.program_id(1), inner, pl.num_programs(1),
+                        pl.num_programs(2)) == inner
+
+
+# ---------------------------------------------------------------------
 # forward kernel
 # ---------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segkv_ref,
+def _fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, segq_ref, segkv_ref,
                 o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
-                *, scale: float, causal: bool, block_q: int, block_k: int):
+                *, scale: float, causal: bool, block_q: int, block_k: int,
+                heads: int):
     i = pl.program_id(1)   # q block
     j = pl.program_id(2)   # kv block
     nk = pl.num_programs(2)
@@ -94,11 +238,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segkv_ref,
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # causal: a kv block strictly in the future of every query row of
-    # this q block contributes nothing — skip its matmuls entirely
-    run = (not causal) or (j * block_k <= i * block_q + (block_q - 1))
-
-    @pl.when(run)
+    @pl.when(_tile_runs(fetch_ref, heads, causal, i, j, block_q, block_k))
     def _step():
         q = q_ref[0]                     # (bq, D)
         k = k_ref[0]                     # (bk, D)
@@ -159,6 +299,54 @@ def _flash(q, k, v, segq, segkv, causal, block_q, block_k, group,
     return out
 
 
+def _bind(kernel, name: str, n_inputs: int, segmented: bool, **static):
+    """``kernel(fetch, *inputs, segq, segkv, *outputs_and_scratch)`` as
+    the function pallas calls: with segment ids it is handed exactly
+    those refs, without them neither the table nor the two id blocks.
+    ``name`` is the lowered custom call's ``kernel_name``; the callers
+    pass the names these calls have always lowered under, so a program
+    without segment ids keeps its text (and its compile-cache key)."""
+    def bound(*refs):
+        if not segmented:
+            refs = (None, *refs[:n_inputs], None, None, *refs[n_inputs:])
+        return kernel(*refs, **static)
+    bound.__name__ = name
+    return bound
+
+
+def _tiled_call(kernel, fetch, args, *, interpret, out_shape,
+                **grid_spec):
+    """The ``pallas_call`` the three kernels share, made on ``args``.
+    ``fetch`` (a ``_nearest_live`` table, or None) goes in flattened by
+    scalar prefetch: the kernel's first ref and every index map's last
+    argument."""
+    if fetch is None:
+        spec = pl.GridSpec(**grid_spec)
+    else:
+        spec = pltpu.PrefetchScalarGridSpec(num_scalar_prefetch=1,
+                                            **grid_spec)
+        args = [fetch.reshape(-1), *args]
+    return pl.pallas_call(
+        kernel,
+        grid_spec=spec,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(*args)
+
+
+def _segment_operands(segq, segkv, block_q, block_k, causal):
+    """What a call with segment ids adds: the ids sublane-padded
+    (B, T) -> (B, 8, T) for the (8, 128) tiling floor, and the
+    (B, nq, nk) liveness of its tiles."""
+    B, T = segq.shape
+    return (jnp.broadcast_to(segq[:, None, :], (B, 8, T)),
+            jnp.broadcast_to(segkv[:, None, :], (B, 8, T)),
+            live_tiles(segq, segkv, block_q, block_k, causal))
+
+
 def _flash_call(q, k, v, segq, segkv, causal, block_q, block_k, group,
                 interpret):
     """q: (B, KVH*G, T, D); k/v: (B, KVH, T, D);
@@ -171,17 +359,19 @@ def _flash_call(q, k, v, segq, segkv, causal, block_q, block_k, group,
     vf = v.reshape(B * KVH, T, D)
     nq, nk = T // block_q, T // block_k
 
-    def q_map(b, i, j):
+    def q_map(b, i, j, *_):
         return (b, i, 0)
 
-    def kv_map(b, i, j):
-        return (b // group, j, 0)
+    kv_of = _inner_block(Hq, nq, nk)
 
-    def segq_map(b, i, j):
+    def kv_map(b, i, j, *fetch):
+        return (b // group, kv_of(b, i, j, *fetch), 0)
+
+    def segq_map(b, i, j, *_):
         return (b // Hq, 0, i)
 
-    def segkv_map(b, i, j):
-        return (b // Hq, 0, j)
+    def segkv_map(b, i, j, *fetch):
+        return (b // Hq, 0, kv_of(b, i, j, *fetch))
 
     in_specs = [
         pl.BlockSpec((1, block_q, D), q_map),
@@ -189,35 +379,24 @@ def _flash_call(q, k, v, segq, segkv, causal, block_q, block_k, group,
         pl.BlockSpec((1, block_k, D), kv_map),
     ]
     args = [qf, kf, vf]
+    fetch = None
     if segq is not None:
-        # sublane-pad (B, T) -> (B, 8, T) for the (8, 128) tiling floor
-        segq8 = jnp.broadcast_to(segq[:, None, :], (B, 8, T))
-        segkv8 = jnp.broadcast_to(segkv[:, None, :], (B, 8, T))
+        segq8, segkv8, live = _segment_operands(segq, segkv, block_q,
+                                                block_k, causal)
+        fetch = _nearest_live(live, axis=2)
         in_specs += [pl.BlockSpec((1, 8, block_q), segq_map),
                      pl.BlockSpec((1, 8, block_k), segkv_map)]
         args += [segq8, segkv8]
 
-        def kernel(q_ref, k_ref, v_ref, segq_ref, segkv_ref, o_ref,
-                   lse_ref, acc_ref, m_ref, l_ref):
-            return _fwd_kernel(q_ref, k_ref, v_ref, segq_ref, segkv_ref,
-                               o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                               scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                   l_ref):
-            return _fwd_kernel(q_ref, k_ref, v_ref, None, None, o_ref,
-                               lse_ref, acc_ref, m_ref, l_ref,
-                               scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k)
-
-    out, lse = pl.pallas_call(
-        kernel,
+    out, lse = _tiled_call(
+        _bind(_fwd_kernel, "kernel", 3, segq is not None, scale=scale,
+              causal=causal, block_q=block_q, block_k=block_k, heads=Hq),
+        fetch, args,
         grid=(B * Hq, nq, nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, D), q_map),
-            pl.BlockSpec((1, 8, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 8, block_q), lambda b, i, j, *_: (b, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, T, D), q.dtype),
@@ -228,11 +407,8 @@ def _flash_call(q, k, v, segq, segkv, causal, block_q, block_k, group,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
         interpret=interpret,
-    )(*args)
+    )
     return out.reshape(B, Hq, T, D), lse[:, 0, :].reshape(B, Hq, T)
 
 
@@ -276,9 +452,9 @@ def _bwd_mask(i, j, block_q, block_k, causal, segq_ref, segkv_ref):
     return mask
 
 
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dq_kernel(fetch_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                segq_ref, segkv_ref, dq_ref, dq_acc,
-               *, scale, causal, block_q, block_k):
+               *, scale, causal, block_q, block_k, heads):
     i = pl.program_id(1)   # q block (parallel)
     j = pl.program_id(2)   # kv block (arbitrary, accumulated)
     nk = pl.num_programs(2)
@@ -287,9 +463,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    run = (not causal) or (j * block_k <= i * block_q + (block_q - 1))
-
-    @pl.when(run)
+    @pl.when(_tile_runs(fetch_ref, heads, causal, i, j, block_q, block_k))
     def _step():
         q = q_ref[0]
         k = k_ref[0]
@@ -318,9 +492,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[0] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _dkv_kernel(fetch_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 segq_ref, segkv_ref, dk_ref, dv_ref, dk_acc, dv_acc,
-                *, scale, causal, block_q, block_k):
+                *, scale, causal, block_q, block_k, heads):
     j = pl.program_id(1)   # kv block (parallel)
     i = pl.program_id(2)   # q block (arbitrary, accumulated)
     nq = pl.num_programs(2)
@@ -330,9 +504,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    run = (not causal) or (j * block_k <= i * block_q + (block_q - 1))
-
-    @pl.when(run)
+    @pl.when(_tile_runs(fetch_ref, heads, causal, i, j, block_q, block_k))
     def _step():
         q = q_ref[0]
         k = k_ref[0]
@@ -370,11 +542,12 @@ def _flash_bwd_pallas(causal, block_q, block_k, group, interpret, res,
                       do):
     """Hand-scheduled backward: two pallas kernels sharing the forward's
     layout tricks (GQA via kv index-map division, sublane-padded
-    residuals, causal block skipping). dq runs on a (BH, nq, nk) grid
-    with kv innermost; dk/dv on (BH, nk, nq) with q innermost, each
+    residuals, tile skipping). dq runs on a (BH, nq, nk) grid with kv
+    innermost; dk/dv on (BH, nk, nq) with q innermost, each
     accumulating its output block in VMEM across the arbitrary dim —
-    future blocks never issue their matmuls, which is the causal 2x the
-    rectangular XLA scan left on the table."""
+    skipped tiles never issue their matmuls nor their DMAs, which is
+    the causal 2x the rectangular XLA scan left on the table and,
+    with segment ids, the documents' share of the triangle."""
     q, k, v, segq, segkv, out, lse = res
     B, Hq, T, D = q.shape
     KVH = k.shape[1]
@@ -393,44 +566,35 @@ def _flash_bwd_pallas(causal, block_q, block_k, group, interpret, res,
     delta8 = jnp.broadcast_to(delta[:, None, :], (B * Hq, 8, T))
 
     nq, nk = T // block_q, T // block_k
-
-    def q_map_qji(b, i, j):
-        return (b, i, 0)
-
-    def kv_map_qji(b, i, j):
-        return (b // group, j, 0)
-
-    def row_map_qji(b, i, j):
-        return (b, 0, i)
-
-    def segq_map_qji(b, i, j):
-        return (b // Hq, 0, i)
-
-    def segkv_map_qji(b, i, j):
-        return (b // Hq, 0, j)
-
-    # dk/dv grid is (b, j, i): same maps with the roles swapped
-    def q_map_kji(b, j, i):
-        return (b, i, 0)
-
-    def kv_map_kji(b, j, i):
-        return (b // group, j, 0)
-
-    def row_map_kji(b, j, i):
-        return (b, 0, i)
-
-    def segq_map_kji(b, j, i):
-        return (b // Hq, 0, i)
-
-    def segkv_map_kji(b, j, i):
-        return (b // Hq, 0, j)
-
+    args = [qf, kf, vf, dof, lse8, delta8]
     has_seg = segq is not None
+    kv_fetch = q_fetch = None
     if has_seg:
-        segq8 = jnp.broadcast_to(segq[:, None, :], (B, 8, T))
-        segkv8 = jnp.broadcast_to(segkv[:, None, :], (B, 8, T))
+        segq8, segkv8, live = _segment_operands(segq, segkv, block_q,
+                                                block_k, causal)
+        args += [segq8, segkv8]
+        kv_fetch = _nearest_live(live, axis=2)               # (B, nq, nk)
+        q_fetch = _nearest_live(jnp.swapaxes(live, 1, 2), axis=2)
 
-    def specs(q_map, kv_map, row_map, segq_map, segkv_map):
+    def specs(i_of, j_of):
+        """The eight operands' specs from a grid step's q block and kv
+        block (each a function of the step and, with segment ids, the
+        prefetched table)."""
+        def q_map(*g):
+            return (g[0], i_of(*g), 0)
+
+        def kv_map(*g):
+            return (g[0] // group, j_of(*g), 0)
+
+        def row_map(*g):
+            return (g[0], 0, i_of(*g))
+
+        def segq_map(*g):
+            return (g[0] // Hq, 0, i_of(*g))
+
+        def segkv_map(*g):
+            return (g[0] // Hq, 0, j_of(*g))
+
         in_specs = [
             pl.BlockSpec((1, block_q, D), q_map),    # q
             pl.BlockSpec((1, block_k, D), kv_map),   # k
@@ -444,47 +608,35 @@ def _flash_bwd_pallas(causal, block_q, block_k, group, interpret, res,
                          pl.BlockSpec((1, 8, block_k), segkv_map)]
         return in_specs
 
-    args = [qf, kf, vf, dof, lse8, delta8]
-    if has_seg:
-        args += [segq8, segkv8]
+    def outer(b, outer, inner, *_):
+        return outer
 
-    def wrap(kernel):
-        if has_seg:
-            def f(q_r, k_r, v_r, do_r, lse_r, dl_r, sq_r, skv_r, *rest):
-                return kernel(q_r, k_r, v_r, do_r, lse_r, dl_r, sq_r,
-                              skv_r, *rest, scale=scale, causal=causal,
-                              block_q=block_q, block_k=block_k)
-        else:
-            def f(q_r, k_r, v_r, do_r, lse_r, dl_r, *rest):
-                return kernel(q_r, k_r, v_r, do_r, lse_r, dl_r, None,
-                              None, *rest, scale=scale, causal=causal,
-                              block_q=block_q, block_k=block_k)
-        return f
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, heads=Hq)
 
-    dq = pl.pallas_call(
-        wrap(_dq_kernel),
+    dq = _tiled_call(
+        _bind(_dq_kernel, "f", 6, has_seg, **static),
+        kv_fetch, args,
         grid=(B * Hq, nq, nk),
-        in_specs=specs(q_map_qji, kv_map_qji, row_map_qji,
-                       segq_map_qji, segkv_map_qji),
-        out_specs=pl.BlockSpec((1, block_q, D), q_map_qji),
+        in_specs=specs(outer, _inner_block(Hq, nq, nk)),
+        out_specs=pl.BlockSpec((1, block_q, D),
+                               lambda b, i, j, *_: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * Hq, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
         interpret=interpret,
-    )(*args)
+    )
 
     # dk/dv per Q-HEAD (B*Hq) — grouped heads fold onto their shared kv
-    # head afterwards, so no two grid rows write the same output block
-    dk_h, dv_h = pl.pallas_call(
-        wrap(_dkv_kernel),
+    # head afterwards, so no two grid rows write the same output block.
+    # The grid is (b, j, i): the same maps with the roles swapped
+    dk_h, dv_h = _tiled_call(
+        _bind(_dkv_kernel, "f", 6, has_seg, **static),
+        q_fetch, args,
         grid=(B * Hq, nk, nq),
-        in_specs=specs(q_map_kji, kv_map_kji, row_map_kji,
-                       segq_map_kji, segkv_map_kji),
+        in_specs=specs(_inner_block(Hq, nk, nq), outer),
         out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j, i, *_: (b, j, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, j, i, *_: (b, j, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * Hq, T, D), k.dtype),
@@ -492,11 +644,8 @@ def _flash_bwd_pallas(causal, block_q, block_k, group, interpret, res,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
                         pltpu.VMEM((block_k, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
         interpret=interpret,
-    )(*args)
+    )
 
     dq = dq.reshape(B, Hq, T, D)
     dk = dk_h.reshape(B, KVH, group, T, D).sum(axis=2)
@@ -588,15 +737,17 @@ def flash_attention(
     causal: bool = True,
     segment_ids_q: jax.Array | None = None,
     segment_ids_kv: jax.Array | None = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Flash attention with the ``dot_product_attention`` layout:
     q (B, T, H, D); k, v (B, T, KVH, D) → (B, T, H, D).
 
     Causality is over local indices; combined with segment ids this is
-    exact for packed documents (module docstring). ``interpret=None``
+    exact for packed documents (module docstring). ``block_q`` /
+    ``block_k`` default as ``tile_for`` says, by whether the call
+    carries segment ids. ``interpret=None``
     selects the pallas interpreter when the operands' computation is
     not for a TPU (``ops.attention.computation_devices``), so tests run
     on CPU; the interpreter is refused for a TPU computation.
@@ -605,8 +756,8 @@ def flash_attention(
     KVH = k.shape[2]
     assert H % KVH == 0
     group = H // KVH
-    block_q = pick_block(block_q, T)
-    block_k = pick_block(block_k, T)
+    block_q, block_k = tile_for(T, segment_ids_q is not None, block_q,
+                                block_k)
     if not block_q or not block_k:
         raise ValueError(
             f"T={T} has no 128-multiple block divisor; use the XLA path")

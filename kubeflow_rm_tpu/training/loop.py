@@ -67,6 +67,10 @@ class LoopMetrics:
     # transfer cost the double-buffering hid behind update compute
     offload_transfer_ms: float = 0.0
     offload_overlap_frac: float = 0.0
+    # packed batches on the flash kernels only: the share of the causal
+    # tiles the step's segment ids left the kernels to visit (the mean
+    # over its microbatches)
+    flash_tiles_live_share: float | None = None
 
 
 def fit(
@@ -187,6 +191,9 @@ def fit(
                             m.get("offload_transfer_ms", 0.0)),
                         offload_overlap_frac=float(
                             m.get("offload_overlap_frac", 0.0)),
+                        flash_tiles_live_share=(
+                            float(m["flash_tiles_live_share"])
+                            if "flash_tiles_live_share" in m else None),
                     )
                     history.append(rec)
                     log.info("step %d loss %.4f %.0f tok/s mfu %s",

@@ -30,6 +30,8 @@ from kubeflow_rm_tpu.models import (
     forward_with_aux,
     init_params,
 )
+from kubeflow_rm_tpu.ops.attention import kernel_choices
+from kubeflow_rm_tpu.ops.flash_attention import flash_tile_counts
 from kubeflow_rm_tpu.ops.losses import softmax_cross_entropy
 from kubeflow_rm_tpu.parallel.sharding import batch_pspec, param_shardings
 from kubeflow_rm_tpu.training.optim import (
@@ -169,19 +171,25 @@ def loss_fn(params, batch, cfg: TrainConfig,
     kwargs = dict(positions=batch.get("positions"),
                   segments=batch.get("segments"),
                   packed=batch.get("segments") is not None)
-    if mesh is not None and mesh.shape.get("pp", 1) > 1:
-        from kubeflow_rm_tpu.parallel.pipeline import (
-            pipeline_forward_with_aux,
-        )
-        logits, router_aux = pipeline_forward_with_aux(
-            params, batch["tokens"], cfg.model, mesh,
-            n_microbatches=n_microbatches, **kwargs)
-    else:
-        logits, router_aux = forward_with_aux(params, batch["tokens"],
-                                              cfg.model, mesh=mesh,
-                                              **kwargs)
+    with kernel_choices() as attention:
+        if mesh is not None and mesh.shape.get("pp", 1) > 1:
+            from kubeflow_rm_tpu.parallel.pipeline import (
+                pipeline_forward_with_aux,
+            )
+            logits, router_aux = pipeline_forward_with_aux(
+                params, batch["tokens"], cfg.model, mesh,
+                n_microbatches=n_microbatches, **kwargs)
+        else:
+            logits, router_aux = forward_with_aux(params, batch["tokens"],
+                                                  cfg.model, mesh=mesh,
+                                                  **kwargs)
     loss, aux = softmax_cross_entropy(logits, batch["labels"],
                                       z_loss=cfg.z_loss)
+    if kwargs["packed"] and "flash" in attention:
+        # how much of the causal triangle this microbatch's documents
+        # left the flash kernels to visit (two reductions over the ids)
+        live, causal = flash_tile_counts(batch["segments"])
+        aux = dict(aux, flash_tiles_live_share=live / causal)
     if router_aux is not None:
         aux = dict(aux, router_aux=router_aux)
         loss = loss + cfg.model.moe.router_aux_weight * router_aux

@@ -180,3 +180,52 @@ def test_factored_optimizer_with_grad_accum(tiny_cfg, devices8):
     for _, batch in zip(range(3), batches):
         state, metrics = step(state, shard_batch(batch, mesh))
     assert np.isfinite(float(metrics["loss"]))
+
+
+@pytest.mark.parametrize("path", ["flash_packed", "flash_unpacked",
+                                  "xla_packed"])
+def test_flash_tiles_live_share_is_logged_only_on_the_packed_flash_path(
+        path, devices8):
+    """``fit()`` logs the share of causal tiles the step's segment ids
+    left the flash kernels to visit — when segment ids reach the flash
+    kernels (forced here, as the chip's "auto" cannot be on a CPU), and
+    at no other time; it is the count ``flash_tile_counts`` makes of
+    the batch, averaged over the microbatches."""
+    from functools import partial
+    from unittest import mock
+
+    from kubeflow_rm_tpu.models import llama
+    from kubeflow_rm_tpu.ops import dot_product_attention
+    from kubeflow_rm_tpu.ops import flash_attention as fa
+    from kubeflow_rm_tpu.training.loop import LoopConfig, fit
+
+    cfg = TrainConfig(model=LlamaConfig.tiny(max_seq_len=256),
+                      optim=OptimConfig(learning_rate=1e-3))
+    mesh = make_mesh(MeshConfig(fsdp=1), devices8[:1])
+    rng = np.random.default_rng(0)
+    docs = [rng.integers(1, 200, size=n).tolist()
+            for n in (90, 30, 150, 20, 70, 200, 60, 45, 110)]
+    batch = {k: v[:2] for k, v in pack_documents(docs, seq_len=256).items()}
+    if path == "flash_unpacked":
+        batch = {k: batch[k] for k in ("tokens", "labels")}
+    impl = "xla" if path == "xla_packed" else "flash"
+    # rows this short would be one 256 tile each: 64 leaves something
+    # to skip
+    with mock.patch.object(llama, "dot_product_attention",
+                           partial(dot_product_attention, impl=impl)), \
+            mock.patch.object(fa, "PACKED_BLOCK_Q", 64), \
+            mock.patch.object(fa, "PACKED_BLOCK_K", 64):
+        _, history = fit(cfg, mesh, [batch, batch],
+                         LoopConfig(total_steps=2, log_every=1,
+                                    grad_accum=2))
+        shares = [rec.flash_tiles_live_share for rec in history]
+        if path != "flash_packed":
+            assert shares == [None, None]
+            return
+        # a row a microbatch
+        counts = [fa.flash_tile_counts(batch["segments"][r:r + 1])
+                  for r in range(2)]
+    assert all(int(causal) == 10 and int(live) < 10
+               for live, causal in counts)
+    want = np.mean([int(live) / int(causal) for live, causal in counts])
+    np.testing.assert_allclose(shares, [want, want], rtol=1e-6)

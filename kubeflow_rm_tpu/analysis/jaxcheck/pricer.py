@@ -66,6 +66,12 @@ _MODEL_DIM_KEYS = ("dim", "n_layers", "n_heads", "n_kv_heads",
                    "hidden_dim", "vocab_size")
 
 
+#: keys of ``models.nemotron_h.NemotronHConfig`` a Llama has none of
+_HYBRID_KEYS = frozenset({"pattern", "mamba_heads", "mamba_head_dim",
+                          "state_size", "n_routed_experts",
+                          "experts_held", "latent_dim"})
+
+
 class DeclarationError(ValueError):
     """The declared-workload JSON is malformed or out of bounds."""
 
@@ -123,6 +129,19 @@ def parse(raw: str | dict) -> DeclaredWorkload:
 
     preset = raw.get("preset")
     model_raw = raw.get("model")
+    # the walk traces the dense decoder's training step: a declaration
+    # of another family is refused by name, not priced as if its
+    # Llama-shaped keys were the whole model
+    family = raw.get("family", "llama")
+    foreign = (sorted(_HYBRID_KEYS & set(model_raw))
+               if isinstance(model_raw, dict) else [])
+    if family != "llama" or foreign:
+        raise DeclarationError(
+            f"model family {family!r}"
+            + (f" (keys {foreign})" if foreign else "")
+            + " is not priced: the pricer walks the dense decoder's "
+              "training step only (models.nemotron_h has no training "
+              "path)")
     if preset is not None:
         from kubeflow_rm_tpu.models.llama import LlamaConfig
         if not isinstance(preset, str) or not hasattr(LlamaConfig,

@@ -282,6 +282,14 @@ class ServingGateway:
         with self._lock:
             return self.engine.chain_coverage(prompt)
 
+    def device_counters(self) -> dict:
+        """The engine's on-device counters (``ContinuousBatchingEngine
+        .device_counters``: one blocking transfer), under the lock: the
+        cache that holds them is donated by every step of the drain
+        thread. For a reader at a window's two ends, not a step."""
+        with self._lock:
+            return self.engine.device_counters()
+
     def wait(self, pending: _Pending, timeout_s: float = 300.0
              ) -> list[int]:
         if not pending.event.wait(timeout_s):

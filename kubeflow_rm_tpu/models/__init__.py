@@ -6,6 +6,7 @@ import jax
 
 from kubeflow_rm_tpu.models import llama as _llama
 from kubeflow_rm_tpu.models import mixtral as _mixtral
+from kubeflow_rm_tpu.models import nemotron_h as _nemotron_h
 from kubeflow_rm_tpu.models.convert import config_from_hf, from_hf_llama
 from kubeflow_rm_tpu.models.lora import add_lora, lora_mask, merge_lora
 from kubeflow_rm_tpu.models.quantize import (
@@ -32,12 +33,15 @@ from kubeflow_rm_tpu.models.generate import (
 )
 from kubeflow_rm_tpu.models.llama import LlamaConfig, forward
 from kubeflow_rm_tpu.models.mixtral import MixtralConfig
+from kubeflow_rm_tpu.models.nemotron_h import NemotronHConfig
 
 
 def init_params(cfg: LlamaConfig, key: jax.Array) -> dict:
     """Family-correct parameter init for any model config."""
     if isinstance(cfg, MixtralConfig):
         return _mixtral.init_params(cfg, key)
+    if isinstance(cfg, NemotronHConfig):
+        return _nemotron_h.init_params(cfg, key)
     return _llama.init_params(cfg, key)
 
 
@@ -48,6 +52,8 @@ def forward_with_aux(params, tokens, cfg: LlamaConfig, **kwargs):
     schedules when ``cfg.attention_backend`` asks for one."""
     if isinstance(cfg, MixtralConfig):
         return _mixtral.forward(params, tokens, cfg, **kwargs)
+    if isinstance(cfg, NemotronHConfig):
+        return _nemotron_h.forward(params, tokens, cfg, **kwargs), None
     return _llama.forward(params, tokens, cfg, **kwargs), None
 
 
@@ -65,7 +71,7 @@ __all__ = ["BlockPool", "ContinuousBatchingEngine",
            "PagedKVCache", "SLO_CLASSES",
            "init_paged_cache", "paged_decode_step", "paged_prefill",
            "prefix_keys",
-           "LlamaConfig", "MixtralConfig", "add_lora",
+           "LlamaConfig", "MixtralConfig", "NemotronHConfig", "add_lora",
            "config_from_hf",
            "cache_shardings", "decode_chunk", "forward", "forward_with_aux", "from_hf_llama",
            "generate", "generate_fused", "generate_speculative_fused",

@@ -185,3 +185,54 @@ def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
     logits = (x @ maybe_dequant(params["lm_head"], cdt)
               ).astype(jnp.float32)
     return logits, ys
+
+
+def _run_hybrid_blocks(params, cfg, tokens, mask, ssm, conv, attend):
+    """The trunk of a config whose layers are of three kinds
+    (``models.nemotron_h``), beside ``_run_blocks`` and for the same
+    callers: embed, the pattern's layers in order, final norm, head.
+
+    A Python loop over the pattern, not a scan: the kinds differ in
+    weights, state and work, one period (what a chip of the benchmark's
+    deployment holds) has nothing that repeats, and a static layer
+    index lets each stacked leaf be read where it lies. ``mask``
+    (B, Tc) marks the real columns (a right-padded bucket's prefix; at
+    decode the live rows): the others advance no state and reach no
+    expert. ``ssm`` (Lm, B, heads, head_dim, state) and ``conv`` (Lm,
+    B, kernel - 1, conv_dim) are the Mamba layers' state entering the
+    chunk; ``attend(q, k, v, i) -> (attn, ys)`` lands the ``i``-th
+    attention layer's K/V and attends, as ``_run_blocks``' does.
+    Returns (logits, (ssm', conv'), the list of ``ys``, int32
+    (expert assignments held, held experts with a token, 1))."""
+    from kubeflow_rm_tpu.models.nemotron_h import (
+        attention_qkv, latent_moe, mamba_mix,
+    )
+
+    B, Tc = tokens.shape
+    cdt = cfg.dtype
+    x = params["embed"]["tokens"][tokens].astype(cdt)
+    at = {"M": 0, "E": 0, "*": 0}
+    stacks = {"M": "blocks_m", "E": "blocks_e", "*": "blocks_a"}
+    ys = []
+    counts = jnp.zeros((2,), jnp.int32)
+    for kind in cfg.pattern:
+        i = at[kind]
+        at[kind] += 1
+        layer = {k: v[i] for k, v in params[stacks[kind]].items()}
+        h = rms_norm(x, layer["norm"], cfg.norm_eps)
+        if kind == "M":
+            out, s_i, c_i = mamba_mix(cfg, layer, h, ssm[i], conv[i], mask)
+            ssm = ssm.at[i].set(s_i)
+            conv = conv.at[i].set(c_i)
+        elif kind == "E":
+            out, held = latent_moe(cfg, layer, h, mask)
+            counts = counts + jnp.stack(held)
+        else:
+            attn, y = attend(*attention_qkv(cfg, layer, h), i)
+            ys.append(y)
+            out = attn.reshape(B, Tc, -1) @ layer["wo"].astype(cdt)
+        x = x + out
+    x = rms_norm(x, params["norm_f"], cfg.norm_eps)
+    logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
+    counts = jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
+    return logits, (ssm, conv), ys, counts

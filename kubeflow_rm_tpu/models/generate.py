@@ -33,7 +33,9 @@ from kubeflow_rm_tpu.models.decode import (
     _UNFILLED, KVCache, decode_chunk, init_cache,
 )
 from kubeflow_rm_tpu.models.llama import LlamaConfig
-from kubeflow_rm_tpu.models.quantize import unpack_int4_params
+from kubeflow_rm_tpu.models.quantize import (
+    has_int4_leaf, unpack_int4_params,
+)
 from kubeflow_rm_tpu.utils.profiling import annotate as _span
 
 
@@ -555,6 +557,17 @@ class ContinuousBatchingEngine:
     prefix or not. Packed-int4 params are unpacked ONCE at
     construction so per-step cost is the int8→bf16 dequant prologue,
     same as the fixed fused path.
+
+    A config with recurrent layers (``cfg.has_recurrent_state``:
+    ``models.nemotron_h``) runs the same loop over a cache that keeps a
+    recurrent state a slot beside the block pool of its attention
+    layers (``paging.HybridPagedCache``). Such state is not addressable
+    by token block, so for that family a prefix hit is not taken (the
+    whole prompt is prefilled; ``prefix_hits_refused_total`` counts the
+    hits passed over) and the entry points that move or rewind a chain
+    of blocks raise: ``install_chain``, ``adopt_chain``,
+    ``prefill_chain`` and ``speculative=True``. State snapshots at
+    block boundaries would lift both (ROADMAP R7).
     """
 
     def __init__(self, params, cfg, *, slots: int = 8,
@@ -577,9 +590,11 @@ class ContinuousBatchingEngine:
         self.slots = slots
         self.slot_len = slot_len
         self.block_size = block_size
-        # unpack int4 leaves once, outside any per-step work; no-op on
-        # int8/bf16 trees
-        self.params = jax.jit(unpack_int4_params)(params)
+        # unpack int4 leaves once, outside any per-step work; a tree
+        # without one is kept as it is, not copied through a program
+        self.params = (jax.jit(unpack_int4_params)(params)
+                       if has_int4_leaf(params) else params)
+        self._recurrent = bool(cfg.has_recurrent_state)
         # the cache lives where the weights live: a replica whose
         # params were committed to its own chip must not allocate its
         # pool on the default device (every replica of a fleet on
@@ -623,6 +638,7 @@ class ContinuousBatchingEngine:
         self.admitted_by_class = {c: 0 for c in SLO_CLASSES}
         self.prefix_hit_tokens = 0
         self.prompt_tokens = 0
+        self.prefix_hits_refused_total = 0
         # disaggregation + speculative counters
         self.chain_installs = 0
         self.chains_exported = 0
@@ -657,6 +673,7 @@ class ContinuousBatchingEngine:
             raise ValueError(f"unknown slo_class {slo_class!r} "
                              f"(one of {SLO_CLASSES})")
         if speculative:
+            self._refuse_recurrent("speculative decode")
             # one fused program monopolizes the device for the whole
             # generation — a latency-class request must never do that,
             # and prompt-lookup drafting is greedy by construction
@@ -705,6 +722,7 @@ class ContinuousBatchingEngine:
         last-token logits — zero prefill FLOPs on this replica.
         Verification happens here, before queueing: a corrupted chunk
         raises ``ValueError`` and nothing is enqueued."""
+        self._refuse_recurrent("install_chain")
         paging.verify_chain(chain)
         if int(chain["block_size"]) != self.block_size:
             raise ValueError(
@@ -732,6 +750,7 @@ class ContinuousBatchingEngine:
         sharing the prefix hits it like any locally-prefilled chain.
         Returns the number of chunks adopted (0 when the chain is
         already local or the pool is transiently full)."""
+        self._refuse_recurrent("adopt_chain")
         keys = list(zip(chain["covers"], chain["keys"]))
         if len(self.pool.lookup_chain(keys)) == len(keys):
             return 0
@@ -743,8 +762,18 @@ class ContinuousBatchingEngine:
         self.chains_adopted += 1
         return len(blocks)
 
+    def _refuse_recurrent(self, what: str) -> None:
+        if self._recurrent:
+            raise ValueError(
+                f"{what} needs cache state addressable by token block; "
+                f"{type(self.cfg).__name__} has recurrent layers, whose "
+                "state is kept a slot (no snapshots yet)")
+
     def chain_coverage(self, prompt) -> int:
-        """Prompt tokens the local prefix cache already covers."""
+        """Prompt tokens the local prefix cache already covers (for a
+        config with recurrent layers none: a hit is not taken)."""
+        if self._recurrent:
+            return 0
         keys = paging.prefix_keys(prompt, self.block_size)
         chain = self.pool.lookup_chain(keys)
         return keys[len(chain) - 1][0] if chain else 0
@@ -869,6 +898,10 @@ class ContinuousBatchingEngine:
         maxb = self.slot_len // BS
         Tp = len(prompt)
         chain = pool.lookup_chain(keys)
+        if self._recurrent and chain:
+            # the blocks are there, the state that goes with them is not
+            self.prefix_hits_refused_total += 1
+            chain = []
         n_hit = min(keys[len(chain) - 1][0] if chain else 0, Tp - 1)
         # fit: cached tokens + the suffix's padding bucket must fit
         # the strip; dropping back to a block boundary only costs
@@ -928,7 +961,8 @@ class ContinuousBatchingEngine:
         if plan is None:
             return None
         n_hit, shared, fresh, final_row, fork_src, prefill = plan
-        last, tk, tv, tpos = prefill
+        # (with recurrent layers a fifth: the state to install)
+        last, tk, tv, tpos, *state = prefill
         # owned chunks land in their blocks; shared chunks and tail
         # chunks past the allocation divert to SINK (never overwrite a
         # shared block, never touch NULL)
@@ -938,7 +972,7 @@ class ContinuousBatchingEngine:
             self.cache, tk, tv, tpos, jnp.asarray(i, jnp.int32),
             jnp.asarray(final_row, jnp.int32),
             jnp.asarray(dest_row, jnp.int32),
-            jnp.asarray(Tp, jnp.int32))
+            jnp.asarray(Tp, jnp.int32), *state)
         if fork_src is not None:
             self.pool.decref([fork_src])   # unpin the fork source
         self._register_chain(keys, final_row)
@@ -1010,6 +1044,7 @@ class ContinuousBatchingEngine:
         behind as retained (ref-0) prefix cache, so a resumed or
         repeated prompt only prefills its new suffix. Returns ``None``
         on transient block OOM."""
+        self._refuse_recurrent("prefill_chain")
         prompt = [int(t) for t in prompt]
         Tp = len(prompt)
         if Tp == 0:
@@ -1171,6 +1206,24 @@ class ContinuousBatchingEngine:
     def active_slots(self) -> int:
         return sum(r is not None for r in self._slot_req)
 
+    def device_counters(self) -> dict:
+        """The counters the programs keep on the device, in the cache
+        they donate (``paging.HybridPagedCache.counters``), fetched by
+        ONE blocking transfer: call it at a window's two ends, never a
+        step (``stats()`` does no device fetch, the gateway calls it
+        every step). ``*_total`` sum decode steps and prefills,
+        ``decode_*`` are the decode steps' alone; empty for a family
+        whose programs keep none."""
+        if not self._recurrent:
+            return {}
+        names = ("expert_assignments_held_total", "experts_active_total",
+                 "moe_steps_total")
+        dec, pre = np.asarray(jax.device_get(self.cache.counters),
+                              np.int64)
+        out = {n: int(d + p) for n, d, p in zip(names, dec, pre)}
+        out.update({"decode_" + n: int(d) for n, d in zip(names, dec)})
+        return out
+
     def stats(self) -> dict:
         steps = self.decode_steps
         return {
@@ -1196,6 +1249,10 @@ class ContinuousBatchingEngine:
             "prefix_hit_ratio": (
                 self.prefix_hit_tokens / self.prompt_tokens
                 if self.prompt_tokens else 0.0),
+            "prefix_hits_refused_total": self.prefix_hits_refused_total,
+            "recurrent_state_bytes": (
+                self.cache.ssm.nbytes + self.cache.conv.nbytes
+                if self._recurrent else 0),
             "chain_installs": self.chain_installs,
             "chains_exported": self.chains_exported,
             "chains_adopted": self.chains_adopted,

@@ -75,6 +75,12 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    @property
+    def has_recurrent_state(self) -> bool:
+        """Every layer's cache state is a strip of positions (compare
+        ``models.nemotron_h``)."""
+        return False
+
     # ---- presets -----------------------------------------------------
     @staticmethod
     def llama2_7b(**overrides) -> "LlamaConfig":

@@ -59,7 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kubeflow_rm_tpu.models.decode import (
-    _UNFILLED, _cache_attend, _run_blocks,
+    _UNFILLED, _cache_attend, _run_blocks, _run_hybrid_blocks,
 )
 from kubeflow_rm_tpu.models.llama import LlamaConfig
 from kubeflow_rm_tpu.ops.paged_attention import paged_decode_attention
@@ -90,15 +90,60 @@ class PagedKVCache:
     pos_next: jax.Array      # (SLOTS,) int32: next token position
 
 
+@jax.tree_util.register_dataclass
+@dataclass
+class HybridPagedCache:
+    """Two kinds of state in one cache, for a config with recurrent
+    layers (``cfg.has_recurrent_state``): the block pool of its
+    attention layers (``kv``; its ``L`` is their number) and, a slot
+    and Mamba layer each, the recurrent state and the convolution's
+    tail, which are no strip of positions and live outside the pool.
+    Admission writes a prefill's final state over whatever the slot
+    held (``paged_install``); a decode step advances the live rows'
+    and leaves the others' as it was. ``counters`` are the expert
+    layers' own, accumulated on the device by the programs that donate
+    the cache (row 0 the decode steps, row 1 the prefills, added at
+    install): token-expert assignments routed to held experts, held
+    experts with at least one token summed over layers, and programs
+    run. int32: they wrap after 2^31, days of traffic; a reader takes
+    differences."""
+    kv: PagedKVCache
+    ssm: jax.Array           # (Lm, SLOTS, heads, head_dim, state) f32
+    conv: jax.Array          # (Lm, SLOTS, kernel - 1, conv_dim)
+    counters: jax.Array      # (2, 3) int32
+
+
 def init_paged_cache(cfg: LlamaConfig, slots: int, slot_len: int,
                      num_blocks: int, block_size: int) -> PagedKVCache:
+    """The cache of ``cfg``'s family: a ``PagedKVCache`` over every
+    layer, or for a config with recurrent layers a ``HybridPagedCache``
+    whose pool serves the attention layers only."""
+    if cfg.has_recurrent_state:
+        Lm = cfg.pattern.count("M")
+        if "*" not in cfg.pattern:
+            raise ValueError(f"pattern {cfg.pattern!r} has no attention "
+                             "layer for the block pool to serve")
+        return HybridPagedCache(
+            kv=_init_kv_pool(cfg, cfg.pattern.count("*"), slots, slot_len,
+                             num_blocks, block_size),
+            ssm=jnp.zeros((Lm, slots, cfg.mamba_heads, cfg.mamba_head_dim,
+                           cfg.state_size), jnp.float32),
+            conv=jnp.zeros((Lm, slots, cfg.conv_kernel - 1, cfg.conv_dim),
+                           cfg.dtype),
+            counters=jnp.zeros((2, 3), jnp.int32))
+    return _init_kv_pool(cfg, cfg.n_layers, slots, slot_len, num_blocks,
+                         block_size)
+
+
+def _init_kv_pool(cfg, L: int, slots: int, slot_len: int,
+                  num_blocks: int, block_size: int) -> PagedKVCache:
     if slot_len % block_size:
         raise ValueError(f"slot_len {slot_len} must be a multiple of "
                          f"block_size {block_size}")
     if num_blocks <= RESERVED_BLOCKS:
         raise ValueError(f"num_blocks {num_blocks} leaves no usable "
                          f"blocks ({RESERVED_BLOCKS} are reserved)")
-    L, KVH, hd = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+    KVH, hd = cfg.n_kv_heads, cfg.head_dim
     maxb = slot_len // block_size
     return PagedKVCache(
         k=jnp.zeros((L, num_blocks, block_size, KVH, hd), cfg.dtype),
@@ -138,6 +183,22 @@ def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
     newest column. The scan's output is only that column, a layer
     each; one scatter after the scan lands it in the pool.
     """
+    if isinstance(cache, HybridPagedCache):
+        return _hybrid_decode_step(params, cfg, cache, tokens, active)
+    positions, wi, blk, off, kv_positions = _decode_columns(cache, active)
+    logits, (col_k, col_v) = _run_blocks(
+        params, cfg, tokens[:, None], positions,
+        jnp.arange(cache.k.shape[0], dtype=jnp.int32),
+        _pool_attend(cache, positions, wi, active, kv_positions))
+    new_cache = _land_columns(cache, col_k, col_v, blk, off, active)
+    return logits[:, -1, :], new_cache
+
+
+def _decode_columns(cache: PagedKVCache, active):
+    """Where a decode step reads and writes: each row's query position
+    (``_UNFILLED`` where inactive), its write index in its strip, the
+    pool block and offset that index falls in (SINK where inactive) and
+    every slot's strip of positions with this token's among them."""
     B, MAXB = cache.block_tables.shape
     BS = cache.positions.shape[1]
     S = MAXB * BS
@@ -153,23 +214,28 @@ def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
     # block table, this token's among them: the attention mask
     gpos = cache.positions[cache.block_tables].reshape(B, S)
     kv_positions = gpos.at[rows, wi].set(positions[:, 0])
+    return positions, wi, blk, off, kv_positions
 
+
+def _pool_attend(cache: PagedKVCache, positions, wi, active, kv_positions):
+    """The decode step's ``attend``: layer ``layer``'s blocks read in
+    place through the block table, this token's K/V as the newest
+    column; the column is the layer's output."""
     def attend(q, k, v, layer):
         attn = paged_decode_attention(
             q[:, 0], k[:, 0], v[:, 0], cache.k, cache.v, layer,
             cache.block_tables, wi, active,
             positions_q=positions[:, 0], kv_positions=kv_positions)
         return attn[:, None], (k[:, 0], v[:, 0])
+    return attend
 
-    logits, (col_k, col_v) = _run_blocks(
-        params, cfg, tokens[:, None], positions,
-        jnp.arange(cache.k.shape[0], dtype=jnp.int32), attend)
 
-    # scatter the written column, (L, B, KVH, hd), into the pool
-    # (inactive rows land in SINK); duplicate sink hits are
-    # garbage-on-garbage
+def _land_columns(cache: PagedKVCache, col_k, col_v, blk, off, active):
+    """Scatter the written column, (L, B, KVH, hd), into the pool
+    (inactive rows land in SINK; duplicate sink hits are
+    garbage-on-garbage) and advance the live rows' counters."""
     inc = active.astype(jnp.int32)
-    new_cache = PagedKVCache(
+    return PagedKVCache(
         k=cache.k.at[:, blk, off].set(col_k),
         v=cache.v.at[:, blk, off].set(col_v),
         positions=cache.positions.at[blk, off].set(
@@ -178,7 +244,25 @@ def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
         write_idx=cache.write_idx + inc,
         pos_next=cache.pos_next + inc,
     )
-    return logits[:, -1, :], new_cache
+
+
+def _hybrid_decode_step(params, cfg, cache: HybridPagedCache, tokens,
+                        active):
+    """``paged_decode_step`` for a config with recurrent layers: the
+    attention layers as above over ``cache.kv``; a Mamba layer takes
+    one step of its recurrence from the slot's state, live rows only
+    (an inactive row's state and tail come out as they went in, not
+    garbage: the slot may be reseated later, or stay retired); the
+    expert layers see the live rows only and add to the counters."""
+    kv = cache.kv
+    positions, wi, blk, off, kv_positions = _decode_columns(kv, active)
+    logits, (ssm, conv), cols, counts = _run_hybrid_blocks(
+        params, cfg, tokens[:, None], active[:, None], cache.ssm,
+        cache.conv, _pool_attend(kv, positions, wi, active, kv_positions))
+    col_k, col_v = (jnp.stack(c) for c in zip(*cols))
+    return logits[:, -1, :], HybridPagedCache(
+        kv=_land_columns(kv, col_k, col_v, blk, off, active),
+        ssm=ssm, conv=conv, counters=cache.counters.at[0].add(counts))
 
 
 # cache is READ-ONLY here: prefill gathers the shared-prefix strip
@@ -206,10 +290,26 @@ def paged_prefill(params, cfg, cache: PagedKVCache,  # kfrm: disable=KFRM008
     columns bit-identical — both properties are what lets a cached
     prefix + suffix prefill replace solo prefill exactly.
     """
+    if isinstance(cache, HybridPagedCache):
+        return _hybrid_prefill(params, cfg, cache, load_row, n_hit, tokens,
+                               n_real)
+    strips, positions, kv_positions, write_kv = _prefix_strip(
+        cache, load_row, n_hit, tokens.shape[1], n_real)
+    logits, (new_k, new_v) = _run_blocks(
+        params, cfg, tokens, positions, strips,
+        _cache_attend(write_kv, positions, kv_positions))
+    last = logits[0, n_real - 1, :]
+    return last, new_k, new_v, kv_positions
+
+
+def _prefix_strip(cache: PagedKVCache, load_row, n_hit, Tc, n_real):
+    """The one-request strip a prefill runs against: the prefix's
+    blocks gathered and cut at ``n_hit``, the suffix's positions (pad
+    columns ``_UNFILLED``), the strip's positions with the suffix in
+    place, and the write of a layer's K/V at ``n_hit``."""
     L = cache.k.shape[0]
     MAXB, BS = load_row.shape[0], cache.positions.shape[1]
     S = MAXB * BS
-    Tc = tokens.shape[1]
 
     gk = cache.k[:, load_row].reshape(L, 1, S, *cache.k.shape[3:])
     gv = cache.v[:, load_row].reshape(L, 1, S, *cache.v.shape[3:])
@@ -226,16 +326,33 @@ def paged_prefill(params, cfg, cache: PagedKVCache,  # kfrm: disable=KFRM008
     def write_kv(c, val):
         return jax.lax.dynamic_update_slice(c, val, (0, n_hit, 0, 0))
 
-    logits, (new_k, new_v) = _run_blocks(
-        params, cfg, tokens, positions, (gk, gv),
-        _cache_attend(write_kv, positions, kv_positions))
+    return (gk, gv), positions, kv_positions, write_kv
+
+
+def _hybrid_prefill(params, cfg, cache: HybridPagedCache, load_row, n_hit,
+                    tokens, n_real):
+    """``paged_prefill`` for a config with recurrent layers. The whole
+    prompt is prefilled from an empty state (``n_hit`` is 0: a state is
+    not addressable by token block, so the engine takes no prefix hit
+    for this family); the pad columns of the bucket advance no state,
+    so what comes back beside the strip is the state after the last
+    REAL token, with the expert layers' counts, for ``paged_install``."""
+    Tc = tokens.shape[1]
+    (gk, gv), positions, kv_positions, write_kv = _prefix_strip(
+        cache.kv, load_row, n_hit, Tc, n_real)
+    strip_attend = _cache_attend(write_kv, positions, kv_positions)
+    logits, (ssm, conv), strips, counts = _run_hybrid_blocks(
+        params, cfg, tokens, jnp.arange(Tc)[None, :] < n_real,
+        jnp.zeros_like(cache.ssm[:, :1]), jnp.zeros_like(cache.conv[:, :1]),
+        lambda q, k, v, i: strip_attend(q, k, v, (gk[i], gv[i])))
+    new_k, new_v = (jnp.stack(c) for c in zip(*strips))
     last = logits[0, n_real - 1, :]
-    return last, new_k, new_v, kv_positions
+    return last, new_k, new_v, kv_positions, (ssm, conv, counts)
 
 
 @partial(jax.jit, donate_argnames=("cache",))
 def paged_install(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
-                  final_row, dest_row, write_idx0):
+                  final_row, dest_row, write_idx0, prefilled=None):
     """Carve a prefilled temp strip into pool blocks and activate the
     slot. ``dest_row`` (MAXB,) maps each strip chunk to its pool
     destination: the request's OWN blocks for owned chunks, SINK for
@@ -246,7 +363,26 @@ def paged_install(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
     before, after install its visible state is exactly the fresh
     strip's. ``write_idx0`` seats both counters at the REAL prompt
     length, so the first generated token overwrites the first pad
-    column — the same offset solo ``generate_fused`` writes."""
+    column — the same offset solo ``generate_fused`` writes.
+
+    For a ``HybridPagedCache``, ``prefilled`` is the prefill's (recurrent
+    state, convolution tail, expert counts): the first two replace
+    whatever the slot held (the wipe a reseated slot relies on), the
+    counts join the cache's counters."""
+    if isinstance(cache, HybridPagedCache):
+        ssm, conv, counts = prefilled
+        return HybridPagedCache(
+            kv=_install_strip(cache.kv, temp_k, temp_v, temp_pos, slot,
+                              final_row, dest_row, write_idx0),
+            ssm=cache.ssm.at[:, slot].set(ssm[:, 0]),
+            conv=cache.conv.at[:, slot].set(conv[:, 0]),
+            counters=cache.counters.at[1].add(counts))
+    return _install_strip(cache, temp_k, temp_v, temp_pos, slot, final_row,
+                          dest_row, write_idx0)
+
+
+def _install_strip(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
+                   final_row, dest_row, write_idx0):
     L = cache.k.shape[0]
     MAXB, BS = dest_row.shape[0], cache.positions.shape[1]
     chunks_k = temp_k[:, 0].reshape(L, MAXB, BS, *temp_k.shape[3:])

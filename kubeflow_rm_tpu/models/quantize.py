@@ -198,6 +198,11 @@ def unpack_int4(leaf: dict) -> dict:
     return {"q8g": q.reshape(gshape), "s": leaf["s"]}
 
 
+def _is_quant_leaf(x) -> bool:
+    """A quantized weight: a dict the tree walks must not descend."""
+    return isinstance(x, dict) and ("q4" in x or "q8g" in x or "q" in x)
+
+
 def unpack_int4_params(params):
     """Rewrite every packed-int4 leaf in a param tree to its unpacked
     ``{"q8g", "s"}`` form; every other leaf passes through untouched.
@@ -209,11 +214,14 @@ def unpack_int4_params(params):
     return jax.tree_util.tree_map(
         lambda x: unpack_int4(x) if isinstance(x, dict) and "q4" in x
         else x,
-        params,
-        is_leaf=lambda x: isinstance(x, dict) and ("q4" in x or
-                                                   "q8g" in x or
-                                                   "q" in x),
-    )
+        params, is_leaf=_is_quant_leaf)
+
+
+def has_int4_leaf(params) -> bool:
+    """Does the tree hold a packed-int4 leaf for ``unpack_int4_params``
+    to rewrite?"""
+    return any(isinstance(x, dict) and "q4" in x for x in
+               jax.tree_util.tree_leaves(params, is_leaf=_is_quant_leaf))
 
 
 def maybe_dequant(leaf, dtype) -> jax.Array:

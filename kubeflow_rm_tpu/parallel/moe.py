@@ -131,3 +131,132 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoeConfig,
                             params["moe_down"].astype(dtype))
     out = jnp.einsum("nec,ecd->nd", combine.astype(dtype), expert_out)
     return out.reshape(B, T, D), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless experts, a share of them held here
+# ---------------------------------------------------------------------------
+
+
+def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, bias: jax.Array,
+                       top_k: int, scale: float):
+    """Sigmoid routing over every expert the router names: ``h`` (N, D)
+    -> (expert ids (N, k) int32, weights (N, k) float32). The ``top_k``
+    largest of ``sigmoid(h W) + bias`` are chosen (the bias steers the
+    choice only), weighted by their own scores normalised to sum to
+    ``scale``. Float32 at full precision: a routing decision should not
+    flip with the compute dtype's rounding."""
+    s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
+                               router_w.astype(jnp.float32),
+                               precision="highest"))
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, idx, axis=-1)
+    w = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20) * scale
+    return idx.astype(jnp.int32), w
+
+
+def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
+                     up: jax.Array, down: jax.Array, first: int,
+                     top_k: int, scale: float, *,
+                     expert_in: jax.Array | None = None,
+                     live: jax.Array | None = None):
+    """The part of a dropless expert layer that the experts held here
+    give: squared-ReLU experts ``up`` (held, d, f) and ``down``
+    (held, f, d), which are experts ``first .. first + held`` of the
+    ``router_w.shape[1]`` the router chooses among.
+
+    Every token of ``h`` (N, D) is routed over all experts; the
+    assignments whose expert lies in the held range are computed, by
+    one of two dispatches chosen by the static row count: many rows
+    are sorted by expert and run through two grouped matmuls
+    (``_sorted_grouped``), few rows through a loop over the experts
+    that met a token (``_active_experts_loop``); either way an expert
+    nobody chose is not read. Shapes are static: the N x top_k
+    assignments are all carried, those of other chips' experts (and of
+    rows ``live`` (N,) marks dead: padding, empty slots) belong to no
+    group. No capacity, so no token is dropped whatever the imbalance.
+    On one chip the layer runs without its exchange: what the absent
+    experts would add is left out.
+
+    The experts read ``expert_in`` (N, d) where given (a latent
+    projection of ``h``), else ``h``. Returns the (N, d) partial sum in
+    ``expert_in``'s dtype and ``(assignments held, experts with at
+    least one)`` as int32 scalars."""
+    x = h if expert_in is None else expert_in
+    N, held = x.shape[0], up.shape[0]
+    idx, w = route_sigmoid_topk(h, router_w, bias, top_k, scale)
+    local = idx - first
+    mine = (local >= 0) & (local < held)
+    if live is not None:
+        mine = mine & live[:, None]
+    group = jnp.where(mine, local, held)                      # (N, k)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[
+        group.reshape(-1)].add(1)[:held]
+    counts = (jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32))
+    if N <= _FEW_ROWS:
+        out = _active_experts_loop(x, group, w, sizes, up, down)
+    else:
+        out = _sorted_grouped(x, group.reshape(-1), w.reshape(-1), sizes,
+                              up, down, top_k)
+    return out.astype(x.dtype), counts
+
+
+#: at or under this many rows (a decode step's slots, a short prompt's
+#: bucket) an expert's few rows are not worth a grouped matmul's tiles:
+#: every row meets each active expert and a weight of 0 where it did
+#: not choose it. 64 is a guess between the two sizes measured on the
+#: chip, not a swept threshold: at 32 rows the loop read an active
+#: expert at 445 GB/s where the grouped matmul read it at 230; a
+#: 128-row prefill takes the grouped matmul. An engine of more than 64
+#: slots decodes through the grouped matmul until that is swept.
+_FEW_ROWS = 64
+
+
+def _sorted_grouped(x, group, w, sizes, up, down, top_k):
+    """Many rows: the assignments sorted by expert, two grouped matmuls
+    (``lax.ragged_dot``), the sum over a token's choices. ``group``
+    (N k,) is the held expert of an assignment, ``held`` where it has
+    none; ``sizes`` (held,) the assignments an expert."""
+    A = group.shape[0]
+    order = jnp.argsort(group)                                # stable
+    valid = (jnp.arange(A) < jnp.sum(sizes))[:, None]
+    rows = x[order // top_k]
+    a = jax.lax.ragged_dot(rows, up.astype(x.dtype), sizes,
+                           preferred_element_type=jnp.float32)
+    # rows past the last group belong to nobody: whatever the grouped
+    # matmul left there is replaced, not scaled
+    a = jnp.where(valid, jnp.square(jax.nn.relu(a)), 0.0).astype(x.dtype)
+    y = jax.lax.ragged_dot(a, down.astype(x.dtype), sizes,
+                           preferred_element_type=jnp.float32)
+    y = jnp.where(valid, y, 0.0) * w[order][:, None]
+    # back to (token, choice) order, then the sum over a token's choices
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(A, dtype=order.dtype))
+    return jnp.sum(y[back].reshape(-1, top_k, y.shape[-1]), axis=1)
+
+
+def _active_experts_loop(x, group, w, sizes, up, down):
+    """Few rows: a loop over the experts that met a token, and only
+    those (its trip count is their number: an expert nobody chose is
+    never read). Each streams its two matrices once; all N rows are
+    multiplied, which costs nothing beside the read, and a row's result
+    counts with the weight it gave that expert, 0 where it did not
+    choose it."""
+    N, held = x.shape[0], up.shape[0]
+    # (held, N): row n's weight for each held expert
+    dense = jnp.zeros((held, N), jnp.float32).at[
+        group, jnp.arange(N)[:, None]].add(w, mode="drop")
+    active = jnp.nonzero(sizes > 0, size=held, fill_value=0)[0]
+
+    def one(i, acc):
+        e = active[i]
+        a = jnp.dot(x, jax.lax.dynamic_index_in_dim(up, e, keepdims=False)
+                    .astype(x.dtype), preferred_element_type=jnp.float32)
+        a = jnp.square(jax.nn.relu(a)).astype(x.dtype)
+        y = jnp.dot(a, jax.lax.dynamic_index_in_dim(down, e, keepdims=False)
+                    .astype(x.dtype), preferred_element_type=jnp.float32)
+        return acc + jax.lax.dynamic_index_in_dim(
+            dense, e, keepdims=False)[:, None] * y
+
+    return jax.lax.fori_loop(0, jnp.sum(sizes > 0), one,
+                             jnp.zeros((N, down.shape[-1]), jnp.float32))

@@ -75,3 +75,73 @@ def mfu(tokens_per_sec: float, cfg: LlamaConfig, seq_len: int,
     """Model FLOPs utilization in [0, 1]."""
     achieved = tokens_per_sec * train_flops_per_token(cfg, seq_len)
     return achieved / (n_devices * peak_flops_per_device)
+
+
+# ---------------------------------------------------------------------------
+# the hybrid family (models.nemotron_h): serving counts that know the
+# experts held, the assignments that met them and the recurrent state.
+# The benchmark's own copy is perf/flops_nemotron_h.py (a test holds
+# the two equal).
+# ---------------------------------------------------------------------------
+
+
+def hybrid_dense_matmul_params(cfg) -> int:
+    """Matmul parameters every token of a ``NemotronHConfig`` meets:
+    the mixers' projections, the expert layers' router, latent
+    projections and shared expert, the head's rows held. Not the
+    embedding, gains, the convolution nor the routed experts."""
+    Lm, Le, La = (cfg.pattern.count(c) for c in "ME*")
+    D, di, cd = cfg.dim, cfg.d_inner, cfg.conv_dim
+    mamba = D * (di + cd + cfg.mamba_heads) + di * D
+    attn = (2 * D * cfg.n_heads * cfg.head_dim
+            + 2 * D * cfg.n_kv_heads * cfg.head_dim)
+    experts = (D * cfg.n_routed_experts + 2 * D * cfg.latent_dim
+               + 2 * D * cfg.shared_dim)
+    return Lm * mamba + La * attn + Le * experts + D * cfg.vocab_size
+
+
+def hybrid_expert_params(cfg) -> int:
+    """One routed expert: up and down in the latent space."""
+    return 2 * cfg.latent_dim * cfg.expert_dim
+
+
+def hybrid_state_flops_per_token(cfg) -> float:
+    """A token's recurrence over every Mamba layer: decay and outer
+    product into the state (3 a state element), the read-out (2), the
+    convolution's taps (2 a tap a channel)."""
+    return cfg.pattern.count("M") * (
+        5.0 * cfg.d_inner * cfg.state_size
+        + 2.0 * cfg.conv_kernel * cfg.conv_dim)
+
+
+def hybrid_serve_flops(cfg, tokens: float, assignments_held: float,
+                       positions_attended: float) -> float:
+    """Forward work of ``tokens`` fed tokens of which
+    ``assignments_held`` token-expert pairs met an expert held here
+    (``engine.device_counters()``) and which attended
+    ``positions_attended`` positions in each attention layer."""
+    return (2.0 * hybrid_dense_matmul_params(cfg) * tokens
+            + 2.0 * hybrid_expert_params(cfg) * assignments_held
+            + 4.0 * cfg.pattern.count("*") * cfg.n_heads * cfg.head_dim
+            * positions_attended
+            + hybrid_state_flops_per_token(cfg) * tokens)
+
+
+def hybrid_decode_step_bytes(cfg, experts_active: float, live_slots: float,
+                             live_kv_tokens: float,
+                             bytes_per_value: int = 2) -> float:
+    """Bytes one decode step must move once: the matmul weights outside
+    the routed experts (the router's in float32), the weights of the
+    ``experts_active`` held experts that met a token (summed over the
+    expert layers), the live slots' recurrent state read and written
+    with their convolution tails, the keys and values attended."""
+    Lm, Le, La = (cfg.pattern.count(c) for c in "ME*")
+    weights = (bytes_per_value * hybrid_dense_matmul_params(cfg)
+               + (4 - bytes_per_value) * Le * cfg.dim * cfg.n_routed_experts
+               + bytes_per_value * hybrid_expert_params(cfg) * experts_active)
+    state = live_slots * Lm * 2 * (
+        4 * cfg.d_inner * cfg.state_size
+        + bytes_per_value * (cfg.conv_kernel - 1) * cfg.conv_dim)
+    kv = (bytes_per_value * 2.0 * La * cfg.n_kv_heads * cfg.head_dim
+          * live_kv_tokens)
+    return weights + state + kv

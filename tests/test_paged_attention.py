@@ -211,8 +211,9 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("H,kvh", [(32, 8), (16, 16)],
-                         ids=["mistral-gqa", "bench1b-mha"])
+@pytest.mark.parametrize("H,kvh", [(32, 8), (16, 16), (32, 2)],
+                         ids=["mistral-gqa", "bench1b-mha",
+                              "nemotron-gqa16"])
 def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh):
     """Mosaic takes the kernel at the benchmark's and the smoke's
     widths, and the pool goes in as it lies: no copy of it, no
@@ -249,3 +250,59 @@ def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh):
     assert "tpu_custom_call" in compiled.as_text()
     one_block = bs * kvh * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * one_block
+
+
+def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
+        one_chip):
+    """The decode step of the hybrid family at the benchmark's widths
+    and share (one 11-layer period, 128 of 512 experts held, 32 slots
+    of 4096): the chip's compiler takes it, the paged kernel is a
+    custom call, and nothing beside the arguments is of a weight's
+    size: an expert's matrices are read where they lie, by the loop
+    over the experts that met a token (a slice of a stack over the
+    layers would be copied, 705 MB a matrix a layer a step, before a
+    grouped matmul's custom call: why they are an array a layer), and
+    the recurrent state is updated in place."""
+    from unittest import mock
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from kubeflow_rm_tpu.models import NemotronHConfig, init_params, paging
+
+    cfg = NemotronHConfig(experts_held=(0, 128), dtype=jnp.bfloat16,
+                          param_dtype=jnp.bfloat16)
+    slots, slot_len, bs = 32, 4096, 16
+    maxb = slot_len // bs
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    assert abs(sum(a.size for a in jax.tree.leaves(params)) / 4.648e9
+               - 1) < 0.001
+    cache = shaped(jax.eval_shape(lambda: paging.init_paged_cache(
+        cfg, slots, slot_len, 2 + slots * maxb + slots * maxb // 2, bs)))
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with mock.patch.object(pa, "computation_devices",
+                               lambda *a, **k: ("tpu", 1)):
+            compiled = paging.paged_decode_step.lower(
+                params, cfg, cache,
+                jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
+            ).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    # 32 rows: the few-rows dispatch, one loop an expert layer
+    assert "ragged-dot" not in text and text.count(" while(") >= 5
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64e6
+    # the cache goes out where it came in: state, tails and pool
+    assert memory.alias_size_in_bytes > 0.8e9

@@ -23,6 +23,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from kubeflow_rm_tpu.ops import expert_ffn
+
 
 @dataclass(frozen=True)
 class MoeConfig:
@@ -167,11 +169,14 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
 
     Every token of ``h`` (N, D) is routed over all experts; the
     assignments whose expert lies in the held range are computed, by
-    one of two dispatches chosen by the static row count: many rows
-    are sorted by expert and run through two grouped matmuls
-    (``_sorted_grouped``), few rows through a loop over the experts
-    that met a token (``_active_experts_loop``); either way an expert
-    nobody chose is not read. Shapes are static: the N x top_k
+    one of two dispatches chosen by the static row count: above
+    ``_FEW_ROWS`` the assignments are sorted by expert and run through
+    two grouped matmuls (``_sorted_grouped``); at or under it all rows
+    meet each expert that met a token, whose matrices are streamed
+    once — by ``ops/expert_ffn.py``'s pallas kernel on a one-device
+    TPU program whose widths tile, by a loop in plain XLA
+    (``_active_experts_loop``) elsewhere; either way an expert nobody
+    chose is not read. Shapes are static: the N x top_k
     assignments are all carried, those of other chips' experts (and of
     rows ``live`` (N,) marks dead: padding, empty slots) belong to no
     group. No capacity, so no token is dropped whatever the imbalance.
@@ -194,22 +199,35 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
         group.reshape(-1)].add(1)[:held]
     counts = (jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32))
     if N <= _FEW_ROWS:
-        out = _active_experts_loop(x, group, w, sizes, up, down)
+        # (held, N): row n's weight for each held expert; the experts
+        # that met a token, ascending, and their number
+        dense = jnp.zeros((held, N), jnp.float32).at[
+            group, jnp.arange(N)[:, None]].add(w, mode="drop")
+        active = jnp.nonzero(sizes > 0, size=held, fill_value=0)[0]
+        few = (expert_ffn.active_experts_ffn
+               if expert_ffn.takes_kernel(x, up) else _active_experts_loop)
+        out = few(x, dense, active, counts[1], up, down)
     else:
         out = _sorted_grouped(x, group.reshape(-1), w.reshape(-1), sizes,
                               up, down, top_k)
     return out.astype(x.dtype), counts
 
 
-#: at or under this many rows (a decode step's slots, a short prompt's
-#: bucket) an expert's few rows are not worth a grouped matmul's tiles:
-#: every row meets each active expert and a weight of 0 where it did
-#: not choose it. 64 is a guess between the two sizes measured on the
-#: chip, not a swept threshold: at 32 rows the loop read an active
-#: expert at 445 GB/s where the grouped matmul read it at 230; a
-#: 128-row prefill takes the grouped matmul. An engine of more than 64
-#: slots decodes through the grouped matmul until that is swept.
-_FEW_ROWS = 64
+#: at or under this many rows (a decode step's slots, a prompt's
+#: bucket) every row meets each active expert, with a weight of 0
+#: where it did not choose it, and an expert's matrices are streamed
+#: once: cheaper than sorting the N x top_k assignments into a grouped
+#: matmul's tiles while the multiplies stay under the reads. Swept on
+#: the v5e at the Nemotron cut's widths and routing (22 of 512 experts
+#: a row, 128 held; PERF.md section 6, PR 41), a layer's call in ms at
+#: 32 / 128 / 256 / 512 / 1024 rows: the kernel 1.6 / 1.9 / 2.1 / 3.9 /
+#: 7.7, the loop 2.1 / 2.6 / 3.0 / 4.6 / 8.5, the grouped matmuls 3.6 /
+#: 5.4 / 6.9 / 7.5 / 9.0. 1024 is the most rows swept (the longest
+#: prompt bucket of the serving cell), not where the two meet: the
+#: kernel's time doubles with the rows from 512 on (the MXU, 0.06 us a
+#: row and expert) and the grouped matmuls' grows by a fifth, so they
+#: would meet somewhere under 2048.
+_FEW_ROWS = 1024
 
 
 def _sorted_grouped(x, group, w, sizes, up, down, top_k):
@@ -235,19 +253,15 @@ def _sorted_grouped(x, group, w, sizes, up, down, top_k):
     return jnp.sum(y[back].reshape(-1, top_k, y.shape[-1]), axis=1)
 
 
-def _active_experts_loop(x, group, w, sizes, up, down):
-    """Few rows: a loop over the experts that met a token, and only
-    those (its trip count is their number: an expert nobody chose is
-    never read). Each streams its two matrices once; all N rows are
-    multiplied, which costs nothing beside the read, and a row's result
-    counts with the weight it gave that expert, 0 where it did not
+def _active_experts_loop(x, dense, active, n, up, down):
+    """Few rows, plain XLA (off the chip, a sharded call; the reference
+    of ``ops/expert_ffn.py``'s kernel, whose arguments it takes): a
+    loop over the ``n`` experts ``active`` lists, those that met a
+    token (an expert nobody chose is never read). Each streams its two
+    matrices once; all N rows are multiplied, which costs nothing
+    beside the read, and a row's result counts with the weight
+    ``dense`` (held, N) gives it for that expert, 0 where it did not
     choose it."""
-    N, held = x.shape[0], up.shape[0]
-    # (held, N): row n's weight for each held expert
-    dense = jnp.zeros((held, N), jnp.float32).at[
-        group, jnp.arange(N)[:, None]].add(w, mode="drop")
-    active = jnp.nonzero(sizes > 0, size=held, fill_value=0)[0]
-
     def one(i, acc):
         e = active[i]
         a = jnp.dot(x, jax.lax.dynamic_index_in_dim(up, e, keepdims=False)
@@ -258,5 +272,5 @@ def _active_experts_loop(x, group, w, sizes, up, down):
         return acc + jax.lax.dynamic_index_in_dim(
             dense, e, keepdims=False)[:, None] * y
 
-    return jax.lax.fori_loop(0, jnp.sum(sizes > 0), one,
-                             jnp.zeros((N, down.shape[-1]), jnp.float32))
+    return jax.lax.fori_loop(
+        0, n, one, jnp.zeros((x.shape[0], down.shape[-1]), jnp.float32))

@@ -214,12 +214,14 @@ def test_four_shares_and_the_shared_expert_add_up_to_the_uncut_layer(tiny):
     assert float(jnp.abs(whole - want).max()) < TOL
 
 
-@pytest.mark.parametrize("N", [40, 200], ids=["loop", "grouped"])
+@pytest.mark.parametrize("N", [40, 1100], ids=["loop", "grouped"])
 def test_no_token_is_dropped_when_all_choose_the_same_experts(N):
     """Every token sent to the same three experts (the bias decides):
     a capacity of tokens x top_k / experts would drop most of them;
     here every assignment is computed, by either dispatch (few rows:
     the loop over active experts; many: the grouped matmuls)."""
+    from kubeflow_rm_tpu.parallel.moe import _FEW_ROWS
+    assert (N > _FEW_ROWS) == (N == 1100)
     D, d, f, E, k = 16, 8, 12, 8, 3
     keys = jax.random.split(jax.random.key(0), 4)
     h = jax.random.normal(keys[0], (N, D))

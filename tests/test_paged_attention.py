@@ -18,6 +18,7 @@ import pytest
 from kubeflow_rm_tpu.models.generate import _UNFILLED
 from kubeflow_rm_tpu.ops import dot_product_attention
 from kubeflow_rm_tpu.ops import paged_attention as pa
+from kubeflow_rm_tpu.ops.attention import kernel_choices
 
 U = int(_UNFILLED)
 L, NB, BS, MAXB, HD = 2, 24, 4, 4, 16
@@ -211,6 +212,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_outside_the_cache(lowered):
+    """``lowered.compile()`` with the persistent cache off: a compile
+    for a described chip cannot be read back from it; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return lowered.compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+
 @pytest.mark.parametrize("H,kvh", [(32, 8), (16, 16), (32, 2)],
                          ids=["mistral-gqa", "bench1b-mha",
                               "nemotron-gqa16"])
@@ -218,8 +235,6 @@ def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh):
     """Mosaic takes the kernel at the benchmark's and the smoke's
     widths, and the pool goes in as it lies: no copy of it, no
     temporary beside it."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     B, hd, layers, nb, bs, maxb = 16, 128, 2, 3074, 16, 128
     bf16 = jnp.bfloat16
 
@@ -232,24 +247,41 @@ def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh):
 
     assert pa.kernel_eligible(sds((B, H, hd), bf16),
                               sds((layers, nb, bs, kvh, hd), bf16))
-    # a compile for a described chip cannot be read back from the
-    # persistent cache; keep it out of there
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = jax.jit(run).lower(
-            sds((B, H, hd), bf16), sds((B, kvh, hd), bf16),
-            sds((B, kvh, hd), bf16),
-            sds((layers, nb, bs, kvh, hd), bf16),
-            sds((layers, nb, bs, kvh, hd), bf16), sds((), jnp.int32),
-            sds((B, maxb), jnp.int32), sds((B,), jnp.int32)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    compiled = _compiled_outside_the_cache(jax.jit(run).lower(
+        sds((B, H, hd), bf16), sds((B, kvh, hd), bf16),
+        sds((B, kvh, hd), bf16),
+        sds((layers, nb, bs, kvh, hd), bf16),
+        sds((layers, nb, bs, kvh, hd), bf16), sds((), jnp.int32),
+        sds((B, maxb), jnp.int32), sds((B,), jnp.int32)))
     assert "tpu_custom_call" in compiled.as_text()
     one_block = bs * kvh * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * one_block
+
+
+@pytest.mark.parametrize("N", [32, 1, 64], ids=["decode-32", "bucket-1",
+                                                "bucket-64"])
+def test_expert_kernel_compiles_for_the_chip_at_published_widths(one_chip,
+                                                                 N):
+    """Mosaic takes ``ops/expert_ffn.py``'s kernel at the benchmark's
+    widths (128 experts of 1024 x 2688 held) for a decode step's rows
+    and the ends of the prefill buckets it serves, and the two weight
+    arrays go in as they lie: no temporary of a matrix's size."""
+    from kubeflow_rm_tpu.ops import expert_ffn
+
+    held, d, f = 128, 1024, 2688
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    compiled = _compiled_outside_the_cache(
+        jax.jit(expert_ffn.active_experts_ffn).lower(
+            sds((N, d), bf16), sds((held, N), jnp.float32),
+            sds((held,), jnp.int32), sds((), jnp.int32),
+            sds((held, d, f), bf16), sds((held, f, d), bf16)))
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * d * f
 
 
 def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
@@ -257,15 +289,17 @@ def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
     """The decode step of the hybrid family at the benchmark's widths
     and share (one 11-layer period, 128 of 512 experts held, 32 slots
     of 4096): the chip's compiler takes it, the paged kernel is a
-    custom call, and nothing beside the arguments is of a weight's
-    size: an expert's matrices are read where they lie, by the loop
-    over the experts that met a token (a slice of a stack over the
-    layers would be copied, 705 MB a matrix a layer a step, before a
-    grouped matmul's custom call: why they are an array a layer), and
-    the recurrent state is updated in place."""
+    custom call, each expert layer's few-rows sum one more (no loop
+    over the active experts, no grouped matmul), and nothing beside
+    the arguments is of a weight's size: an expert's matrices are read
+    where they lie, picked by the kernel's index maps out of the
+    layer's whole array (a slice of a stack over the layers would be
+    copied, 705 MB a matrix a layer a step, before a custom call: why
+    they are an array a layer), and the recurrent state is updated in
+    place."""
     from unittest import mock
 
-    from jax.experimental.compilation_cache import compilation_cache
+    from kubeflow_rm_tpu.ops import expert_ffn
 
     from kubeflow_rm_tpu.models import NemotronHConfig, init_params, paging
 
@@ -284,24 +318,22 @@ def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
                - 1) < 0.001
     cache = shaped(jax.eval_shape(lambda: paging.init_paged_cache(
         cfg, slots, slot_len, 2 + slots * maxb + slots * maxb // 2, bs)))
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        with mock.patch.object(pa, "computation_devices",
-                               lambda *a, **k: ("tpu", 1)):
-            compiled = paging.paged_decode_step.lower(
-                params, cfg, cache,
-                jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
-                jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip)
-            ).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    on_chip = lambda *a, **k: ("tpu", 1)  # noqa: E731
+    with mock.patch.object(pa, "computation_devices", on_chip), \
+            mock.patch.object(expert_ffn, "computation_devices",
+                              on_chip), kernel_choices() as chosen:
+        lowered = paging.paged_decode_step.lower(
+            params, cfg, cache,
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip))
+    compiled = _compiled_outside_the_cache(lowered)
     text = compiled.as_text()
-    assert 'custom_call_target="tpu_custom_call"' in text
-    # 32 rows: the few-rows dispatch, one loop an expert layer
-    assert "ragged-dot" not in text and text.count(" while(") >= 5
+    # 32 rows: the few-rows kernel, one call an expert layer beside
+    # the paged kernel's one; no while is left in the step
+    n_e = cfg.pattern.count("E")
+    assert sorted(chosen) == ["held_experts"] * n_e + ["paged_decode"]
+    assert text.count('custom_call_target="tpu_custom_call"') == n_e + 1
+    assert "ragged-dot" not in text and " while(" not in text
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64e6
     # the cache goes out where it came in: state, tails and pool

@@ -187,52 +187,64 @@ def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
     return logits, ys
 
 
-def _run_hybrid_blocks(params, cfg, tokens, mask, ssm, conv, attend):
-    """The trunk of a config whose layers are of three kinds
-    (``models.nemotron_h``), beside ``_run_blocks`` and for the same
-    callers: embed, the pattern's layers in order, final norm, head.
+def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
+                       attend):
+    """The trunk of a config whose layers are parts of several kinds
+    (``models.nemotron_h``, ``models.qwen3_next``), beside
+    ``_run_blocks`` and for the same callers: embed, the pattern's
+    parts in order, each ``x <- x + part(norm(x))``, final norm, head.
+
+    The config names its parts: ``cfg.hybrid_parts()`` maps each
+    character of ``cfg.pattern`` to (the stack of its parameters, its
+    role, its function), and ``cfg.norm(x, gain)`` is the family's
+    pre-norm. Three roles:
+
+    - ``"recurrent"``: ``fn(cfg, layer, h, ssm, conv, i, mask) -> (out,
+      ssm', conv')`` advances the ``i``-th recurrent layer's state, a
+      row at a time, in the whole stacked arrays (``ssm`` (Lr, B, ...)
+      float32, ``conv`` (Lr, B, kernel - 1, channels));
+    - ``"experts"``: ``fn(cfg, layer, h, mask) -> (out, (assignments
+      held, held experts with a token))``;
+    - ``"attention"``: ``fn(cfg, layer, h, positions) -> (q, k, v,
+      finish)``; ``attend(q, k, v, i) -> (attn, ys)`` lands the
+      ``i``-th attention layer's K/V and attends, as ``_run_blocks``'
+      does, and ``finish(attn (B, Tc, H hd))`` is the part's output.
 
     A Python loop over the pattern, not a scan: the kinds differ in
     weights, state and work, one period (what a chip of the benchmark's
-    deployment holds) has nothing that repeats, and a static layer
+    deployments holds) has nothing that repeats, and a static layer
     index lets each stacked leaf be read where it lies. ``mask``
     (B, Tc) marks the real columns (a right-padded bucket's prefix; at
     decode the live rows): the others advance no state and reach no
-    expert. ``ssm`` (Lm, B, heads, head_dim, state) and ``conv`` (Lm,
-    B, kernel - 1, conv_dim) are the Mamba layers' state entering the
-    chunk; ``attend(q, k, v, i) -> (attn, ys)`` lands the ``i``-th
-    attention layer's K/V and attends, as ``_run_blocks``' does.
-    Returns (logits, (ssm', conv'), the list of ``ys``, int32
-    (expert assignments held, held experts with a token, 1))."""
-    from kubeflow_rm_tpu.models.nemotron_h import (
-        attention_qkv, latent_moe, mamba_mix,
-    )
-
+    expert. ``positions`` (B, Tc) are the columns' (``_UNFILLED`` where
+    masked), for a family whose attention rotates by position.
+    Returns (logits, (ssm', conv'), the list of ``ys``, int32 (expert
+    assignments held, held experts with a token, 1))."""
     B, Tc = tokens.shape
     cdt = cfg.dtype
+    parts = cfg.hybrid_parts()
     x = params["embed"]["tokens"][tokens].astype(cdt)
-    at = {"M": 0, "E": 0, "*": 0}
-    stacks = {"M": "blocks_m", "E": "blocks_e", "*": "blocks_a"}
+    at = dict.fromkeys(parts, 0)
     ys = []
     counts = jnp.zeros((2,), jnp.int32)
     for kind in cfg.pattern:
+        stack, role, part = parts[kind]
         i = at[kind]
         at[kind] += 1
-        layer = {k: v[i] for k, v in params[stacks[kind]].items()}
-        h = rms_norm(x, layer["norm"], cfg.norm_eps)
-        if kind == "M":
-            out, s_i, c_i = mamba_mix(cfg, layer, h, ssm[i], conv[i], mask)
-            ssm = ssm.at[i].set(s_i)
-            conv = conv.at[i].set(c_i)
-        elif kind == "E":
-            out, held = latent_moe(cfg, layer, h, mask)
+        layer = {k: v[i] for k, v in params[stack].items()}
+        h = cfg.norm(x, layer["norm"])
+        if role == "recurrent":
+            out, ssm, conv = part(cfg, layer, h, ssm, conv, i, mask)
+        elif role == "experts":
+            out, held = part(cfg, layer, h, mask)
             counts = counts + jnp.stack(held)
         else:
-            attn, y = attend(*attention_qkv(cfg, layer, h), i)
+            q, k, v, finish = part(cfg, layer, h, positions)
+            attn, y = attend(q, k, v, i)
             ys.append(y)
-            out = attn.reshape(B, Tc, -1) @ layer["wo"].astype(cdt)
+            out = finish(attn.reshape(B, Tc, -1))
         x = x + out
-    x = rms_norm(x, params["norm_f"], cfg.norm_eps)
+    x = cfg.norm(x, params["norm_f"])
     logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
     counts = jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
     return logits, (ssm, conv), ys, counts

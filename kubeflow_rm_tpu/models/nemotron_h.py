@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeflow_rm_tpu.models.llama import init_leaf
+from kubeflow_rm_tpu.ops import rms_norm
 from kubeflow_rm_tpu.parallel.moe import held_experts_ffn
 
 F32 = jnp.float32
@@ -111,6 +112,28 @@ class NemotronHConfig:
         """State that is not addressable by token block: a prefix of
         the cache cannot be adopted, exported or rewound."""
         return "M" in self.pattern
+
+    @property
+    def n_attention_layers(self) -> int:
+        return self.pattern.count("*")
+
+    def recurrent_shapes(self, slots: int) -> tuple[tuple, tuple]:
+        """The shapes of the cache's recurrent state (float32) and
+        convolution tail (compute dtype) for ``slots`` slots."""
+        Lm = self.pattern.count("M")
+        return ((Lm, slots, self.mamba_heads, self.mamba_head_dim,
+                 self.state_size),
+                (Lm, slots, self.conv_kernel - 1, self.conv_dim))
+
+    def hybrid_parts(self) -> dict:
+        """``decode._run_hybrid_blocks``' parts: a pattern character's
+        (stack, role, function)."""
+        return {"M": ("blocks_m", "recurrent", _mamba_part),
+                "E": ("blocks_e", "experts", latent_moe),
+                "*": ("blocks_a", "attention", _attention_part)}
+
+    def norm(self, x, gain):
+        return rms_norm(x, gain, self.norm_eps)
 
     @staticmethod
     def tiny(**overrides) -> "NemotronHConfig":
@@ -328,6 +351,19 @@ def _chunked_scan(x, dt, a, Bm, Cm, state, chunk):
     return y.reshape(B, nc * Q, *y.shape[3:])[:, :T], state
 
 
+def _mamba_part(cfg, layer, h, ssm, conv, i, mask):
+    """``mamba_mix`` on the ``i``-th Mamba layer's state, as a part."""
+    out, s_i, c_i = mamba_mix(cfg, layer, h, ssm[i], conv[i], mask)
+    return out, ssm.at[i].set(s_i), conv.at[i].set(c_i)
+
+
+def _attention_part(cfg, layer, h, _positions):
+    """``attention_qkv`` and the output projection, as a part."""
+    def finish(attn):
+        return attn @ layer["wo"].astype(cfg.dtype)
+    return (*attention_qkv(cfg, layer, h), finish)
+
+
 def attention_qkv(cfg: NemotronHConfig, layer: dict, h: jax.Array):
     """q (B, T, H, hd), k and v (B, T, KVH, hd); no rotary embedding."""
     B, T, _ = h.shape
@@ -371,16 +407,16 @@ def forward(params: dict, tokens: jax.Array,
     from kubeflow_rm_tpu.ops import dot_product_attention
 
     B, T = tokens.shape
-    Lm = cfg.pattern.count("M")
-    ssm = jnp.zeros((Lm, B, cfg.mamba_heads, cfg.mamba_head_dim,
-                     cfg.state_size), F32)
-    conv = jnp.zeros((Lm, B, cfg.conv_kernel - 1, cfg.conv_dim), cfg.dtype)
+    state, tail = cfg.recurrent_shapes(B)
+    ssm = jnp.zeros(state, F32)
+    conv = jnp.zeros(tail, cfg.dtype)
 
     def attend(q, k, v, _layer):
         return dot_product_attention(q, k, v, causal=True), None
 
     logits, _state, _ys, _counts = _run_hybrid_blocks(
-        params, cfg, tokens, jnp.ones((B, T), bool), ssm, conv, attend)
+        params, cfg, tokens, jnp.ones((B, T), bool), None, ssm, conv,
+        attend)
     return logits
 
 

@@ -96,8 +96,10 @@ class HybridPagedCache:
     """Two kinds of state in one cache, for a config with recurrent
     layers (``cfg.has_recurrent_state``): the block pool of its
     attention layers (``kv``; its ``L`` is their number) and, a slot
-    and Mamba layer each, the recurrent state and the convolution's
-    tail, which are no strip of positions and live outside the pool.
+    and recurrent layer each, the recurrent state and the convolution's
+    tail, which are no strip of positions and live outside the pool;
+    their shapes are the config's (``cfg.recurrent_shapes``: a Mamba-2
+    layer's, a Gated DeltaNet layer's delta-rule state).
     Admission writes a prefill's final state over whatever the slot
     held (``paged_install``); a decode step advances the live rows'
     and leaves the others' as it was. ``counters`` are the expert
@@ -108,8 +110,8 @@ class HybridPagedCache:
     run. int32: they wrap after 2^31, days of traffic; a reader takes
     differences."""
     kv: PagedKVCache
-    ssm: jax.Array           # (Lm, SLOTS, heads, head_dim, state) f32
-    conv: jax.Array          # (Lm, SLOTS, kernel - 1, conv_dim)
+    ssm: jax.Array           # (Lr, SLOTS, heads, ...) f32
+    conv: jax.Array          # (Lr, SLOTS, kernel - 1, channels)
     counters: jax.Array      # (2, 3) int32
 
 
@@ -119,17 +121,15 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, slot_len: int,
     layer, or for a config with recurrent layers a ``HybridPagedCache``
     whose pool serves the attention layers only."""
     if cfg.has_recurrent_state:
-        Lm = cfg.pattern.count("M")
-        if "*" not in cfg.pattern:
+        if not cfg.n_attention_layers:
             raise ValueError(f"pattern {cfg.pattern!r} has no attention "
                              "layer for the block pool to serve")
+        state, tail = cfg.recurrent_shapes(slots)
         return HybridPagedCache(
-            kv=_init_kv_pool(cfg, cfg.pattern.count("*"), slots, slot_len,
+            kv=_init_kv_pool(cfg, cfg.n_attention_layers, slots, slot_len,
                              num_blocks, block_size),
-            ssm=jnp.zeros((Lm, slots, cfg.mamba_heads, cfg.mamba_head_dim,
-                           cfg.state_size), jnp.float32),
-            conv=jnp.zeros((Lm, slots, cfg.conv_kernel - 1, cfg.conv_dim),
-                           cfg.dtype),
+            ssm=jnp.zeros(state, jnp.float32),
+            conv=jnp.zeros(tail, cfg.dtype),
             counters=jnp.zeros((2, 3), jnp.int32))
     return _init_kv_pool(cfg, cfg.n_layers, slots, slot_len, num_blocks,
                          block_size)
@@ -257,7 +257,7 @@ def _hybrid_decode_step(params, cfg, cache: HybridPagedCache, tokens,
     kv = cache.kv
     positions, wi, blk, off, kv_positions = _decode_columns(kv, active)
     logits, (ssm, conv), cols, counts = _run_hybrid_blocks(
-        params, cfg, tokens[:, None], active[:, None], cache.ssm,
+        params, cfg, tokens[:, None], active[:, None], positions, cache.ssm,
         cache.conv, _pool_attend(kv, positions, wi, active, kv_positions))
     col_k, col_v = (jnp.stack(c) for c in zip(*cols))
     return logits[:, -1, :], HybridPagedCache(
@@ -342,7 +342,7 @@ def _hybrid_prefill(params, cfg, cache: HybridPagedCache, load_row, n_hit,
         cache.kv, load_row, n_hit, Tc, n_real)
     strip_attend = _cache_attend(write_kv, positions, kv_positions)
     logits, (ssm, conv), strips, counts = _run_hybrid_blocks(
-        params, cfg, tokens, jnp.arange(Tc)[None, :] < n_real,
+        params, cfg, tokens, jnp.arange(Tc)[None, :] < n_real, positions,
         jnp.zeros_like(cache.ssm[:, :1]), jnp.zeros_like(cache.conv[:, :1]),
         lambda q, k, v, i: strip_attend(q, k, v, (gk[i], gv[i])))
     new_k, new_v = (jnp.stack(c) for c in zip(*strips))

@@ -3,8 +3,9 @@ matrices streamed once.
 
 A decode step (or a prompt's bucket) brings a few rows to a
 layer of squared-ReLU experts ``up`` (held, d, f) and ``down`` (held,
-f, d); some of the held experts met a token, and only their matrices
-must move. Two implementations of one function, chosen the way
+f, d), or of SwiGLU experts with a third matrix ``gate`` (held, d, f)
+(``down(silu(x gate) * x up)``); some of the held experts met a token,
+and only their matrices must move. Two implementations of one function, chosen the way
 ``ops/paged_attention.py`` chooses (by what ``computation_devices``
 observes; no flag, no environment variable):
 
@@ -53,10 +54,18 @@ from kubeflow_rm_tpu.ops.attention import _log_choice, computation_devices
 TILE_F = 896
 
 
-def tile_of(f: int) -> int:
+def tile_of(f: int, cap: int = TILE_F) -> int:
     """The tile of ``f`` the kernel takes: the largest multiple of 128
-    dividing ``f`` that is not above ``TILE_F``."""
-    return max(t for t in range(128, min(f, TILE_F) + 1, 128) if f % t == 0)
+    dividing ``f`` that is not above ``cap``."""
+    return max(t for t in range(128, max(128, min(f, cap)) + 1, 128)
+               if f % t == 0)
+
+
+def gated_cap(d: int) -> int:
+    """``TILE_F`` for a SwiGLU expert of width ``d``: the columns whose
+    three tiles hold what two of ``TILE_F`` hold at ``d`` = 1024 (3.1
+    MB a grid step at Qwen3-Next's 2048 x 512 experts: tile 256)."""
+    return 2 * 1024 * TILE_F // (3 * d)
 
 
 def takes_kernel(x, up) -> bool:
@@ -100,10 +109,38 @@ def _kernel(active_ref, n_ref,                         # scalar prefetch
         o_ref[...] += w * y
 
 
-def active_experts_ffn(x, dense, active, n, up, down, *,
+def _gated_kernel(active_ref, n_ref,                   # scalar prefetch
+                  x_ref, w_ref, gate_ref, up_ref, down_ref, o_ref):
+    """``_kernel`` for SwiGLU experts: the gate's tile beside ``up``'s."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, gate_ref[...].astype(x.dtype),
+                    preferred_element_type=jnp.float32)
+        a = jnp.dot(x, up_ref[...].astype(x.dtype),
+                    preferred_element_type=jnp.float32)
+        a = (g * jax.nn.sigmoid(g) * a).astype(x.dtype)
+        y = jnp.dot(a, down_ref[...].astype(x.dtype),
+                    preferred_element_type=jnp.float32)
+        w = w_ref[...]
+        col = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+        w = jnp.sum(jnp.where(col == active_ref[i], w, 0.0), axis=1,
+                    keepdims=True)
+        o_ref[...] += w * y
+
+
+def active_experts_ffn(x, dense, active, n, up, down, *, gate=None,
                        interpret: bool = False):
     """``sum_e dense[e][:, None] * (square(relu(x @ up[e])) @ down[e])``
-    over the experts ``active[:n]``.
+    over the experts ``active[:n]``; with ``gate`` the SwiGLU experts'
+    ``silu(x @ gate[e]) * (x @ up[e])`` in the squared ReLU's place,
+    the three tiles of an expert streamed back to back.
 
     Args:
       x: (N, d) rows.
@@ -112,13 +149,14 @@ def active_experts_ffn(x, dense, active, n, up, down, *,
       active: (held,) int32 the experts that met a token, ascending,
         then anything; n: () int32 their number (0 gives zeros).
       up, down: (held, d, f) and (held, f, d), whole.
+      gate: None, or (held, d, f) whole: SwiGLU experts.
       interpret: run the kernel in the pallas interpreter (off-TPU).
 
     Returns (N, d) float32.
     """
     N, d = x.shape
     held, _, f = up.shape
-    tf = tile_of(f)
+    tf = tile_of(f) if gate is None else tile_of(f, gated_cap(d))
     # whole sublane tiles of the rows' (packed) dtype; a padding row
     # weighs 0 with every expert
     sub = 8 * (4 // jnp.dtype(x.dtype).itemsize)
@@ -130,15 +168,17 @@ def active_experts_ffn(x, dense, active, n, up, down, *,
     def full(*shape):
         return pl.BlockSpec(shape, lambda i, j, *_: (0,) * len(shape))
 
+    col_tile = pl.BlockSpec((None, d, tf),
+                            lambda i, j, active, n: (active[i], 0, j))
+    weights = (up, down) if gate is None else (gate, up, down)
     out = pl.pallas_call(
-        _kernel,
+        _kernel if gate is None else _gated_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(jnp.maximum(n[0], 1), f // tf),
             in_specs=[
                 full(Np, d), full(Np, held),
-                pl.BlockSpec((None, d, tf),
-                             lambda i, j, active, n: (active[i], 0, j)),
+                *[col_tile] * (len(weights) - 1),
                 pl.BlockSpec((None, tf, d),
                              lambda i, j, active, n: (active[i], j, 0)),
             ],
@@ -148,8 +188,9 @@ def active_experts_ffn(x, dense, active, n, up, down, *,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="held_experts_ffn",
-    )(active.astype(jnp.int32), n, x, w, up, down)
+    )(active.astype(jnp.int32), n, x, w, *weights)
     return out[:N]
 
 
-__all__ = ["TILE_F", "active_experts_ffn", "takes_kernel", "tile_of"]
+__all__ = ["TILE_F", "active_experts_ffn", "gated_cap", "takes_kernel",
+           "tile_of"]

@@ -275,6 +275,14 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
     def full(*shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
 
+    # a block's (BS, KVH, hd) lies in HBM as one (R, hd) tile when the
+    # head fills one lane tile, so the pool goes in as (L, NB, R, hd),
+    # a bitcast. A wider head lies in (KVH, 128) tiles, where that
+    # reshape is a copy of the whole pool every step (537 MB at
+    # Qwen3-Next's 2 x 256): the pool goes in as it lies, a block is
+    # copied as it lies and flattened in VMEM
+    wide = hd > 128
+    block = (BS, KVH, hd) if wide else (R, hd)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         functools.partial(_kernel, block_size=BS, chunk_blocks=CB),
@@ -287,8 +295,8 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
             scratch_shapes=[
                 pltpu.SMEM((B * pl.cdiv(MAXB, CB),), jnp.int32),
                 pltpu.SMEM((B * pl.cdiv(MAXB, CB),), jnp.int32),
-                pltpu.VMEM((2, CB, R, hd), pool_k.dtype),
-                pltpu.VMEM((2, CB, R, hd), pool_v.dtype),
+                pltpu.VMEM((2, CB, *block), pool_k.dtype),
+                pltpu.VMEM((2, CB, *block), pool_v.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
             ]),
@@ -300,4 +308,5 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       q, kn, vn, head_bias, col_tok,
-      pool_k.reshape(L, NB, R, hd), pool_v.reshape(L, NB, R, hd))
+      *((pool_k, pool_v) if wide else
+        (pool_k.reshape(L, NB, R, hd), pool_v.reshape(L, NB, R, hd))))

@@ -157,15 +157,34 @@ def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     return idx.astype(jnp.int32), w
 
 
+def route_softmax_topk(h: jax.Array, router_w: jax.Array, top_k: int):
+    """Softmax routing over every expert the router names: ``h`` (N, D)
+    -> (expert ids (N, k) int32, weights (N, k) float32). The ``top_k``
+    most probable of ``softmax(h W)`` are chosen and their
+    probabilities renormalised to sum to 1. Float32 at full precision,
+    as the sigmoid router is."""
+    p = jax.nn.softmax(jnp.dot(h.astype(jnp.float32),
+                               router_w.astype(jnp.float32),
+                               precision="highest"), axis=-1)
+    chosen, idx = jax.lax.top_k(p, top_k)
+    return idx.astype(jnp.int32), chosen / jnp.sum(chosen, -1, keepdims=True)
+
+
 def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
                      up: jax.Array, down: jax.Array, first: int,
                      top_k: int, scale: float, *,
                      expert_in: jax.Array | None = None,
-                     live: jax.Array | None = None):
+                     live: jax.Array | None = None,
+                     gate: jax.Array | None = None,
+                     routing: str = "sigmoid"):
     """The part of a dropless expert layer that the experts held here
     give: squared-ReLU experts ``up`` (held, d, f) and ``down``
-    (held, f, d), which are experts ``first .. first + held`` of the
-    ``router_w.shape[1]`` the router chooses among.
+    (held, f, d), or with ``gate`` (held, d, f) SwiGLU experts
+    ``down(silu(x gate) * x up)``, which are experts ``first .. first +
+    held`` of the ``router_w.shape[1]`` the router chooses among.
+    ``routing`` is ``"sigmoid"`` (``route_sigmoid_topk`` with ``bias``
+    and ``scale``) or ``"softmax"`` (``route_softmax_topk``; ``bias``
+    and ``scale`` unused). Both are static properties of the call.
 
     Every token of ``h`` (N, D) is routed over all experts; the
     assignments whose expert lies in the held range are computed, by
@@ -189,7 +208,13 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     least one)`` as int32 scalars."""
     x = h if expert_in is None else expert_in
     N, held = x.shape[0], up.shape[0]
-    idx, w = route_sigmoid_topk(h, router_w, bias, top_k, scale)
+    if routing == "sigmoid":
+        idx, w = route_sigmoid_topk(h, router_w, bias, top_k, scale)
+    elif routing == "softmax":
+        idx, w = route_softmax_topk(h, router_w, top_k)
+    else:
+        raise ValueError(f"routing {routing!r}: sigmoid or softmax")
+    swiglu = {} if gate is None else {"gate": gate}
     local = idx - first
     mine = (local >= 0) & (local < held)
     if live is not None:
@@ -206,10 +231,10 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
         active = jnp.nonzero(sizes > 0, size=held, fill_value=0)[0]
         few = (expert_ffn.active_experts_ffn
                if expert_ffn.takes_kernel(x, up) else _active_experts_loop)
-        out = few(x, dense, active, counts[1], up, down)
+        out = few(x, dense, active, counts[1], up, down, **swiglu)
     else:
         out = _sorted_grouped(x, group.reshape(-1), w.reshape(-1), sizes,
-                              up, down, top_k)
+                              up, down, top_k, **swiglu)
     return out.astype(x.dtype), counts
 
 
@@ -230,20 +255,27 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
 _FEW_ROWS = 1024
 
 
-def _sorted_grouped(x, group, w, sizes, up, down, top_k):
+def _sorted_grouped(x, group, w, sizes, up, down, top_k, gate=None):
     """Many rows: the assignments sorted by expert, two grouped matmuls
-    (``lax.ragged_dot``), the sum over a token's choices. ``group``
-    (N k,) is the held expert of an assignment, ``held`` where it has
-    none; ``sizes`` (held,) the assignments an expert."""
+    (three with ``gate``: SwiGLU experts; ``lax.ragged_dot``), the sum
+    over a token's choices. ``group`` (N k,) is the held expert of an
+    assignment, ``held`` where it has none; ``sizes`` (held,) the
+    assignments an expert."""
     A = group.shape[0]
     order = jnp.argsort(group)                                # stable
     valid = (jnp.arange(A) < jnp.sum(sizes))[:, None]
     rows = x[order // top_k]
     a = jax.lax.ragged_dot(rows, up.astype(x.dtype), sizes,
                            preferred_element_type=jnp.float32)
+    if gate is None:
+        a = jnp.square(jax.nn.relu(a))
+    else:
+        a = jax.nn.silu(jax.lax.ragged_dot(
+            rows, gate.astype(x.dtype), sizes,
+            preferred_element_type=jnp.float32)) * a
     # rows past the last group belong to nobody: whatever the grouped
     # matmul left there is replaced, not scaled
-    a = jnp.where(valid, jnp.square(jax.nn.relu(a)), 0.0).astype(x.dtype)
+    a = jnp.where(valid, a, 0.0).astype(x.dtype)
     y = jax.lax.ragged_dot(a, down.astype(x.dtype), sizes,
                            preferred_element_type=jnp.float32)
     y = jnp.where(valid, y, 0.0) * w[order][:, None]
@@ -253,22 +285,29 @@ def _sorted_grouped(x, group, w, sizes, up, down, top_k):
     return jnp.sum(y[back].reshape(-1, top_k, y.shape[-1]), axis=1)
 
 
-def _active_experts_loop(x, dense, active, n, up, down):
+def _active_experts_loop(x, dense, active, n, up, down, gate=None):
     """Few rows, plain XLA (off the chip, a sharded call; the reference
     of ``ops/expert_ffn.py``'s kernel, whose arguments it takes): a
     loop over the ``n`` experts ``active`` lists, those that met a
     token (an expert nobody chose is never read). Each streams its two
-    matrices once; all N rows are multiplied, which costs nothing
-    beside the read, and a row's result counts with the weight
-    ``dense`` (held, N) gives it for that expert, 0 where it did not
-    choose it."""
+    matrices (three with ``gate``) once; all N rows are multiplied,
+    which costs nothing beside the read, and a row's result counts
+    with the weight ``dense`` (held, N) gives it for that expert, 0
+    where it did not choose it."""
+    def matrix(m, e):
+        return jax.lax.dynamic_index_in_dim(m, e, keepdims=False).astype(
+            x.dtype)
+
     def one(i, acc):
         e = active[i]
-        a = jnp.dot(x, jax.lax.dynamic_index_in_dim(up, e, keepdims=False)
-                    .astype(x.dtype), preferred_element_type=jnp.float32)
-        a = jnp.square(jax.nn.relu(a)).astype(x.dtype)
-        y = jnp.dot(a, jax.lax.dynamic_index_in_dim(down, e, keepdims=False)
-                    .astype(x.dtype), preferred_element_type=jnp.float32)
+        a = jnp.dot(x, matrix(up, e), preferred_element_type=jnp.float32)
+        if gate is None:
+            a = jnp.square(jax.nn.relu(a)).astype(x.dtype)
+        else:
+            a = (jax.nn.silu(jnp.dot(x, matrix(gate, e),
+                                     preferred_element_type=jnp.float32))
+                 * a).astype(x.dtype)
+        y = jnp.dot(a, matrix(down, e), preferred_element_type=jnp.float32)
         return acc + jax.lax.dynamic_index_in_dim(
             dense, e, keepdims=False)[:, None] * y
 

@@ -145,3 +145,63 @@ def hybrid_decode_step_bytes(cfg, experts_active: float, live_slots: float,
     kv = (bytes_per_value * 2.0 * La * cfg.n_kv_heads * cfg.head_dim
           * live_kv_tokens)
     return weights + state + kv
+
+
+# ---------------------------------------------------------------------------
+# the qwen3_next family (models.qwen3_next): serving counts that know
+# the experts held, the assignments that met them and the delta rule.
+# The benchmark's own copy is perf/flops_qwen3_next.py (a test holds
+# the two equal).
+# ---------------------------------------------------------------------------
+
+
+def qwen3_next_dense_matmul_params(cfg) -> int:
+    """Matmul parameters every token of a ``Qwen3NextConfig`` meets: the
+    mixers' projections, the router, the shared expert and its gate,
+    the head's rows held. Not the embedding, gains, the convolution
+    nor the routed experts."""
+    Ll, Lf, Le = (cfg.pattern.count(c) for c in "LFE")
+    D = cfg.dim
+    linear = (D * (2 * cfg.key_dim + 2 * cfg.value_dim)
+              + D * 2 * cfg.linear_value_heads + cfg.value_dim * D)
+    H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    full = D * 2 * H * hd + 2 * D * KVH * hd + H * hd * D
+    experts = D * cfg.n_routed_experts + 3 * D * cfg.shared_dim + D
+    return Ll * linear + Lf * full + Le * experts + D * cfg.vocab_size
+
+
+def qwen3_next_expert_params(cfg) -> int:
+    """One routed expert: gate, up and down."""
+    return 3 * cfg.dim * cfg.expert_dim
+
+
+def qwen3_next_state_flops_per_token(cfg) -> float:
+    """A token's delta rule over every linear layer (decay, ``S^T k``,
+    the rank-one update, the read-out: 7 a state element) and the
+    convolution's taps (2 a tap a channel)."""
+    return cfg.pattern.count("L") * (
+        7.0 * cfg.linear_value_heads * cfg.linear_key_dim
+        * cfg.linear_value_dim + 2.0 * cfg.conv_kernel * cfg.conv_dim)
+
+
+def qwen3_next_serve_flops(cfg, tokens: float, assignments_held: float,
+                           positions_attended: float) -> float:
+    """Forward work of ``tokens`` fed tokens of which
+    ``assignments_held`` token-expert pairs met an expert held here
+    (``engine.device_counters()``) and which attended
+    ``positions_attended`` positions in each attention layer."""
+    return (2.0 * qwen3_next_dense_matmul_params(cfg) * tokens
+            + 2.0 * qwen3_next_expert_params(cfg) * assignments_held
+            + 4.0 * cfg.n_attention_layers * cfg.n_heads * cfg.head_dim
+            * positions_attended
+            + qwen3_next_state_flops_per_token(cfg) * tokens)
+
+
+def qwen3_next_deltanet_decode_bytes(cfg, live_slots: float) -> float:
+    """Bytes a decode step's delta-rule kernels must move: each live
+    slot's float32 state read and written, its q, k, v and gates read
+    and its output written, in every linear layer."""
+    Hv, dk, dv = (cfg.linear_value_heads, cfg.linear_key_dim,
+                  cfg.linear_value_dim)
+    a_slot = 4 * (2 * Hv * dk * dv + 2 * Hv * dk + 2 * Hv * dv + Hv * 128)
+    return cfg.pattern.count("L") * live_slots * a_slot

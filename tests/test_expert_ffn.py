@@ -68,7 +68,7 @@ def _dense_sum(h, x, router, bias, up, down, first, live):
 
 
 _KERNEL = expert_ffn.active_experts_ffn
-_interpreted = jax.jit(lambda *a: _KERNEL(*a, interpret=True))
+_interpreted = jax.jit(lambda *a, **kw: _KERNEL(*a, interpret=True, **kw))
 
 
 def _forced(impl):
@@ -213,3 +213,83 @@ def test_choice_is_recorded_and_auto_takes_the_loop_off_the_chip():
                              expert_in=x)
     assert chosen == []
     assert expert_ffn.tile_of(2688) == 896 and expert_ffn.tile_of(256) == 256
+
+
+# -- SwiGLU experts under softmax routing (the qwen3_next family) -------
+
+
+def _swiglu_dense_sum(h, x, router, gate, up, down, first, k):
+    """The held SwiGLU experts' sum written out in float32, one
+    assignment at a time, under softmax routing renormalised over the
+    top ``k``."""
+    idx, w = moe.route_softmax_topk(h, router, k)
+    idx, w = np.asarray(idx), np.asarray(w)
+    out = np.zeros((x.shape[0], d), np.float32)
+    for n in range(x.shape[0]):
+        xn = np.asarray(x[n], np.float32)
+        for e, g in zip(idx[n] - first, w[n]):
+            if 0 <= e < HELD:
+                a = xn @ np.asarray(gate[e])
+                a = a / (1 + np.exp(-a)) * (xn @ np.asarray(up[e]))
+                out[n] += g * (a @ np.asarray(down[e]))
+    return out
+
+
+@pytest.mark.parametrize("first,routed", [(0, HELD), (8, 24)],
+                         ids=["whole", "share"])
+@pytest.mark.parametrize("N", [8, 40])
+def test_swiglu_kernel_matches_the_loop_and_a_dense_float32_sum(N, first,
+                                                                routed):
+    """The gated variant: three matrices an expert, the kernel
+    (interpreted) against the loop and a dense sum; the rows here are
+    the experts' own input (no latent projection)."""
+    ks = jax.random.split(jax.random.key(5), 4)
+    router = jax.random.normal(ks[0], (d, routed))
+    gate, up = (jax.random.normal(kk, (HELD, d, f)) * 0.1 for kk in ks[1:3])
+    down = jax.random.normal(ks[3], (HELD, f, d)) * 0.1
+    x = jax.random.normal(jax.random.key(N), (N, d))
+    outs = {}
+    for impl in ("kernel", "loop"):
+        with _forced(impl):
+            outs[impl], (n, active) = moe.held_experts_ffn(
+                x, router, None, up, down, first, K, 1.0, gate=gate,
+                routing="softmax")
+    want = _swiglu_dense_sum(x, x, router, gate, up, down, first, K)
+    scale = max(1.0, float(np.abs(want).max()))
+    assert int(active) > 0
+    assert float(jnp.abs(outs["kernel"] - outs["loop"]).max()) <= TOL * scale
+    assert float(np.abs(outs["kernel"] - want).max()) <= TOL * scale
+
+
+def test_each_variant_gets_its_own_kernel_and_tile():
+    """A squared-ReLU call takes the two-matrix kernel at ``TILE_F``'s
+    tile, as before; a SwiGLU call the three-matrix one at the tile
+    whose three matrices hold what two did (256 of 512 at 2048 wide)."""
+    from jax.experimental import pallas as pl
+
+    seen = []
+    real = pl.pallas_call
+
+    def spy(kernel, *, grid_spec, **kw):
+        seen.append((kernel, [s.block_shape for s in grid_spec.in_specs[2:]]))
+        return real(kernel, grid_spec=grid_spec, **kw)
+
+    sds = jax.ShapeDtypeStruct
+    bf = jnp.bfloat16
+    args = (sds((32, 1024), bf), sds((128, 32), jnp.float32),
+            sds((128,), jnp.int32), sds((), jnp.int32))
+    with mock.patch.object(pl, "pallas_call", spy):
+        jax.eval_shape(lambda *a: expert_ffn.active_experts_ffn(
+            *a, interpret=True), *args, sds((128, 1024, 2688), bf),
+            sds((128, 2688, 1024), bf))
+        jax.eval_shape(lambda *a: expert_ffn.active_experts_ffn(
+            *a[:4], a[5], a[6], gate=a[4], interpret=True),
+            sds((32, 2048), bf), sds((256, 32), jnp.float32),
+            sds((256,), jnp.int32), sds((), jnp.int32),
+            sds((256, 2048, 512), bf), sds((256, 2048, 512), bf),
+            sds((256, 512, 2048), bf))
+    (k1, blocks1), (k2, blocks2) = seen
+    assert k1 is expert_ffn._kernel and k2 is expert_ffn._gated_kernel
+    assert blocks1 == [(None, 1024, 896), (None, 896, 1024)]
+    assert blocks2 == [(None, 2048, 256)] * 2 + [(None, 256, 2048)]
+    assert expert_ffn.tile_of(512, expert_ffn.gated_cap(2048)) == 256

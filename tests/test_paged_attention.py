@@ -31,9 +31,9 @@ class _Pool:
     for ``n`` cached tokens (plus the one being decoded), ``share``
     points a table entry at another slot's block."""
 
-    def __init__(self, rng, kvh, dtype):
-        self.k = rng.normal(size=(L, NB, BS, kvh, HD)).astype(np.float32)
-        self.v = rng.normal(size=(L, NB, BS, kvh, HD)).astype(np.float32)
+    def __init__(self, rng, kvh, dtype, hd=HD):
+        self.k = rng.normal(size=(L, NB, BS, kvh, hd)).astype(np.float32)
+        self.v = rng.normal(size=(L, NB, BS, kvh, hd)).astype(np.float32)
         # NULL and SINK as the engine keeps them; everything else
         # starts as a block some earlier request left behind
         self.k[:, 0] = self.v[:, 0] = 0
@@ -174,6 +174,34 @@ def test_kernel_matches_block_table_path_and_gathered_strip(
         # chip_smoke.py's tolerance for flash against the XLA path
         err = np.abs(f32(kernel) - f32(plain)).max()
         assert err <= 5e-2 * np.abs(f32(plain)).max()
+
+
+@pytest.mark.parametrize("scene", ["block_boundary", "shared_block",
+                                   "inactive_beside_active"])
+def test_wide_head_kernel_matches_the_block_table_path(scene):
+    """A head wider than a lane tile (Qwen3-Next's 256, 8 query heads
+    a KV head): the kernel copies a block as it lies in the pool and
+    flattens it in VMEM; interpreted, it says what the XLA path says."""
+    kvh, H, hd = 2, 16, 256
+    rng = np.random.default_rng(7)
+    pool = _Pool(rng, kvh, "float32", hd=hd)
+    SCENES[scene](pool)
+    while len(pool.tables) < SLOTS:
+        pool.seat(0, active=False)
+    pk, pv, pos, tables, lengths, active = pool.arrays()
+    B = tables.shape[0]
+    q, k_new, v_new = (jnp.asarray(rng.normal(size=s), jnp.float32)
+                       for s in ((B, H, hd), (B, kvh, hd), (B, kvh, hd)))
+    positions_q = jnp.where(active, lengths, U)
+    kv_positions = pos[tables].reshape(B, S).at[np.arange(B), lengths].set(
+        positions_q)
+    args = (q, k_new, v_new, pk, pv, jnp.int32(LAYER), tables, lengths,
+            active)
+    kw = dict(positions_q=positions_q, kv_positions=kv_positions)
+    live = np.asarray(active)
+    kernel = np.asarray(_attend(*args, impl="pallas", **kw))[live]
+    xla = np.asarray(_attend(*args, impl="xla", **kw))[live]
+    np.testing.assert_allclose(kernel, xla, atol=1e-5, rtol=0)
 
 
 def test_choice_is_logged_and_auto_takes_xla_off_the_chip(caplog):
@@ -338,3 +366,79 @@ def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
     assert memory.temp_size_in_bytes < 64e6
     # the cache goes out where it came in: state, tails and pool
     assert memory.alias_size_in_bytes > 0.8e9
+
+
+def test_wide_head_kernel_reads_the_pool_where_it_lies(one_chip):
+    """At head 256 and 2 KV heads a pool block lies in (2, 128) tiles:
+    reshaped to (rows, hd) outside the kernel it would be copied whole
+    every step (537 MB at the Qwen3-Next cell's pool). The kernel takes
+    it as it lies: no temporary of the pool's size."""
+    B, H, hd, kvh, layers, nb, bs, maxb = 32, 16, 256, 2, 1, 16386, 16, 512
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def run(q, kn, vn, pk, pv, layer, tables, lengths):
+        return pa._paged_decode_kernel(q, kn, vn, pk, pv, layer, tables,
+                                       lengths, interpret=False)
+
+    compiled = _compiled_outside_the_cache(jax.jit(run).lower(
+        sds((B, H, hd), bf16), sds((B, kvh, hd), bf16),
+        sds((B, kvh, hd), bf16), sds((layers, nb, bs, kvh, hd), bf16),
+        sds((layers, nb, bs, kvh, hd), bf16), sds((), jnp.int32),
+        sds((B, maxb), jnp.int32), sds((B,), jnp.int32)))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
+def test_qwen3_next_decode_step_compiles_for_the_chip_at_published_widths(
+        one_chip):
+    """The decode step of the Qwen3-Next cut at the benchmark's widths
+    and share (one 4-layer period, 256 of 512 SwiGLU experts held, 32
+    slots of 8192): the chip's compiler takes it; each Gated DeltaNet
+    layer's delta rule is one custom call, each expert layer's few-rows
+    sum one more, the paged kernel one (no while, no grouped matmul);
+    the delta-rule state, tails and pool go out where they came in, and
+    nothing of a weight's or of the pool's size is a temporary."""
+    from unittest import mock
+
+    from kubeflow_rm_tpu.models import paging
+    from kubeflow_rm_tpu.models.qwen3_next import (
+        Qwen3NextConfig, init_params,
+    )
+    from kubeflow_rm_tpu.ops import expert_ffn, gated_delta
+
+    cfg = Qwen3NextConfig(vocab_size=75968, experts_held=(0, 256),
+                          dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, slot_len, bs = 32, 8192, 16
+    maxb = slot_len // bs
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.key(0))))
+    # 3,677,613,120 parameters: the cut as PERF.md counts it
+    assert sum(a.size for a in jax.tree.leaves(params)) == 3677613120
+    cache = shaped(jax.eval_shape(lambda: paging.init_paged_cache(
+        cfg, slots, slot_len, 2 + slots * maxb + slots * maxb // 2, bs)))
+    on_chip = lambda *a, **k: ("tpu", 1)  # noqa: E731
+    with mock.patch.object(pa, "computation_devices", on_chip), \
+            mock.patch.object(expert_ffn, "computation_devices", on_chip), \
+            mock.patch.object(gated_delta, "computation_devices", on_chip), \
+            kernel_choices() as chosen:
+        lowered = paging.paged_decode_step.lower(
+            params, cfg, cache,
+            jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+            jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip))
+    compiled = _compiled_outside_the_cache(lowered)
+    text = compiled.as_text()
+    assert sorted(chosen) == (["gated_delta_decode"] * 3
+                              + ["held_experts"] * 4 + ["paged_decode"])
+    assert text.count('custom_call_target="tpu_custom_call"') == 8
+    assert "ragged-dot" not in text and " while(" not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 160e6
+    assert memory.alias_size_in_bytes > 1.0e9

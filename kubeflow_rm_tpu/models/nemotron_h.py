@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeflow_rm_tpu.models.llama import init_leaf
-from kubeflow_rm_tpu.ops import rms_norm
+from kubeflow_rm_tpu.ops import mamba_decode, rms_norm
 from kubeflow_rm_tpu.parallel.moe import held_experts_ffn
 
 F32 = jnp.float32
@@ -128,7 +128,7 @@ class NemotronHConfig:
     def hybrid_parts(self) -> dict:
         """``decode._run_hybrid_blocks``' parts: a pattern character's
         (stack, role, function)."""
-        return {"M": ("blocks_m", "recurrent", _mamba_part),
+        return {"M": ("blocks_m", "recurrent", mamba_mix),
                 "E": ("blocks_e", "experts", latent_moe),
                 "*": ("blocks_a", "attention", _attention_part)}
 
@@ -253,11 +253,12 @@ def init_params(cfg: NemotronHConfig, key: jax.Array) -> dict:
 
 
 def mamba_mix(cfg: NemotronHConfig, layer: dict, h: jax.Array,
-              state: jax.Array, conv: jax.Array, mask: jax.Array):
-    """One Mamba-2 mixer over ``h`` (B, T, D) from ``state`` (B, heads,
-    head_dim, state_size) float32 and ``conv`` (B, conv_kernel - 1,
-    conv_dim), the last columns of ``xBC`` before this chunk. Returns
-    ``(y (B, T, D), state', conv')``.
+              ssm: jax.Array, conv: jax.Array, i: int, mask: jax.Array):
+    """The ``i``-th Mamba-2 mixer over ``h`` (B, T, D), from its state
+    in ``ssm`` (Lm, B, heads, head_dim, state_size) float32 and its tail
+    in ``conv`` (Lm, B, conv_kernel - 1, conv_dim), the last columns of
+    ``xBC`` before this chunk. Returns ``(y (B, T, D), ssm', conv')``,
+    the whole arrays.
 
     ``mask`` (B, T) marks the real columns, a prefix of each row (a
     right-padded prefill bucket; at decode a row is live or not): a
@@ -266,20 +267,26 @@ def mamba_mix(cfg: NemotronHConfig, layer: dict, h: jax.Array,
     so a row with none keeps both. Two shapes of use: a chunk (T > 1:
     the scan in chunks of ``chunk_size``, quadratic inside a chunk and
     recurrent between chunks) and a single column (T = 1: the
-    recurrence itself, elementwise in float32)."""
+    recurrence itself in float32, ``mamba_decode.decode_step``: the
+    kernel on the chip, which walks the live rows only)."""
     B, T, _ = h.shape
     Hm, P, G, N = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.n_groups,
                    cfg.state_size)
     K, di, cd, cdt = cfg.conv_kernel, cfg.d_inner, cfg.conv_dim, cfg.dtype
+    # a chunk scans from the layer's slice of the state; a column hands
+    # the whole stack to ``mamba_decode``, whose kernel updates the live
+    # rows where they lie
+    state = ssm[i] if T > 1 else None
+    tail = conv[i]
     zxbcdt = h @ layer["in_proj"].astype(cdt)
     z, xbc, dt = jnp.split(zxbcdt, [di, di + cd], axis=-1)
 
-    seq = jnp.concatenate([conv.astype(cdt), xbc], axis=1)  # (B,T+K-1,cd)
+    seq = jnp.concatenate([tail.astype(cdt), xbc], axis=1)  # (B,T+K-1,cd)
     w = layer["conv_w"].astype(F32)
     xbc = sum(seq[:, j:j + T].astype(F32) * w[j] for j in range(K))
     xbc = jax.nn.silu(xbc + layer["conv_b"].astype(F32))
     n_real = jnp.sum(mask, axis=1, dtype=jnp.int32)
-    new_conv = jax.vmap(
+    tail = jax.vmap(
         lambda s, n: jax.lax.dynamic_slice(s, (n, 0), (K - 1, cd))
     )(seq, n_real).astype(conv.dtype)
 
@@ -289,21 +296,24 @@ def mamba_mix(cfg: NemotronHConfig, layer: dict, h: jax.Array,
     dt = jax.nn.softplus(dt.astype(F32) + layer["dt_bias"])
     dt = jnp.where(mask[..., None], dt, 0.0).reshape(B, T, G, Hm // G)
     a = dt * -jnp.exp(layer["A_log"]).reshape(G, Hm // G)
-    state = state.reshape(B, G, Hm // G, P, N)
     if T == 1:
-        xb = dt[:, 0, ..., None] * x[:, 0]                   # (B,G,R,P)
-        state = (state * jnp.exp(a[:, 0])[..., None, None]
-                 + xb[..., None] * Bm[:, 0, :, None, None, :])
-        y = jnp.sum(state * Cm[:, 0, :, None, None, :], axis=-1)[:, None]
+        y, ssm = mamba_decode.decode_step(
+            ssm, i, x[:, 0].reshape(B, Hm, P), dt[:, 0].reshape(B, Hm),
+            a[:, 0].reshape(B, Hm), Bm[:, 0], Cm[:, 0], mask[:, 0])
+        y = y.reshape(B, 1, G, Hm // G, P)
     else:
-        y, state = _chunked_scan(x, dt, a, Bm, Cm, state, cfg.chunk_size)
+        y, state = _chunked_scan(x, dt, a, Bm, Cm,
+                                 state.reshape(B, G, Hm // G, P, N),
+                                 cfg.chunk_size)
     y = y + layer["D"].reshape(G, Hm // G, 1) * x
     y = y.reshape(B, T, di) * jax.nn.silu(z.astype(F32))
     y = y.reshape(B, T, G, di // G)
     y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True) + cfg.norm_eps)
     y = (y.reshape(B, T, di) * layer["gate_norm"].astype(F32)).astype(cdt)
-    return (y @ layer["out_proj"].astype(cdt),
-            state.reshape(B, Hm, P, N), new_conv)
+    out = y @ layer["out_proj"].astype(cdt)
+    if T > 1:
+        ssm = ssm.at[i].set(state.reshape(B, Hm, P, N))
+    return out, ssm, conv.at[i].set(tail)
 
 
 def _chunked_scan(x, dt, a, Bm, Cm, state, chunk):
@@ -349,12 +359,6 @@ def _chunked_scan(x, dt, a, Bm, Cm, state, chunk):
         step, state, (jnp.moveaxis(added, 1, 0), jnp.moveaxis(whole, 1, 0)))
     y = y + ein("bcign,cbgrpn,bcgri->bcigrp", Cm, entering, jnp.exp(cs))
     return y.reshape(B, nc * Q, *y.shape[3:])[:, :T], state
-
-
-def _mamba_part(cfg, layer, h, ssm, conv, i, mask):
-    """``mamba_mix`` on the ``i``-th Mamba layer's state, as a part."""
-    out, s_i, c_i = mamba_mix(cfg, layer, h, ssm[i], conv[i], mask)
-    return out, ssm.at[i].set(s_i), conv.at[i].set(c_i)
 
 
 def _attention_part(cfg, layer, h, _positions):

@@ -30,6 +30,7 @@ from kubeflow_rm_tpu.models import (  # noqa: E402
     ContinuousBatchingEngine, LlamaConfig, NemotronHConfig, init_params,
 )
 from kubeflow_rm_tpu.models import nemotron_h as nh, paging  # noqa: E402
+from kubeflow_rm_tpu.ops import mamba_decode  # noqa: E402
 from kubeflow_rm_tpu.parallel.moe import held_experts_ffn  # noqa: E402
 from perf import reference_nemotron_h as ref  # noqa: E402
 
@@ -134,21 +135,23 @@ def test_prefill_then_decode_through_the_cache_matches_the_reference(tiny):
 def test_chunked_scan_equals_the_recurrence(tiny, T):
     """One Mamba layer over T columns at once (chunks of 8: T a
     multiple, not a multiple, shorter than a chunk) against the same
-    columns one at a time."""
+    columns one at a time, in the second of the stack's two layers."""
     cfg, params = tiny
     layer = layer_of(params, "blocks_m", 1)
     h = jax.random.normal(jax.random.key(T), (2, T, cfg.dim))
-    s0 = jax.random.normal(jax.random.key(1), (2, 8, 8, 16)) * 0.1
-    c0 = jax.random.normal(jax.random.key(2), (2, 3, cfg.conv_dim))
-    y, s, c = nh.mamba_mix(cfg, layer, h, s0, c0, jnp.ones((2, T), bool))
+    s0 = jax.random.normal(jax.random.key(1), (2, 2, 8, 8, 16)) * 0.1
+    c0 = jax.random.normal(jax.random.key(2), (2, 2, 3, cfg.conv_dim))
+    y, s, c = nh.mamba_mix(cfg, layer, h, s0, c0, 1, jnp.ones((2, T), bool))
     ys, s1, c1 = [], s0, c0
     for t in range(T):
-        yt, s1, c1 = nh.mamba_mix(cfg, layer, h[:, t:t + 1], s1, c1,
+        yt, s1, c1 = nh.mamba_mix(cfg, layer, h[:, t:t + 1], s1, c1, 1,
                                   jnp.ones((2, 1), bool))
         ys.append(yt)
     assert float(jnp.abs(y - jnp.concatenate(ys, 1)).max()) < TOL
     assert float(jnp.abs(s - s1).max()) < TOL
     assert bool(jnp.all(c == c1))
+    # the other layer's state and tail are not touched
+    assert bool(jnp.all(s[0] == s0[0])) and bool(jnp.all(c[0] == c0[0]))
 
 
 def test_right_padded_bucket_leaves_the_unpadded_state_and_logits(tiny):
@@ -186,6 +189,40 @@ def test_inactive_rows_do_not_advance(tiny):
     step = np.asarray(after.counters[0]) - before.counters[0]
     assert step.tolist()[0] == cfg.top_k * 2 and step.tolist()[2] == 1
     assert np.array_equal(after.counters[1], before.counters[1])
+
+
+def test_decode_through_the_kernel_matches_the_xla_step(tiny):
+    """The same decode step with the Mamba-2 pallas kernel (interpreted)
+    in place of the XLA step, two of four slots dead: every row's
+    logits and state agree, and the dead rows' state and tail are left
+    bit for bit."""
+    cfg, params = tiny
+    _, cache, _ = seat(params, cfg, fresh_cache(cfg, slots=4), 0,
+                       [5, 6, 7, 8, 9], 8, [2, 3, 4])
+    _, cache, _ = seat(params, cfg, cache, 2, [11, 12, 13], 4, [5, 6])
+    tokens = jnp.asarray([3, 9, 4, 7], jnp.int32)
+    active = jnp.asarray([True, False, True, False])
+    before = jax.tree.map(np.asarray, cache)
+    xla, want = paging.paged_decode_step(       # the cache is donated
+        params, cfg, jax.tree.map(jnp.copy, cache), tokens, active)
+    with mock.patch.object(mamba_decode, "takes_kernel",
+                           lambda state, g: True), \
+            mock.patch.object(mamba_decode, "_decode_kernel",
+                              wraps=mamba_decode._decode_kernel) as kernel:
+        paging.paged_decode_step.clear_cache()
+        got, mine = paging.paged_decode_step(params, cfg, cache, tokens,
+                                             active)
+    paging.paged_decode_step.clear_cache()
+    assert kernel.call_count == cfg.pattern.count("M")
+    assert float(jnp.abs(got - xla).max()) < TOL
+    assert float(jnp.abs(mine.ssm - want.ssm).max()) < TOL
+    assert bool(jnp.all(mine.conv == want.conv))
+    for row in (1, 3):
+        assert np.array_equal(np.asarray(mine.ssm)[:, row],
+                              before.ssm[:, row])
+        assert np.array_equal(np.asarray(mine.conv)[:, row],
+                              before.conv[:, row])
+    assert not np.array_equal(np.asarray(mine.ssm)[:, 0], before.ssm[:, 0])
 
 
 # -- the expert layer ---------------------------------------------------

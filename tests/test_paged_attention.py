@@ -324,10 +324,11 @@ def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
     layer's whole array (a slice of a stack over the layers would be
     copied, 705 MB a matrix a layer a step, before a custom call: why
     they are an array a layer), and the recurrent state is updated in
-    place."""
+    place by each Mamba layer's kernel, one more custom call: no
+    fusion or copy of the whole state is left in the step."""
     from unittest import mock
 
-    from kubeflow_rm_tpu.ops import expert_ffn
+    from kubeflow_rm_tpu.ops import expert_ffn, mamba_decode
 
     from kubeflow_rm_tpu.models import NemotronHConfig, init_params, paging
 
@@ -349,6 +350,8 @@ def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
     on_chip = lambda *a, **k: ("tpu", 1)  # noqa: E731
     with mock.patch.object(pa, "computation_devices", on_chip), \
             mock.patch.object(expert_ffn, "computation_devices",
+                              on_chip), \
+            mock.patch.object(mamba_decode, "computation_devices",
                               on_chip), kernel_choices() as chosen:
         lowered = paging.paged_decode_step.lower(
             params, cfg, cache,
@@ -356,12 +359,17 @@ def test_hybrid_decode_step_compiles_for_the_chip_at_published_widths(
             jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip))
     compiled = _compiled_outside_the_cache(lowered)
     text = compiled.as_text()
-    # 32 rows: the few-rows kernel, one call an expert layer beside
-    # the paged kernel's one; no while is left in the step
-    n_e = cfg.pattern.count("E")
-    assert sorted(chosen) == ["held_experts"] * n_e + ["paged_decode"]
-    assert text.count('custom_call_target="tpu_custom_call"') == n_e + 1
+    # 32 rows: the few-rows kernel, one call an expert layer, and the
+    # state's kernel one a Mamba layer, beside the paged kernel's one;
+    # no while is left in the step
+    n_e, n_m = cfg.pattern.count("E"), cfg.pattern.count("M")
+    assert sorted(chosen) == (["held_experts"] * n_e + ["mamba_decode"] * n_m
+                              + ["paged_decode"])
+    assert text.count('custom_call_target="tpu_custom_call"') == n_e + n_m + 1
     assert "ragged-dot" not in text and " while(" not in text
+    state = "f32[5,32,128,64,128]"
+    assert not [line for line in text.splitlines() if state in line
+                and (" fusion(" in line or " copy(" in line)]
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 64e6
     # the cache goes out where it came in: state, tails and pool

@@ -1106,7 +1106,8 @@ class ContinuousBatchingEngine:
         """Admit, sample, retire, decode — one token boundary. Returns
         the requests that finished at this boundary. Four spans
         partition it (recorded while a profiler session is open):
-        admit, pick, dispatch, scatter."""
+        admit, pick, dispatch, scatter; two more partition each of pick
+        (fetch, walk) and dispatch (prepare, launch)."""
         with _span("engine.step"):
             with _span("engine.admit"):
                 self._admit()
@@ -1119,6 +1120,14 @@ class ContinuousBatchingEngine:
                     self._last = last
                     self.decode_steps += 1
                     self.occupancy_sum += sum(active)
+                    # what the step touched of each live slot's table:
+                    # the blocks up to the one its token landed in. The
+                    # host knows every length (prompt + tokens picked so
+                    # far, the one fed among them) — no device sync, and
+                    # counted here, while the device runs the step
+                    self.kv_blocks_read_total += sum(
+                        -(-(len(r.prompt) + len(r.tokens)) // self.block_size)
+                        for r in self._slot_req if r is not None)
         return finished
 
     def _pick(self):
@@ -1126,62 +1135,64 @@ class ContinuousBatchingEngine:
         retire the slots that are through. Returns the requests that
         finished and, a slot each, the token to feed and whether the
         slot stays live."""
-        # speculative requests ran whole inside _admit
-        finished, self._spec_finished = self._spec_finished, []
-        tokens = [0] * self.slots
-        active = [False] * self.slots
-        live = [(i, r) for i, r in enumerate(self._slot_req)
-                if r is not None]
-        # one program for all greedy rows, one for each sampling row
-        # (its request's own key stream)
-        greedy, sampled = None, {}
-        if any(r.temperature <= 0 for _, r in live):
-            greedy = _pick_row(self._last, None, temperature=0.0,
-                               top_k=None)
-        for i, req in live:
-            if req.temperature > 0:
-                req.key, sub = jax.random.split(req.key)
-                sampled[i] = _pick_row(self._last[i], sub,
-                                       temperature=req.temperature,
-                                       top_k=req.top_k)
-        if live:
-            # the ONE deliberate sync per token boundary, once all are
-            # dispatched: the tokens drive host-side scheduling (EOS
-            # retirement, admission) and cannot stay on device.
-            self.pick_programs_total += len(sampled) + (greedy is not None)
-            self.host_syncs_total += 1
-            greedy, sampled = jax.device_get((greedy, sampled))
-        for i, req in live:
-            nxt = int(sampled[i] if i in sampled else greedy[i])
-            now = time.perf_counter()
-            req.tokens.append(nxt)
-            req.t_tokens.append(now)
-            hit_eos = req.eos_id is not None and nxt == req.eos_id
-            if hit_eos or len(req.tokens) >= req.max_new_tokens:
-                req.done = True
-                req.t_finished = now
-                finished.append(req)
-                self._retire(i)
-                self.finished_total += 1
-            else:
-                tokens[i] = nxt
-                active[i] = True
+        # the pick programs' dispatch and the one fetch; the walk after
+        # it queues nothing, so the device sits idle all through it
+        with _span("engine.pick.fetch"):
+            # speculative requests ran whole inside _admit
+            finished, self._spec_finished = self._spec_finished, []
+            tokens = [0] * self.slots
+            active = [False] * self.slots
+            live = [(i, r) for i, r in enumerate(self._slot_req)
+                    if r is not None]
+            # one program for all greedy rows, one for each sampling row
+            # (its request's own key stream)
+            greedy, sampled = None, {}
+            if any(r.temperature <= 0 for _, r in live):
+                greedy = _pick_row(self._last, None, temperature=0.0,
+                                   top_k=None)
+            for i, req in live:
+                if req.temperature > 0:
+                    req.key, sub = jax.random.split(req.key)
+                    sampled[i] = _pick_row(self._last[i], sub,
+                                           temperature=req.temperature,
+                                           top_k=req.top_k)
+            if live:
+                # the ONE deliberate sync per token boundary, once all
+                # are dispatched: the tokens drive host-side scheduling
+                # (EOS retirement, admission) and cannot stay on device.
+                self.pick_programs_total += (len(sampled)
+                                             + (greedy is not None))
+                self.host_syncs_total += 1
+                greedy, sampled = jax.device_get((greedy, sampled))
+        with _span("engine.pick.walk"):
+            for i, req in live:
+                nxt = int(sampled[i] if i in sampled else greedy[i])
+                now = time.perf_counter()
+                req.tokens.append(nxt)
+                req.t_tokens.append(now)
+                hit_eos = req.eos_id is not None and nxt == req.eos_id
+                if hit_eos or len(req.tokens) >= req.max_new_tokens:
+                    req.done = True
+                    req.t_finished = now
+                    finished.append(req)
+                    self._retire(i)
+                    self.finished_total += 1
+                else:
+                    tokens[i] = nxt
+                    active[i] = True
         return finished, tokens, active
 
     def _dispatch(self, tokens, active):
         """One decode step for all slots; returns the logits rows."""
-        tok_arr = np.asarray(tokens, np.int32)
-        act_arr = np.asarray(active, bool)
-        _jit_sentinel.note("engine.decode_step", tok_arr, act_arr)
-        # what the step touches of each live slot's table: the blocks
-        # up to the one this token lands in. The host knows every
-        # length (prompt + tokens picked so far, the one being fed
-        # among them) — no device sync
-        self.kv_blocks_read_total += sum(
-            -(-(len(r.prompt) + len(r.tokens)) // self.block_size)
-            for r in self._slot_req if r is not None)
-        last, self.cache = paging.paged_decode_step(
-            self.params, self.cfg, self.cache, tok_arr, act_arr)
+        with _span("engine.dispatch.prepare"):
+            tok_arr = np.asarray(tokens, np.int32)
+            act_arr = np.asarray(active, bool)
+            _jit_sentinel.note("engine.decode_step", tok_arr, act_arr)
+        # jax's dispatch of the program, nothing else: the span ends
+        # when the call returns
+        with _span("engine.dispatch.launch"):
+            last, self.cache = paging.paged_decode_step(
+                self.params, self.cfg, self.cache, tok_arr, act_arr)
         return last
 
     def run(self) -> list[EngineRequest]:

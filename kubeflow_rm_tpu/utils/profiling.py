@@ -109,6 +109,12 @@ def annotate(name: str, *, hot: bool = False):
       ``engine.admit`` (with an ``engine.prefill`` around each prefill
       dispatch), ``engine.pick``, ``engine.dispatch``,
       ``engine.scatter`` — ``ContinuousBatchingEngine.step()``
+    - inside ``engine.pick``: ``engine.pick.fetch`` (the pick programs'
+      dispatch and the one ``jax.device_get``), then
+      ``engine.pick.walk`` (the loop over the live slots); inside
+      ``engine.dispatch``: ``engine.dispatch.prepare`` (the host
+      arrays, the sentinel's note), then ``engine.dispatch.launch``
+      (the ``paged_decode_step`` call alone)
     - ``gateway.drain`` around a working iteration of the gateway's
       drain thread, ``gateway.publish`` around its gauge block
     - ``train.shard_batch``, ``train.step``, ``train.log`` in ``fit()``

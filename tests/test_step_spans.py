@@ -160,6 +160,45 @@ def test_engine_and_gateway_spans_on_the_profilers_clock(model, tmp_path):
         assert all(x[1] <= y[0] for x, y in zip(kids, kids[1:]))
 
 
+def test_pick_and_dispatch_split_at_the_fetch_and_the_launch(model,
+                                                             tmp_path):
+    eng = _engine(model, slots=2)
+    eng.submit(PROMPT, max_new_tokens=2)
+    eng.run()                           # compiled before the session
+
+    def body():
+        for p, budget in ((PROMPT, 5), (PROMPT[::-1], 3), (PROMPT[2:], 4)):
+            eng.submit(p, max_new_tokens=budget)
+        eng.run()
+    host = _session(tmp_path, body)
+    steps = _spans(host, "engine.step")
+    dispatching = 0
+    for a, b in steps:
+        def mine(name):
+            return [s for s in _spans(host, name) if a <= s[0] and s[1] <= b]
+        pick, dispatch = mine("engine.pick"), mine("engine.dispatch")
+        fetch, walk = mine("engine.pick.fetch"), mine("engine.pick.walk")
+        prepare = mine("engine.dispatch.prepare")
+        launch = mine("engine.dispatch.launch")
+        # every step picks: one fetch, then one walk, inside its pick
+        assert len(pick) == len(fetch) == len(walk) == 1
+        assert pick[0][0] <= fetch[0][0] and fetch[0][1] <= walk[0][0]
+        assert walk[0][1] <= pick[0][1]
+        if not dispatch:
+            assert not prepare and not launch
+            continue
+        dispatching += 1
+        # a step that decodes: one preparation, then one launch, inside
+        # its dispatch, which begins after the walk has ended
+        assert len(dispatch) == len(prepare) == len(launch) == 1
+        assert dispatch[0][0] <= prepare[0][0]
+        assert prepare[0][1] <= launch[0][0]
+        assert launch[0][1] <= dispatch[0][1]
+        assert walk[0][1] <= dispatch[0][0]
+    assert dispatching >= 4
+    assert dispatching == len(_spans(host, "engine.dispatch.launch"))
+
+
 def test_fit_spans_on_the_profilers_clock(tmp_path):
     cfg = TrainConfig(model=LlamaConfig.tiny())
     mesh = make_mesh(MeshConfig(fsdp=1), devices=jax.devices()[:1])
@@ -193,6 +232,25 @@ def test_no_session_no_span_and_no_switch():
         with annotate("engine.step"):
             pass
     assert (time.perf_counter() - t) / 1000 < 50e-6
+    # a decoding step's whole set: the step's span and the eight inside
+    t = time.perf_counter()
+    for _ in range(1000):
+        with annotate("engine.step"):
+            with annotate("engine.admit"):
+                pass
+            with annotate("engine.pick"):
+                with annotate("engine.pick.fetch"):
+                    pass
+                with annotate("engine.pick.walk"):
+                    pass
+            with annotate("engine.dispatch", hot=True):
+                with annotate("engine.dispatch.prepare"):
+                    pass
+                with annotate("engine.dispatch.launch"):
+                    pass
+            with annotate("engine.scatter"):
+                pass
+    assert (time.perf_counter() - t) / 1000 < 9 * 50e-6
 
 
 # ---- (d) the fleet hands the stamps on; three request spans -----------

@@ -202,10 +202,12 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
         ContinuousBatchingEngine, _bucket_len,
     )
 
+    # the references below read the tree as trained; the engine shares
+    # its leaves but for the projections it keeps (out, in)
+    params = init_params(cfg, jax.random.key(SEED))
     engine = ContinuousBatchingEngine(
-        init_params(cfg, jax.random.key(SEED)), cfg, slots=sz.slots,
-        slot_len=sz.slot_len, block_size=sz.block_size)
-    params = engine.params      # the engine's own copy is the only one
+        params, cfg, slots=sz.slots, slot_len=sz.slot_len,
+        block_size=sz.block_size)
     # the tenant's latency promise has to admit a cold start: the
     # first requests wait out every compile, and the default 2 s SLO
     # would shed whoever submits after them
@@ -220,7 +222,7 @@ def serve_phase(cfg, sz: Sizes, device) -> dict:
     # XLA gather (nothing runs here, so the cache is not donated yet)
     from kubeflow_rm_tpu.models import paging
     kernels = paging.paged_decode_step.lower(
-        params, cfg, engine.cache, jnp.zeros((sz.slots,), jnp.int32),
+        engine.params, cfg, engine.cache, jnp.zeros((sz.slots,), jnp.int32),
         jnp.zeros((sz.slots,), bool)).as_text().count("tpu_custom_call")
     if device.platform == "tpu" and not kernels:
         raise RuntimeError("the lowered paged_decode_step holds no "

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from kubeflow_rm_tpu.models.llama import LlamaConfig
-from kubeflow_rm_tpu.models.lora import lora_proj
+from kubeflow_rm_tpu.models.lora import LORA_A, lora_proj
 from kubeflow_rm_tpu.models.quantize import maybe_dequant
 from kubeflow_rm_tpu.ops import (
     apply_rope,
@@ -41,6 +41,44 @@ from kubeflow_rm_tpu.ops import (
 )
 
 _UNFILLED = jnp.iinfo(jnp.int32).max
+
+#: the projections the serving engine keeps (out, in), each as a leaf
+#: ``<name>_out_in`` (L, out, in) in place of ``<name>`` (L, in, out):
+#: stored (in, out), XLA re-lays the layer's slice (out, in) before the
+#: matmul, every layer of every step, for what rope, the attention and
+#: the cache ask of q, k and v (``copy bf16[1,4096,4096]`` for ``wq`` at
+#: Mistral's widths; ``wv``'s in prefill only). ``wo`` and the FFN are
+#: read in place either way
+OUT_IN_LEAVES = ("wq", "wk", "wv")
+OUT_IN = "_out_in"
+
+
+def serving_layout(params: dict) -> dict:
+    """``params`` with each of ``OUT_IN_LEAVES`` in ``blocks`` kept
+    (out, in) under its own name, for ``_run_blocks``; the tree itself
+    where there is none to turn. A quantized leaf (a dict) and a leaf
+    with LoRA adapters stay as they are, as does every other leaf (the
+    same arrays, not copies)."""
+    blocks = params.get("blocks", {})
+    turn = [name for name in OUT_IN_LEAVES
+            if name in blocks and not isinstance(blocks[name], dict)
+            and name + LORA_A not in blocks]
+    if not turn:
+        return params
+    blocks = dict(blocks)
+    for name in turn:
+        blocks[name + OUT_IN] = jnp.swapaxes(blocks.pop(name), -1, -2)
+    return dict(params, blocks=blocks)
+
+
+def _proj(layer: dict, name: str, h: jax.Array, *, alpha: float,
+          dtype) -> jax.Array:
+    """``lora_proj``, or ``h`` contracted with the last axis of the
+    leaf kept (out, in) where the layer holds one."""
+    w = layer.get(name + OUT_IN)
+    if w is None:
+        return lora_proj(layer, name, h, alpha=alpha, dtype=dtype)
+    return jnp.einsum("btd,od->bto", h, w.astype(dtype))
 
 
 @jax.tree_util.register_dataclass
@@ -134,7 +172,10 @@ def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
     layer's index for ``paged_decode_step``, which reads the pool
     through the block table. The math around it is identical, which
     is what makes the continuous-batching engine bit-identical to
-    ``generate_fused``. Returns (logits, the stacked ``ys``)."""
+    ``generate_fused``. A projection the tree holds (out, in)
+    (``serving_layout``, the engine's tree) is contracted on its last
+    axis, one held (in, out) as trained on its first. Returns (logits,
+    the stacked ``ys``)."""
     B, Tc = tokens.shape
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cdt = cfg.dtype
@@ -168,7 +209,7 @@ def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
 
     def body(x, scanned):
         layer, xs = scanned
-        proj = partial(lora_proj, layer, alpha=cfg.lora_alpha, dtype=cdt)
+        proj = partial(_proj, layer, alpha=cfg.lora_alpha, dtype=cdt)
         h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = proj("wq", h).reshape(B, Tc, H, hd)
         k = proj("wk", h).reshape(B, Tc, KVH, hd)

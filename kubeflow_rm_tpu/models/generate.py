@@ -30,7 +30,7 @@ import numpy as np
 from kubeflow_rm_tpu.analysis.jaxcheck import recompile as _jit_sentinel
 from kubeflow_rm_tpu.models import paging
 from kubeflow_rm_tpu.models.decode import (
-    _UNFILLED, KVCache, decode_chunk, init_cache,
+    _UNFILLED, KVCache, decode_chunk, init_cache, serving_layout,
 )
 from kubeflow_rm_tpu.models.llama import LlamaConfig
 from kubeflow_rm_tpu.models.quantize import (
@@ -591,9 +591,12 @@ class ContinuousBatchingEngine:
         self.slot_len = slot_len
         self.block_size = block_size
         # unpack int4 leaves once, outside any per-step work; a tree
-        # without one is kept as it is, not copied through a program
-        self.params = (jax.jit(unpack_int4_params)(params)
-                       if has_int4_leaf(params) else params)
+        # without one is kept as it is, not copied through a program;
+        # the projections the decode matmuls read (out, in) are turned
+        # once here too, the other leaves shared with the caller's tree
+        self.params = serving_layout(
+            jax.jit(unpack_int4_params)(params) if has_int4_leaf(params)
+            else params)
         self._recurrent = bool(cfg.has_recurrent_state)
         # the cache lives where the weights live: a replica whose
         # params were committed to its own chip must not allocate its

@@ -37,7 +37,9 @@ then ``E``.
 Parameters are stacked per kind as ``models.nemotron_h``'s are
 (``blocks_l``, ``blocks_f``, ``blocks_e``), each layer of a stack drawn
 from its own key; the expert matrices are a list with an array a layer
-(a slice of a stacked operand is copied before a custom call).
+(a slice of a stacked operand is copied before a custom call), and so
+is ``W_qkvz`` (from a stack, XLA copies all its layers' slices out
+every decode step, 150 MB), each drawn as the stack's layer was.
 ``vocab_size`` is the rows of embedding and head held here. The
 multi-token-prediction layer of the published model is not built.
 
@@ -201,8 +203,9 @@ def param_spec_shapes(cfg: Qwen3NextConfig) -> dict:
         "blocks_l": {
             "norm": (Ll, D),
             # (out, in) as published: a key head's [q | k | v of its
-            # value heads | z of them] (see _PUBLISHED_LAYOUT)
-            "w_qkvz": (Ll, 2 * cfg.key_dim + 2 * cfg.value_dim, D),
+            # value heads | z of them] (see _PUBLISHED_LAYOUT); a layer
+            # each: see the module docstring
+            "w_qkvz": [(2 * cfg.key_dim + 2 * cfg.value_dim, D)] * Ll,
             "w_ba": (Ll, D, 2 * Hv),                  # [b | a] a key head
             "conv_w": (Ll, cfg.conv_kernel, cfg.conv_dim),
             "dt_bias": (Ll, Hv),

@@ -409,6 +409,62 @@ def test_engine_matches_one_shot_fused(model):
     assert 0 < stats["batch_occupancy"] <= 1.0
 
 
+@pytest.mark.parametrize("tree", ["plain", "int8-wq", "lora-wq-wv"])
+def test_engine_keeps_projections_out_in_and_serves_the_same_tokens(
+        model, tree):
+    """The engine keeps ``wq``, ``wk`` and ``wv`` (out, in) under names
+    of their own, once, and serves the tokens ``generate_fused`` gives
+    on the tree as trained; a quantized leaf and a leaf with LoRA
+    adapters stay as they are beside the turned ones."""
+    from kubeflow_rm_tpu.models.decode import OUT_IN, OUT_IN_LEAVES
+    from kubeflow_rm_tpu.models.generate import (
+        ContinuousBatchingEngine, generate_fused,
+    )
+    from kubeflow_rm_tpu.models.lora import LORA_B, add_lora
+    from kubeflow_rm_tpu.models.quantize import quantize_params
+
+    cfg, params = model
+    kept = ()
+    if tree == "int8-wq":
+        q8 = quantize_params(params)["blocks"]["wq"]
+        params = dict(params, blocks={**params["blocks"], "wq": q8})
+        kept = ("wq",)
+    elif tree == "lora-wq-wv":
+        params = add_lora(params, 4, key=jax.random.key(5))
+        blocks = dict(params["blocks"])
+        for name, k in (("wq", 6), ("wv", 7)):   # adapters that matter
+            b = blocks[name + LORA_B]
+            blocks[name + LORA_B] = jax.random.normal(
+                jax.random.key(k), b.shape) * 0.5
+        params = dict(params, blocks=blocks)
+        kept = ("wq", "wv")
+    slot_len = 32
+    eng = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=slot_len)
+    blocks = eng.params["blocks"]
+    for name in OUT_IN_LEAVES:
+        if name in kept:
+            assert blocks[name] is params["blocks"][name]
+            assert name + OUT_IN not in blocks
+        else:
+            assert name not in blocks
+            np.testing.assert_array_equal(
+                blocks[name + OUT_IN],
+                np.swapaxes(params["blocks"][name], 1, 2))
+    assert blocks["wo"] is params["blocks"]["wo"]
+
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+               for n in (4, 9, 6)]
+    reqs = [eng.submit(p, max_new_tokens=m)
+            for p, m in zip(prompts, (7, 5, 8))]
+    eng.run()
+    for p, r in zip(prompts, reqs):
+        ref = generate_fused(params, cfg, jnp.asarray([p], jnp.int32),
+                             max_new_tokens=len(r.tokens),
+                             max_len=slot_len)
+        assert r.tokens == np.asarray(ref[0, len(p):]).tolist()
+
+
 def test_engine_validation_and_sampling(model):
     """Capacity guard (prefill bucket + budget must fit the slot),
     empty prompts, the sampling key requirement — and that a sampled

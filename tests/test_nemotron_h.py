@@ -30,6 +30,7 @@ from kubeflow_rm_tpu.models import (  # noqa: E402
     ContinuousBatchingEngine, LlamaConfig, NemotronHConfig, init_params,
 )
 from kubeflow_rm_tpu.models import nemotron_h as nh, paging  # noqa: E402
+from kubeflow_rm_tpu.models.decode import OUT_IN, OUT_IN_LEAVES  # noqa: E402
 from kubeflow_rm_tpu.ops import mamba_decode  # noqa: E402
 from kubeflow_rm_tpu.parallel.moe import held_experts_ffn  # noqa: E402
 from perf import reference_nemotron_h as ref  # noqa: E402
@@ -344,7 +345,17 @@ def test_llama_keeps_its_prefix_hits_and_chains():
     params = init_params(cfg, jax.random.key(0))
     eng = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=64,
                                    block_size=4)
-    assert eng.params is params          # no int4 leaf: not copied
+    # no int4 leaf: nothing is copied through a program; the decode
+    # projections are turned (out, in), every other leaf is the caller's
+    mine = eng.params["blocks"]
+    for name, leaf in params["blocks"].items():
+        if name in OUT_IN_LEAVES:
+            assert name not in mine
+            assert bool(jnp.all(mine[name + OUT_IN]
+                                == jnp.swapaxes(leaf, 1, 2)))
+        else:
+            assert mine[name] is leaf
+    assert all(eng.params[k] is params[k] for k in params if k != "blocks")
     prompt = list(range(1, 14))
     eng.submit(prompt, max_new_tokens=4)
     eng.run()

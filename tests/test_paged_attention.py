@@ -10,6 +10,8 @@ numpy. The last test compiles the kernel for the chip at the
 benchmark's widths, which interpret mode cannot vouch for.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -400,6 +402,93 @@ def test_wide_head_kernel_reads_the_pool_where_it_lies(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1e6
 
 
+def _weight_writes(text, shapes):
+    """The top-level ops of a compiled module (outside fused
+    computations and custom calls) that write an array of one of
+    ``shapes`` (leading 1s dropped), alone or in a tuple: a ``copy``,
+    or a fusion whose output lies in HBM. A fusion into VMEM (``S(1)``)
+    of a layer's slice is how the matmul that follows reads its
+    operand, not a copy; nor are the async ``copy-start``/``copy-done``
+    prefetches."""
+    found = []
+    for comp in re.split(r"\n(?=\S)", text):
+        if "fused" in comp.split("\n", 1)[0]:
+            continue
+        for line in comp.split("\n")[1:]:
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) (copy|fusion)\(", line)
+            if not m:
+                continue
+            for dims, layout in re.findall(r"\w+\[([\d,]*)\](\{[^}]*\})?",
+                                           m.group(1)):
+                dims = tuple(int(d) for d in dims.split(",") if d)
+                while dims[:1] == (1,):
+                    dims = dims[1:]
+                if dims in shapes and (m.group(2) == "copy"
+                                       or "S(1)" not in layout):
+                    found.append(line.strip()[:160])
+                    break
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill-16", "prefill-128"])
+def test_mistral_programs_read_their_projections_in_place(one_chip,
+                                                           program):
+    """Mistral's serving programs at published widths (two layers, so
+    the layer scan stays a while), compiled for the chip: from the
+    engine's tree, ``wq``, ``wk`` and ``wv`` kept (out, in), no layer's
+    slice of a projection is copied or re-laid in HBM. From the tree as
+    trained, (in, out), XLA re-lays ``wq``'s and ``wk``'s slice every
+    layer of every step (``copy bf16[1,4096,4096]``, rope and the
+    attention read q and k (out, in)), and ``wv``'s in prefill: the
+    check sees a copy where there is one."""
+    from unittest import mock
+
+    from kubeflow_rm_tpu.models import LlamaConfig, init_params, paging
+    from kubeflow_rm_tpu.models.decode import serving_layout
+
+    cfg = LlamaConfig(vocab_size=32000, dim=4096, n_layers=2, n_heads=32,
+                      n_kv_heads=8, hidden_dim=14336, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    slots, slot_len, bs = 16, 2048, 16
+    maxb = slot_len // bs
+    D, hd = cfg.dim, cfg.head_dim
+    per_layer = set()
+    for out in (cfg.n_heads * hd, cfg.n_kv_heads * hd):
+        per_layer |= {(D, out), (out, D), (out // hd, hd, D),
+                      (D, out // hd, hd)}
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype), tree)
+
+    trained = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    cache = shaped(jax.eval_shape(lambda: paging.init_paged_cache(
+        cfg, slots, slot_len, 2 + slots * maxb + slots * maxb // 2, bs)))
+
+    def compiled_text(params):
+        params = shaped(params)
+        on_chip = lambda *a, **k: ("tpu", 1)  # noqa: E731
+        with mock.patch.object(pa, "computation_devices", on_chip):
+            if program == "decode":
+                lowered = paging.paged_decode_step.lower(
+                    params, cfg, cache, sds((slots,), jnp.int32),
+                    sds((slots,), jnp.bool_))
+            else:
+                T = int(program.split("-")[1])
+                lowered = paging.paged_prefill.lower(
+                    params, cfg, cache, sds((maxb,), jnp.int32),
+                    sds((), jnp.int32), sds((1, T), jnp.int32),
+                    sds((), jnp.int32))
+        return _compiled_outside_the_cache(lowered).as_text()
+
+    served = compiled_text(jax.eval_shape(serving_layout, trained))
+    assert " while(" in served
+    assert _weight_writes(served, per_layer) == []
+    assert _weight_writes(compiled_text(trained), per_layer)
+
+
 def test_qwen3_next_decode_step_compiles_for_the_chip_at_published_widths(
         one_chip):
     """The decode step of the Qwen3-Next cut at the benchmark's widths
@@ -448,5 +537,9 @@ def test_qwen3_next_decode_step_compiles_for_the_chip_at_published_widths(
     assert text.count('custom_call_target="tpu_custom_call"') == 8
     assert "ragged-dot" not in text and " while(" not in text
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 160e6
+    # W_qkvz is an array a layer: sliced out of a stack over the three
+    # linear layers, all three layers' (12288, 2048) were copied every
+    # step (104 MB of temporaries)
+    assert memory.temp_size_in_bytes < 16e6
+    assert _weight_writes(text, {(12288, 2048)}) == []
     assert memory.alias_size_in_bytes > 1.0e9

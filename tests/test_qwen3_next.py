@@ -121,6 +121,37 @@ def test_weights_and_forward_match_the_reference(held):
     assert float(jnp.abs(want).max()) > 0.3          # logits of order 1
 
 
+def test_w_qkvz_is_an_array_a_layer_drawn_as_the_stack_was(tiny):
+    """``W_qkvz`` is a list with an array a layer (sliced out of one
+    stack over the linear layers, every layer's was copied every decode
+    step), and each is the layer the stacked draw gave: the leaf's key
+    in the tree's order, split a layer, under ``vmap``. At the cell's
+    widths the count stays 3,677,613,120."""
+    cfg, params = tiny
+    shapes = qn.param_spec_shapes(cfg)
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        shapes, is_leaf=lambda x: isinstance(x, (tuple, list)))
+    names = [tuple(p.key for p in path) for path, _ in flat]
+    key = jax.random.split(jax.random.key(3), len(flat))[
+        names.index(("blocks_l", "w_qkvz"))]
+    Ll = cfg.pattern.count("L")
+    one = shapes["blocks_l"]["w_qkvz"][0]
+    stacked = jax.jit(jax.vmap(
+        lambda k: qn._init_leaf(cfg, "w_qkvz", one, k)))(
+        jax.random.split(key, Ll))
+    mine = params["blocks_l"]["w_qkvz"]
+    assert isinstance(mine, list) and len(mine) == Ll
+    for i in range(Ll):
+        assert mine[i].shape == one
+        np.testing.assert_array_equal(mine[i], stacked[i])
+    cut = Config(vocab_size=75968, experts_held=(0, 256),
+                 dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    tree = jax.eval_shape(lambda: init_params(cut, jax.random.key(0)))
+    assert [a.shape for a in tree["blocks_l"]["w_qkvz"]] == [(12288,
+                                                             2048)] * 3
+    assert sum(a.size for a in jax.tree.leaves(tree)) == 3677613120
+
+
 def test_reference_head_readings_are_its_logits():
     """The reference's blocked head says of each column what its whole
     logits say: the served token's gap, the argmax, the largest size."""

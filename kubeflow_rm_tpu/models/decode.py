@@ -229,16 +229,20 @@ def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
 
 
 def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
-                       attend):
+                       attend, attend_window=None, head_at=None):
     """The trunk of a config whose layers are parts of several kinds
-    (``models.nemotron_h``, ``models.qwen3_next``), beside
-    ``_run_blocks`` and for the same callers: embed, the pattern's
-    parts in order, each ``x <- x + part(norm(x))``, final norm, head.
+    (``models.nemotron_h``, ``models.qwen3_next``, ``models.afmoe``),
+    beside ``_run_blocks`` and for the same callers: embed, the
+    pattern's parts in order, each ``x <- x + part(norm(x))``, final
+    norm, head.
 
     The config names its parts: ``cfg.hybrid_parts()`` maps each
     character of ``cfg.pattern`` to (the stack of its parameters, its
     role, its function), and ``cfg.norm(x, gain)`` is the family's
-    pre-norm. Three roles:
+    pre-norm. A part whose layer holds a ``post_norm`` gain is normed
+    after as well (``x <- x + norm'(part(norm(x)))``, sandwich norms),
+    and a config with an ``embed_scale`` multiplies the embedding by
+    it. Five roles:
 
     - ``"recurrent"``: ``fn(cfg, layer, h, ssm, conv, i, mask) -> (out,
       ssm', conv')`` advances the ``i``-th recurrent layer's state, a
@@ -249,7 +253,11 @@ def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
     - ``"attention"``: ``fn(cfg, layer, h, positions) -> (q, k, v,
       finish)``; ``attend(q, k, v, i) -> (attn, ys)`` lands the
       ``i``-th attention layer's K/V and attends, as ``_run_blocks``'
-      does, and ``finish(attn (B, Tc, H hd))`` is the part's output.
+      does, and ``finish(attn (B, Tc, H hd))`` is the part's output;
+    - ``"window"``: the same, through ``attend_window`` and its own
+      count of layers: attention over a sliding window, whose cache is
+      not the block pool;
+    - ``"mlp"``: ``fn(cfg, layer, h) -> out``, a dense feed-forward.
 
     A Python loop over the pattern, not a scan: the kinds differ in
     weights, state and work, one period (what a chip of the benchmark's
@@ -259,12 +267,20 @@ def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
     decode the live rows): the others advance no state and reach no
     expert. ``positions`` (B, Tc) are the columns' (``_UNFILLED`` where
     masked), for a family whose attention rotates by position.
-    Returns (logits, (ssm', conv'), the list of ``ys``, int32 (expert
-    assignments held, held experts with a token, 1))."""
+    ``head_at`` (a traced column index) applies the final norm and the
+    head to that column alone, the logits then (B, 1, vocab): a long
+    prompt's prefill wants its last real column's, and the head over
+    every column of an 8,192 bucket is a (8192, vocab) product.
+    Returns (logits, (ssm', conv'), the list of ``ys`` (both attention
+    roles', in pattern order), int32 (expert assignments held, held
+    experts with a token, 1))."""
     B, Tc = tokens.shape
     cdt = cfg.dtype
     parts = cfg.hybrid_parts()
     x = params["embed"]["tokens"][tokens].astype(cdt)
+    scale = getattr(cfg, "embed_scale", None)
+    if scale is not None:
+        x = x * scale
     at = dict.fromkeys(parts, 0)
     ys = []
     counts = jnp.zeros((2,), jnp.int32)
@@ -279,12 +295,19 @@ def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
         elif role == "experts":
             out, held = part(cfg, layer, h, mask)
             counts = counts + jnp.stack(held)
+        elif role == "mlp":
+            out = part(cfg, layer, h)
         else:
             q, k, v, finish = part(cfg, layer, h, positions)
-            attn, y = attend(q, k, v, i)
+            attn, y = (attend_window if role == "window" else attend)(
+                q, k, v, i)
             ys.append(y)
             out = finish(attn.reshape(B, Tc, -1))
+        if "post_norm" in layer:
+            out = cfg.norm(out, layer["post_norm"])
         x = x + out
+    if head_at is not None:
+        x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
     x = cfg.norm(x, params["norm_f"])
     logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
     counts = jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])

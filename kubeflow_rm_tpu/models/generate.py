@@ -567,7 +567,10 @@ class ContinuousBatchingEngine:
     hits passed over) and the entry points that move or rewind a chain
     of blocks raise: ``install_chain``, ``adopt_chain``,
     ``prefill_chain`` and ``speculative=True``. State snapshots at
-    block boundaries would lift both (ROADMAP R7).
+    block boundaries would lift both (ROADMAP R7). A config with
+    sliding-window layers (``models.afmoe``) is refused the same, for
+    the same reason: a window layer keeps a ring of its last positions
+    a slot (``paging.WindowPagedCache``), overwritten in place.
     """
 
     def __init__(self, params, cfg, *, slots: int = 8,
@@ -598,6 +601,8 @@ class ContinuousBatchingEngine:
             jax.jit(unpack_int4_params)(params) if has_int4_leaf(params)
             else params)
         self._recurrent = bool(cfg.has_recurrent_state)
+        # what a slot holds beside its blocks, or None
+        self._slot_state = paging.slot_state(cfg)
         # the cache lives where the weights live: a replica whose
         # params were committed to its own chip must not allocate its
         # pool on the default device (every replica of a fleet on
@@ -676,7 +681,7 @@ class ContinuousBatchingEngine:
             raise ValueError(f"unknown slo_class {slo_class!r} "
                              f"(one of {SLO_CLASSES})")
         if speculative:
-            self._refuse_recurrent("speculative decode")
+            self._refuse_slot_state("speculative decode")
             # one fused program monopolizes the device for the whole
             # generation — a latency-class request must never do that,
             # and prompt-lookup drafting is greedy by construction
@@ -725,7 +730,7 @@ class ContinuousBatchingEngine:
         last-token logits — zero prefill FLOPs on this replica.
         Verification happens here, before queueing: a corrupted chunk
         raises ``ValueError`` and nothing is enqueued."""
-        self._refuse_recurrent("install_chain")
+        self._refuse_slot_state("install_chain")
         paging.verify_chain(chain)
         if int(chain["block_size"]) != self.block_size:
             raise ValueError(
@@ -753,7 +758,7 @@ class ContinuousBatchingEngine:
         sharing the prefix hits it like any locally-prefilled chain.
         Returns the number of chunks adopted (0 when the chain is
         already local or the pool is transiently full)."""
-        self._refuse_recurrent("adopt_chain")
+        self._refuse_slot_state("adopt_chain")
         keys = list(zip(chain["covers"], chain["keys"]))
         if len(self.pool.lookup_chain(keys)) == len(keys):
             return 0
@@ -765,17 +770,17 @@ class ContinuousBatchingEngine:
         self.chains_adopted += 1
         return len(blocks)
 
-    def _refuse_recurrent(self, what: str) -> None:
-        if self._recurrent:
+    def _refuse_slot_state(self, what: str) -> None:
+        if self._slot_state:
             raise ValueError(
                 f"{what} needs cache state addressable by token block; "
-                f"{type(self.cfg).__name__} has recurrent layers, whose "
-                "state is kept a slot (no snapshots yet)")
+                f"{type(self.cfg).__name__} has {self._slot_state}")
 
     def chain_coverage(self, prompt) -> int:
         """Prompt tokens the local prefix cache already covers (for a
-        config with recurrent layers none: a hit is not taken)."""
-        if self._recurrent:
+        config with recurrent or window layers none: a hit is not
+        taken)."""
+        if self._slot_state:
             return 0
         keys = paging.prefix_keys(prompt, self.block_size)
         chain = self.pool.lookup_chain(keys)
@@ -901,7 +906,7 @@ class ContinuousBatchingEngine:
         maxb = self.slot_len // BS
         Tp = len(prompt)
         chain = pool.lookup_chain(keys)
-        if self._recurrent and chain:
+        if self._slot_state and chain:
             # the blocks are there, the state that goes with them is not
             self.prefix_hits_refused_total += 1
             chain = []
@@ -1047,7 +1052,7 @@ class ContinuousBatchingEngine:
         behind as retained (ref-0) prefix cache, so a resumed or
         repeated prompt only prefills its new suffix. Returns ``None``
         on transient block OOM."""
-        self._refuse_recurrent("prefill_chain")
+        self._refuse_slot_state("prefill_chain")
         prompt = [int(t) for t in prompt]
         Tp = len(prompt)
         if Tp == 0:
@@ -1227,19 +1232,25 @@ class ContinuousBatchingEngine:
         step (``stats()`` does no device fetch, the gateway calls it
         every step). ``*_total`` sum decode steps and prefills,
         ``decode_*`` are the decode steps' alone; empty for a family
-        whose programs keep none."""
-        if not self._recurrent:
+        whose programs keep none. With window layers two more: the
+        pairs one window layer and one full layer attended, each as a
+        count modulo 2^32 (a reader takes differences modulo 2^32)."""
+        if not hasattr(self.cache, "counters"):
             return {}
         names = ("expert_assignments_held_total", "experts_active_total",
-                 "moe_steps_total")
+                 "moe_steps_total", "window_positions_total",
+                 "full_positions_total")
         dec, pre = np.asarray(jax.device_get(self.cache.counters),
                               np.int64)
         out = {n: int(d + p) for n, d, p in zip(names, dec, pre)}
         out.update({"decode_" + n: int(d) for n, d in zip(names, dec)})
-        return out
+        # the pairs' counts wrap: their residues, never a negative
+        return {n: v % (1 << 32) if "positions" in n else v
+                for n, v in out.items()}
 
     def stats(self) -> dict:
         steps = self.decode_steps
+        kv = getattr(self.cache, "kv", self.cache)     # the block pool
         return {
             "slots": self.slots,
             "slot_len": self.slot_len,
@@ -1267,6 +1278,10 @@ class ContinuousBatchingEngine:
             "recurrent_state_bytes": (
                 self.cache.ssm.nbytes + self.cache.conv.nbytes
                 if self._recurrent else 0),
+            "window_state_bytes": (
+                self.cache.ring_k.nbytes + self.cache.ring_v.nbytes
+                if hasattr(self.cache, "ring_k") else 0),
+            "kv_pool_bytes": kv.k.nbytes + kv.v.nbytes,
             "chain_installs": self.chain_installs,
             "chains_exported": self.chains_exported,
             "chains_adopted": self.chains_adopted,

@@ -62,7 +62,10 @@ from kubeflow_rm_tpu.models.decode import (
     _UNFILLED, _cache_attend, _run_blocks, _run_hybrid_blocks,
 )
 from kubeflow_rm_tpu.models.llama import LlamaConfig
-from kubeflow_rm_tpu.ops.paged_attention import paged_decode_attention
+from kubeflow_rm_tpu.ops import dot_product_attention
+from kubeflow_rm_tpu.ops.paged_attention import (
+    paged_decode_attention, window_decode_attention,
+)
 
 #: reserved block ids (see module docstring)
 NULL_BLOCK = 0
@@ -115,11 +118,69 @@ class HybridPagedCache:
     counters: jax.Array      # (2, 3) int32
 
 
+@jax.tree_util.register_dataclass
+@dataclass
+class WindowPagedCache:
+    """Two kinds of attention state in one cache, for a config with
+    sliding-window layers (``cfg.n_window_layers``): the block pool of
+    its full attention layers (``kv``; its ``L`` is their number) and,
+    a slot and window layer each, a ring of the last ``Wc`` positions'
+    keys and values (``ring_len``: the window rounded up to whole
+    blocks), position ``p`` at offset ``p % Wc``, overwritten in place
+    as the context grows: a long context never grows it. A slot's count
+    of tokens is its pool counters' (``kv.write_idx``: no prefix is
+    adopted, so offset and position agree). Admission writes a
+    prefill's last window over whatever the ring held
+    (``paged_install``).
+
+    ``counters`` (2, 5) int32 are the programs' own, accumulated on the
+    device by the programs that donate the cache (row 0 the decode
+    steps, row 1 the prefills, added at install): the expert layers'
+    three (``HybridPagedCache``'s), then the (query, key) pairs one
+    window layer and one full layer attended, over the live slots (a
+    decode step: each slot's cached tokens and its own, the window's at
+    most; a prefill: its real tokens'). The pairs wrap within minutes
+    of traffic: a reader takes differences modulo 2^32."""
+    kv: PagedKVCache
+    ring_k: jax.Array        # (Lw, SLOTS, Wc, KVH, hd)
+    ring_v: jax.Array
+    counters: jax.Array      # (2, 5) int32
+
+
+def ring_len(cfg, block_size: int) -> int:
+    """Positions of a window layer's ring a slot: the window rounded up
+    to whole blocks."""
+    return -(-cfg.sliding_window // block_size) * block_size
+
+
+def slot_state(cfg) -> str | None:
+    """What a slot's cache holds that no block of tokens addresses, said
+    for a refusal, or None: a prefix of such a cache cannot be adopted,
+    exported or rewound."""
+    if cfg.has_recurrent_state:
+        return "recurrent layers, whose state is kept a slot (no snapshots yet)"
+    if getattr(cfg, "n_window_layers", 0):
+        return ("window layers, whose last positions are kept a slot in a "
+                "ring overwritten in place (no block of it stays whole)")
+    return None
+
+
 def init_paged_cache(cfg: LlamaConfig, slots: int, slot_len: int,
                      num_blocks: int, block_size: int) -> PagedKVCache:
     """The cache of ``cfg``'s family: a ``PagedKVCache`` over every
-    layer, or for a config with recurrent layers a ``HybridPagedCache``
-    whose pool serves the attention layers only."""
+    layer, for a config with recurrent layers a ``HybridPagedCache``
+    whose pool serves the attention layers only, and for one with
+    window layers a ``WindowPagedCache`` whose pool serves the full
+    layers only."""
+    if getattr(cfg, "n_window_layers", 0):
+        shape = (cfg.n_window_layers, slots, ring_len(cfg, block_size),
+                 cfg.n_kv_heads, cfg.head_dim)
+        return WindowPagedCache(
+            kv=_init_kv_pool(cfg, cfg.n_attention_layers, slots, slot_len,
+                             num_blocks, block_size),
+            ring_k=jnp.zeros(shape, cfg.dtype),
+            ring_v=jnp.zeros(shape, cfg.dtype),
+            counters=jnp.zeros((2, 5), jnp.int32))
     if cfg.has_recurrent_state:
         if not cfg.n_attention_layers:
             raise ValueError(f"pattern {cfg.pattern!r} has no attention "
@@ -185,6 +246,8 @@ def paged_decode_step(params, cfg, cache: PagedKVCache, tokens, active):
     """
     if isinstance(cache, HybridPagedCache):
         return _hybrid_decode_step(params, cfg, cache, tokens, active)
+    if isinstance(cache, WindowPagedCache):
+        return _window_decode_step(params, cfg, cache, tokens, active)
     positions, wi, blk, off, kv_positions = _decode_columns(cache, active)
     logits, (col_k, col_v) = _run_blocks(
         params, cfg, tokens[:, None], positions,
@@ -265,6 +328,60 @@ def _hybrid_decode_step(params, cfg, cache: HybridPagedCache, tokens,
         ssm=ssm, conv=conv, counters=cache.counters.at[0].add(counts))
 
 
+def _by_kind(cfg, cols):
+    """The trunk's attention outputs, in pattern order, split into the
+    full layers' and the window layers' (each stacked)."""
+    kinds = [c for c in cfg.pattern if c in "WF"]
+    return tuple(tuple(jnp.stack(c) for c in zip(
+        *[y for kind, y in zip(kinds, cols) if kind == want]))
+        for want in "FW")
+
+
+def _window_pairs(n, window: int):
+    """(query, key) pairs a causal window of ``window`` holds over the
+    first ``n`` positions."""
+    inside = jnp.minimum(n, window)
+    return inside * (inside + 1) // 2 + (n - inside) * window
+
+
+def _window_decode_step(params, cfg, cache: WindowPagedCache, tokens,
+                        active):
+    """``paged_decode_step`` for a config with window layers: the full
+    layers as above over ``cache.kv``; a window layer reads its ring
+    (``ops.paged_attention.window_decode_attention``: the window's
+    positions at most, however long the context) and its new column
+    lands at ``n % Wc`` of a live row's ring (an inactive row's ring is
+    left as it was); the expert layers and the pairs each kind attended
+    add to the counters."""
+    kv = cache.kv
+    W, Wc = cfg.sliding_window, cache.ring_k.shape[2]
+    n = kv.write_idx
+    positions, wi, blk, off, kv_positions = _decode_columns(kv, active)
+
+    def attend_window(q, k, v, i):
+        attn = window_decode_attention(
+            q[:, 0], k[:, 0], v[:, 0], cache.ring_k, cache.ring_v, i, n,
+            active, window=W)
+        return attn[:, None], (k[:, 0], v[:, 0])
+
+    logits, _state, cols, counts = _run_hybrid_blocks(
+        params, cfg, tokens[:, None], active[:, None], positions, None, None,
+        _pool_attend(kv, positions, wi, active, kv_positions),
+        attend_window)
+    (col_k, col_v), (ring_col_k, ring_col_v) = _by_kind(cfg, cols)
+    rows = jnp.arange(n.shape[0], dtype=jnp.int32)
+    at = jnp.where(active, jnp.remainder(n, Wc), Wc)   # Wc: dropped
+    live = active.astype(jnp.int32)
+    pairs = jnp.stack([jnp.sum(live * jnp.minimum(n + 1, W)),
+                       jnp.sum(live * (n + 1))])
+    return logits[:, -1, :], WindowPagedCache(
+        kv=_land_columns(kv, col_k, col_v, blk, off, active),
+        ring_k=cache.ring_k.at[:, rows, at].set(ring_col_k, mode="drop"),
+        ring_v=cache.ring_v.at[:, rows, at].set(ring_col_v, mode="drop"),
+        counters=cache.counters.at[0].add(
+            jnp.concatenate([counts, pairs])))
+
+
 # cache is READ-ONLY here: prefill gathers the shared-prefix strip
 # out of the pool and writes a fresh single-request row cache; the
 # pool has no successor to alias, and donating it would free buffers
@@ -293,6 +410,8 @@ def paged_prefill(params, cfg, cache: PagedKVCache,  # kfrm: disable=KFRM008
     if isinstance(cache, HybridPagedCache):
         return _hybrid_prefill(params, cfg, cache, load_row, n_hit, tokens,
                                n_real)
+    if isinstance(cache, WindowPagedCache):
+        return _window_prefill(params, cfg, cache, tokens, n_real)
     strips, positions, kv_positions, write_kv = _prefix_strip(
         cache, load_row, n_hit, tokens.shape[1], n_real)
     logits, (new_k, new_v) = _run_blocks(
@@ -350,6 +469,54 @@ def _hybrid_prefill(params, cfg, cache: HybridPagedCache, load_row, n_hit,
     return last, new_k, new_v, kv_positions, (ssm, conv, counts)
 
 
+def _window_prefill(params, cfg, cache: WindowPagedCache, tokens, n_real):
+    """``paged_prefill`` for a config with window layers. The whole
+    prompt is prefilled (no prefix is adopted: a ring keeps no whole
+    block), attending over the bucket itself and no strip: causal on
+    the full layers, windowed on the window layers, through the flash
+    forward on a one-device TPU program (``dot_product_attention``).
+    The pad columns come after every real one, so no real query sees
+    them. The head reads the last real column alone. Returns the full
+    layers' strip as ``paged_prefill`` does and,
+    for ``paged_install``, each window layer's ring of the prompt's
+    last ``Wc`` positions (an offset the prompt never reached holds
+    its first column: masked by position) with the counts."""
+    Tc = tokens.shape[1]
+    L, NB, BS, KVH, hd = cache.kv.k.shape
+    S = cache.kv.block_tables.shape[1] * BS
+    Wc = cache.ring_k.shape[2]
+    W = cfg.sliding_window
+    col = jnp.arange(Tc, dtype=jnp.int32)[None, :]
+    real = col < n_real
+    positions = jnp.where(real, col, _UNFILLED)
+    kv_positions = jnp.full((1, S), _UNFILLED, jnp.int32).at[:, :Tc].set(
+        positions[:, :S])
+
+    def attend(q, k, v, _i):
+        strips = tuple(jnp.zeros((1, S, KVH, hd), x.dtype).at[:, :Tc].set(
+            x[:, :S]) for x in (k, v))
+        return dot_product_attention(q, k, v, causal=True), strips
+
+    # offset o of the ring holds the prompt's latest position p = o
+    # (mod Wc): n - 1 - ((n - 1 - o) mod Wc)
+    offs = jnp.arange(Wc, dtype=jnp.int32)
+    take = jnp.clip(n_real - 1 - jnp.remainder(n_real - 1 - offs, Wc),
+                    0, Tc - 1)
+
+    def attend_window(q, k, v, _i):
+        return (dot_product_attention(q, k, v, causal=True, window=W),
+                (k[:, take], v[:, take]))
+
+    logits, _state, cols, counts = _run_hybrid_blocks(
+        params, cfg, tokens, real, positions, None, None, attend,
+        attend_window, head_at=n_real - 1)
+    (new_k, new_v), rings = _by_kind(cfg, cols)
+    pairs = jnp.stack([_window_pairs(n_real, W), n_real * (n_real + 1) // 2])
+    last = logits[0, 0, :]
+    return last, new_k, new_v, kv_positions, (
+        *rings, jnp.concatenate([counts, pairs.astype(jnp.int32)]))
+
+
 @partial(jax.jit, donate_argnames=("cache",))
 def paged_install(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
                   final_row, dest_row, write_idx0, prefilled=None):
@@ -368,7 +535,17 @@ def paged_install(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
     For a ``HybridPagedCache``, ``prefilled`` is the prefill's (recurrent
     state, convolution tail, expert counts): the first two replace
     whatever the slot held (the wipe a reseated slot relies on), the
-    counts join the cache's counters."""
+    counts join the cache's counters. For a ``WindowPagedCache`` it is
+    the prefill's (rings, counts): each window layer's ring replaces
+    the slot's."""
+    if isinstance(cache, WindowPagedCache):
+        ring_k, ring_v, counts = prefilled
+        return WindowPagedCache(
+            kv=_install_strip(cache.kv, temp_k, temp_v, temp_pos, slot,
+                              final_row, dest_row, write_idx0),
+            ring_k=cache.ring_k.at[:, slot].set(ring_k[:, 0]),
+            ring_v=cache.ring_v.at[:, slot].set(ring_v[:, 0]),
+            counters=cache.counters.at[1].add(counts))
     if isinstance(cache, HybridPagedCache):
         ssm, conv, counts = prefilled
         return HybridPagedCache(

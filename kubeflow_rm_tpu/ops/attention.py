@@ -87,20 +87,29 @@ def attention_mask(
     positions_kv: jax.Array | None = None,
     segment_ids_q: jax.Array | None = None,
     segment_ids_kv: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array | None:
     """Boolean keep-mask, (Tq, Tk) or (B, Tq, Tk), or None if unmasked.
 
     Causality uses global positions when given (sequence-parallel shards,
     packed sequences); segment ids — when given — additionally restrict
     attention to ``seg_q == seg_kv`` so packed documents stay independent
-    and padding (its own segment) is never attended.
+    and padding (its own segment) is never attended. ``window`` (causal
+    only) keeps the ``window`` newest keys a query sees, itself among
+    them: query ``p`` reads keys ``p - window + 1 .. p``.
     """
     mask = None
     if causal:
         if positions_q is None:
             mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]  # (Tq, Tk)
+            if window is not None:
+                mask &= (jnp.arange(Tq)[:, None] - jnp.arange(Tk)[None, :]
+                         < window)
         else:
             mask = positions_q[:, :, None] >= positions_kv[:, None, :]  # (B, Tq, Tk)
+            if window is not None:
+                mask &= (positions_q[:, :, None] - positions_kv[:, None, :]
+                         < window)
     if segment_ids_q is not None:
         seg = segment_ids_q[:, :, None] == segment_ids_kv[:, None, :]  # (B, Tq, Tk)
         mask = seg if mask is None else mask & seg
@@ -137,6 +146,7 @@ def dot_product_attention(
     segment_ids_q: jax.Array | None = None,
     segment_ids_kv: jax.Array | None = None,
     bias: jax.Array | None = None,
+    window: int | None = None,
     impl: str = "auto",
     mesh=None,
 ) -> jax.Array:
@@ -152,6 +162,9 @@ def dot_product_attention(
       segment_ids_q / segment_ids_kv: optional (B, T) int segment ids for
         packed sequences; attention is restricted to equal segments.
       bias: optional additive bias broadcastable to (B, H, Tq, Tk).
+      window: causal only, the keys a query reads: its own and the
+        ``window - 1`` before it (a sliding-window layer). The flash
+        kernel takes it without segment ids, forward only.
 
       impl: "auto" (the pallas flash kernel when the computation is a
         one-device TPU program and the call is exactly representable,
@@ -195,7 +208,7 @@ def dot_product_attention(
         return flash_attention(
             q, k, v, causal=causal,
             segment_ids_q=segment_ids_q, segment_ids_kv=segment_ids_kv,
-            interpret=interpret)
+            window=window, interpret=interpret)
 
     B, Tq, H, D = q.shape
     _, Tk, KVH, _ = k.shape
@@ -216,6 +229,7 @@ def dot_product_attention(
         Tq, Tk, causal=causal,
         positions_q=positions_q, positions_kv=positions_kv,
         segment_ids_q=segment_ids_q, segment_ids_kv=segment_ids_kv,
+        window=window,
     )
     if mask is not None:
         if mask.ndim == 2:

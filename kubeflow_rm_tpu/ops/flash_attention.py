@@ -136,6 +136,18 @@ def _causal_tiles(nq: int, nk: int, block_q: int, block_k: int):
     return j * block_k <= i * block_q + (block_q - 1)
 
 
+def _window_tiles(nq: int, nk: int, block_q: int, block_k: int,
+                  window: int):
+    """(nq, nk) bool, the causal test with a window: a kv block whose
+    last key lies ``window`` or more before every query row of the q
+    block holds no work either (query ``p`` reads keys ``p - window + 1
+    .. p``)."""
+    i = jnp.arange(nq)[:, None]
+    j = jnp.arange(nk)[None, :]
+    return (_causal_tiles(nq, nk, block_q, block_k)
+            & ((j + 1) * block_k - 1 > i * block_q - window))
+
+
 def live_tiles(segq, segkv, block_q: int, block_k: int,
                causal: bool = True) -> jax.Array:
     """(B, nq, nk) bool: may any query of q block i attend any key of
@@ -227,7 +239,7 @@ def _fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, segq_ref, segkv_ref,
                 o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, scale: float, causal: bool, block_q: int, block_k: int,
-                heads: int):
+                heads: int, window: int | None = None):
     i = pl.program_id(1)   # q block
     j = pl.program_id(2)   # kv block
     nk = pl.num_programs(2)
@@ -254,6 +266,8 @@ def _fwd_kernel(fetch_ref, q_ref, k_ref, v_ref, segq_ref, segkv_ref,
             cols = j * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             mask = rows >= cols
+            if window is not None:
+                mask = mask & (rows - cols < window)
         if segq_ref is not None:
             # segment blocks are (1, 8, b*): sublane-padded, row 0 live
             seg = segq_ref[0, 0][:, None] == segkv_ref[0, 0][None, :]
@@ -299,15 +313,22 @@ def _flash(q, k, v, segq, segkv, causal, block_q, block_k, group,
     return out
 
 
-def _bind(kernel, name: str, n_inputs: int, segmented: bool, **static):
+def _bind(kernel, name: str, n_inputs: int, segmented: bool,
+          fetched: bool | None = None, **static):
     """``kernel(fetch, *inputs, segq, segkv, *outputs_and_scratch)`` as
     the function pallas calls: with segment ids it is handed exactly
-    those refs, without them neither the table nor the two id blocks.
+    those refs, without them neither the table nor the two id blocks,
+    and with a table but no ids (``fetched``: a window's forward) the
+    table alone.
     ``name`` is the lowered custom call's ``kernel_name``; the callers
     pass the names these calls have always lowered under, so a program
     without segment ids keeps its text (and its compile-cache key)."""
+    fetched = segmented if fetched is None else fetched
+
     def bound(*refs):
-        if not segmented:
+        if fetched and not segmented:
+            refs = (*refs[:1 + n_inputs], None, None, *refs[1 + n_inputs:])
+        elif not segmented:
             refs = (None, *refs[:n_inputs], None, None, *refs[n_inputs:])
         return kernel(*refs, **static)
     bound.__name__ = name
@@ -348,9 +369,11 @@ def _segment_operands(segq, segkv, block_q, block_k, causal):
 
 
 def _flash_call(q, k, v, segq, segkv, causal, block_q, block_k, group,
-                interpret):
+                interpret, window=None):
     """q: (B, KVH*G, T, D); k/v: (B, KVH, T, D);
-    segq/segkv: (B, T) int32 or None. Returns (out, lse)."""
+    segq/segkv: (B, T) int32 or None; ``window`` (causal, no segment
+    ids) the keys a query reads, its tiles' table made from it.
+    Returns (out, lse)."""
     B, Hq, T, D = q.shape
     KVH = k.shape[1]
     scale = D ** -0.5
@@ -387,10 +410,16 @@ def _flash_call(q, k, v, segq, segkv, causal, block_q, block_k, group,
         in_specs += [pl.BlockSpec((1, 8, block_q), segq_map),
                      pl.BlockSpec((1, 8, block_k), segkv_map)]
         args += [segq8, segkv8]
+    window_static = {}
+    if window is not None:
+        live = _window_tiles(nq, nk, block_q, block_k, window)
+        fetch = _nearest_live(jnp.broadcast_to(live, (B, nq, nk)), axis=2)
+        window_static = {"fetched": True, "window": window}
 
     out, lse = _tiled_call(
         _bind(_fwd_kernel, "kernel", 3, segq is not None, scale=scale,
-              causal=causal, block_q=block_q, block_k=block_k, heads=Hq),
+              causal=causal, block_q=block_q, block_k=block_k, heads=Hq,
+              **window_static),
         fetch, args,
         grid=(B * Hq, nq, nk),
         in_specs=in_specs,
@@ -739,13 +768,19 @@ def flash_attention(
     segment_ids_kv: jax.Array | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
+    window: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
     """Flash attention with the ``dot_product_attention`` layout:
     q (B, T, H, D); k, v (B, T, KVH, D) → (B, T, H, D).
 
     Causality is over local indices; combined with segment ids this is
-    exact for packed documents (module docstring). ``block_q`` /
+    exact for packed documents (module docstring). ``window`` (causal,
+    no segment ids) keeps for query ``p`` the keys ``p - window + 1 ..
+    p``: a tile wholly before every query's window is one more dead
+    tile of the table the kernel is handed (``_window_tiles``). The
+    windowed call is the forward alone (serving's prefill): it has no
+    gradient rule. ``block_q`` /
     ``block_k`` default as ``tile_for`` says, by whether the call
     carries segment ids. ``interpret=None``
     selects the pallas interpreter when the operands' computation is
@@ -777,6 +812,12 @@ def flash_attention(
         jnp.int32)
     segkv = None if segment_ids_kv is None else segment_ids_kv.astype(
         jnp.int32)
+    if window is not None:
+        if not causal or segq is not None:
+            raise ValueError("a window is causal and takes no segment ids")
+        out, _lse = _flash_call(qh, kh, vh, None, None, True, block_q,
+                                block_k, group, interpret, window=window)
+        return jnp.swapaxes(out, 1, 2)
     out = _flash(qh, kh, vh, segq, segkv, causal, block_q, block_k,
                  group, interpret)
     return jnp.swapaxes(out, 1, 2)

@@ -40,11 +40,25 @@ slot's write index; whatever a recycled block, a NULL table entry or
 a shared block's tail holds past it is never copied or is masked by
 its column index. This token's own K/V enters as the online softmax's
 starting state, so the pool is only read.
+
+**A window layer** (``window_decode_attention``) keeps no block table:
+a slot's state is a ring of its last ``Wc`` positions (``Wc`` the
+window rounded up to whole blocks), position ``p`` at offset ``p %
+Wc``, overwritten in place as the context grows. The same kernel
+reads it (the ring laid out as blocks a slot owns in order, a block
+the kernel's chunk) and never more than its ``Wc`` positions, however
+long the context; a key is seen iff it is filled and among the
+``window`` newest, the token itself one of them. Its mask is the age
+of a column, ``(n - 1 - offset) mod Wc`` for a slot holding ``n``
+tokens, against ``min(window - 1, n)``: two scalars a slot the kernel
+is handed beside the lengths. The XLA path gathers nothing: the ring
+is the strip, its positions worked out from ``n``.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +69,9 @@ from jax.experimental.pallas import tpu as pltpu
 from kubeflow_rm_tpu.ops.attention import (
     NEG_INF, _log_choice, computation_devices, dot_product_attention,
 )
+
+#: a position no query reaches (``models.decode._UNFILLED``)
+_UNFILLED = np.iinfo(np.int32).max
 
 #: tokens of one slot the kernel copies and multiplies at a time (a
 #: chunk is ``CHUNK_TOKENS // block_size`` blocks, two chunks in
@@ -138,11 +155,14 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer,
 
 
 def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
-            q_ref, kn_ref, vn_ref, head_bias_ref, col_tok_ref,
-            k_hbm, v_hbm,
-            o_ref,
-            item_slot, item_chunk, kbuf, vbuf, ksem, vsem,
-            *, block_size: int, chunk_blocks: int):
+            *refs, block_size: int, chunk_blocks: int,
+            ring: int | None = None):
+    if ring is None:
+        ring_ref = None
+    else:                   # a window's ring: one more scalar prefetch
+        ring_ref, *refs = refs
+    (q_ref, kn_ref, vn_ref, head_bias_ref, col_tok_ref, k_hbm, v_hbm,
+     o_ref, item_slot, item_chunk, kbuf, vbuf, ksem, vsem) = refs
     B, H, _ = q_ref.shape
     BS, CB = block_size, chunk_blocks
     layer = layer_ref[0]
@@ -232,8 +252,17 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (H, CB*R)
         s = s + head_bias
-        s = jnp.where(col_tok < lengths_ref[b] - c * (CB * BS), s,
-                      NEG_INF)
+        if ring_ref is None:
+            s = jnp.where(col_tok < lengths_ref[b] - c * (CB * BS), s,
+                          NEG_INF)
+        else:
+            # a column's age: how many tokens before the slot's newest
+            # cached one it was written, read round the ring
+            newest, seen = ring_ref[0, b], ring_ref[1, b]
+            off = c * (CB * BS) + col_tok
+            age = jnp.where(off <= newest, newest - off,
+                            newest - off + ring)
+            s = jnp.where(age < seen, s, NEG_INF)
         m = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m)
         p = jnp.exp(s - m)
@@ -251,7 +280,11 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
 
 
 def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
-                         block_tables, lengths, *, interpret: bool):
+                         block_tables, lengths, *, interpret: bool,
+                         ring=None):
+    """The kernel's call. ``ring`` = (``Wc``, (2, B) int32: each slot's
+    newest cached offset and the ages it sees) for a window's ring,
+    whose ``lengths`` are then its filled offsets."""
     B, H, hd = q.shape
     L, NB, BS, KVH, _ = pool_k.shape
     G = H // KVH
@@ -284,10 +317,16 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
     wide = hd > 128
     block = (BS, KVH, hd) if wide else (R, hd)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    static, scalars, name = {}, (), "paged_decode_attention"
+    if ring is not None:
+        static = {"ring": ring[0]}
+        scalars = (ring[1].astype(jnp.int32),)
+        name = "window_decode_attention"
     return pl.pallas_call(
-        functools.partial(_kernel, block_size=BS, chunk_blocks=CB),
+        functools.partial(_kernel, block_size=BS, chunk_blocks=CB,
+                          **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=3 + len(scalars),
             grid=(1,),
             in_specs=[full(B, H, hd), full(B, H, hd), full(B, H, hd),
                       full(H, CB * R), full(1, CB * R), hbm, hbm],
@@ -304,9 +343,80 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_decode_attention",
+        name=name,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
-      lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
+      lengths.astype(jnp.int32), block_tables.astype(jnp.int32), *scalars,
       q, kn, vn, head_bias, col_tok,
       *((pool_k, pool_v) if wide else
         (pool_k.reshape(L, NB, R, hd), pool_v.reshape(L, NB, R, hd))))
+
+
+def ring_block(ring_len: int) -> int:
+    """Positions of a window's ring the kernel copies at a time: one
+    chunk, ``CHUNK_TOKENS``, where that divides the ring."""
+    return math.gcd(ring_len, CHUNK_TOKENS)
+
+
+def window_decode_attention(q, k_new, v_new, ring_k, ring_v, layer,
+                            lengths, active, *, window: int,
+                            impl: str = "auto"):
+    """Attention of one new token a slot over the ``window`` newest
+    positions of its window layer's ring.
+
+    Args:
+      q: (B, H, hd) the token's queries; k_new, v_new: (B, KVH, hd) its
+        key and value, not yet in the ring.
+      ring_k, ring_v: (Lw, B, Wc, KVH, hd) every window layer's ring,
+        position ``p`` of slot ``b`` at ``[:, b, p % Wc]``; ``Wc >=
+        window``.
+      layer: () int32, the window layer to read.
+      lengths: (B,) int32 tokens the slot holds before this one (its
+        position); active: (B,) bool.
+      window: the keys a query reads, itself among them.
+      impl: as ``paged_decode_attention``'s.
+
+    Returns (B, H, hd) in q.dtype; an inactive row's is unspecified.
+    """
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"impl must be auto|pallas|xla, got {impl!r}")
+    Lw, B, Wc, KVH, hd = ring_k.shape
+    if Wc < window:
+        raise ValueError(f"a ring of {Wc} cannot hold a window of {window}")
+    n = jnp.where(active, lengths, 0).astype(jnp.int32)
+    BS = ring_block(Wc)
+    nb = Wc // BS
+    pool_k = ring_k.reshape(Lw, B * nb, BS, KVH, hd)
+    platform, n_devices = computation_devices(q)
+    use_kernel = impl == "pallas"
+    if impl == "auto":
+        use_kernel = (platform == "tpu" and n_devices == 1
+                      and kernel_eligible(q, pool_k))
+        if not use_kernel:
+            _log_choice("window_decode_xla", False, platform, n_devices,
+                        "not a one-device tpu program whose shapes tile")
+    if use_kernel:
+        interpret = platform != "tpu"
+        _log_choice("window_decode", interpret, platform, n_devices,
+                    f"impl={impl}")
+        tables = (jnp.arange(B, dtype=jnp.int32)[:, None] * nb
+                  + jnp.arange(nb, dtype=jnp.int32)[None, :])
+        ages = jnp.stack([jnp.remainder(n - 1, Wc),
+                          jnp.minimum(window - 1, n)])
+        return _paged_decode_kernel(
+            q, k_new, v_new, pool_k, ring_v.reshape(pool_k.shape), layer,
+            tables, jnp.minimum(n, Wc), interpret=interpret,
+            ring=(Wc, ages))
+
+    # the ring with this token written in: offset o then holds the
+    # position ``n - age``, age = (n - o) mod Wc
+    rows = jnp.arange(B, dtype=jnp.int32)
+    at = jnp.remainder(n, Wc)
+    age = jnp.remainder(n[:, None] - jnp.arange(Wc, dtype=jnp.int32), Wc)
+    kv_positions = jnp.where((age < window) & (age <= n[:, None]),
+                             n[:, None] - age, _UNFILLED)
+    out = dot_product_attention(
+        q[:, None], ring_k[layer].at[rows, at].set(k_new),
+        ring_v[layer].at[rows, at].set(v_new), causal=True,
+        positions_q=jnp.where(active, n, _UNFILLED)[:, None],
+        positions_kv=kv_positions)
+    return out[:, 0]

@@ -36,6 +36,7 @@ from kubeflow_rm_tpu.models.llama import LlamaConfig
 from kubeflow_rm_tpu.models.quantize import (
     has_int4_leaf, unpack_int4_params,
 )
+from kubeflow_rm_tpu.ops.paged_attention import chunk_blocks, whole_chunks
 from kubeflow_rm_tpu.utils.profiling import annotate as _span
 
 
@@ -629,6 +630,10 @@ class ContinuousBatchingEngine:
                 else None)
         self._slot_req: list[EngineRequest | None] = [None] * slots
         self._slot_blocks: list[list | None] = [None] * slots
+        # the decode kernel's chunk of a strip, and a seated slot's
+        # whole chunks among its first k (whole_chunks)
+        self._chunk_blocks = chunk_blocks(block_size, maxb)
+        self._slot_whole: list[list | None] = [None] * slots
         self._queues = {c: [] for c in SLO_CLASSES}
         self.class_weights = dict(DEFAULT_CLASS_WEIGHTS)
         if class_weights:
@@ -639,6 +644,8 @@ class ContinuousBatchingEngine:
         self.host_syncs_total = 0      # blocking transfers in _pick
         self.pick_programs_total = 0   # programs _pick dispatched
         self.kv_blocks_read_total = 0
+        self.kv_chunks_total = 0       # chunks the decode kernel walks
+        self.kv_chunks_whole_total = 0   # ... copied in one DMA each
         self.prefills = 0
         self.occupancy_sum = 0
         self.admitted_total = 0
@@ -851,6 +858,9 @@ class ContinuousBatchingEngine:
                 self.prefills += 1
             self._last = _seat_row(self._last, last, i)
             self._slot_req[i] = req
+            # the strip is fixed from here to retirement
+            self._slot_whole[i] = whole_chunks(
+                self._slot_blocks[i], self._chunk_blocks).tolist()
             req.admitted_step = self.decode_steps
             req.t_admitted = t_taken
             self.admitted_total += 1
@@ -1129,13 +1139,22 @@ class ContinuousBatchingEngine:
                     self.decode_steps += 1
                     self.occupancy_sum += sum(active)
                     # what the step touched of each live slot's table:
-                    # the blocks up to the one its token landed in. The
-                    # host knows every length (prompt + tokens picked so
-                    # far, the one fed among them) — no device sync, and
-                    # counted here, while the device runs the step
-                    self.kv_blocks_read_total += sum(
-                        -(-(len(r.prompt) + len(r.tokens)) // self.block_size)
-                        for r in self._slot_req if r is not None)
+                    # the blocks up to the one its token landed in, and
+                    # the kernel's chunks over those the tokens before
+                    # it fill. The host knows every length (prompt +
+                    # tokens picked so far, the one fed among them) —
+                    # no device sync, and counted here, while the
+                    # device runs the step
+                    BS, CB = self.block_size, self._chunk_blocks
+                    for i, r in enumerate(self._slot_req):
+                        if r is None:
+                            continue
+                        n = len(r.prompt) + len(r.tokens)
+                        self.kv_blocks_read_total += -(-n // BS)
+                        nb = -(-(n - 1) // BS)
+                        self.kv_chunks_total += -(-nb // CB)
+                        self.kv_chunks_whole_total += (
+                            self._slot_whole[i][nb // CB])
         return finished
 
     def _pick(self):
@@ -1288,4 +1307,8 @@ class ContinuousBatchingEngine:
             # against decode_steps x slots x (slot_len / block_size),
             # the share of a whole-cache strip the steps still touch
             "kv_blocks_read_total": self.kv_blocks_read_total,
+            # the chunks the decode kernel walks over those blocks, and
+            # those it copies in one DMA for K and one for V
+            "kv_chunks_total": self.kv_chunks_total,
+            "kv_chunks_whole_total": self.kv_chunks_whole_total,
         }

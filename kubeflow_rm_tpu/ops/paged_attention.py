@@ -10,9 +10,12 @@ function, chosen the way ``ops/attention.py`` chooses flash (by what
   HBM; block tables, lengths and the layer index are scalar-prefetched;
   the kernel walks the live slots' blocks in chunks and copies only
   ``block_tables[slot, : ceil(len / BS)]`` of layer ``layer`` into a
-  double-buffered VMEM scratch, one DMA a block (a block of one layer
-  is contiguous: ``(BS, KVH, hd)``, 32 KB at Mistral's widths). An
-  inactive slot, or one with nothing cached yet, costs no DMA. Online
+  double-buffered VMEM scratch (a block of one layer is contiguous:
+  ``(BS, KVH, hd)``, 32 KB at Mistral's widths). A chunk whose blocks
+  are all live and whose ids run consecutively is one region of the
+  pool and goes in one DMA for K and one for V; any other chunk goes a
+  DMA a live block (``whole_chunks``). An inactive slot, or one with
+  nothing cached yet, costs no DMA. Online
   softmax in float32 over bf16 scores accumulated in float32,
   probabilities cast to the value dtype before the second matmul: the
   precision of ``dot_product_attention``'s XLA path, nothing lower.
@@ -80,6 +83,25 @@ _UNFILLED = np.iinfo(np.int32).max
 #: / 22.5 / 28.6, sixteen full 2048-token strips 336 / 231 / 186 / 186
 #: (PERF.md section 6, PR 31).
 CHUNK_TOKENS = 256
+
+
+def chunk_blocks(block_size: int, max_blocks: int) -> int:
+    """Blocks of one slot's strip the kernel copies and multiplies at a
+    time: ``CHUNK_TOKENS`` worth, no more than a strip holds."""
+    return max(1, min(CHUNK_TOKENS // block_size, max_blocks))
+
+
+def whole_chunks(blocks, cb: int) -> np.ndarray:
+    """For a strip's blocks in table order, the number of whole chunks
+    among its first ``k`` chunks, ``k = 0 .. len(blocks) // cb``: a
+    chunk of ``cb`` blocks is whole where its ids run consecutively,
+    so that the kernel copies it as one region of the pool. A slot
+    whose cached tokens fill ``n`` blocks walks ``ceil(n / cb)`` chunks,
+    ``whole_chunks(blocks, cb)[n // cb]`` of them whole."""
+    ids = np.asarray(blocks, np.int64)
+    runs = ids[:len(ids) // cb * cb].reshape(-1, cb)
+    whole = (np.diff(runs, axis=1) == 1).all(axis=1)
+    return np.concatenate([[0], np.cumsum(whole)])
 
 
 def kernel_eligible(q, pool_k) -> bool:
@@ -162,9 +184,13 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
     else:                   # a window's ring: one more scalar prefetch
         ring_ref, *refs = refs
     (q_ref, kn_ref, vn_ref, head_bias_ref, col_tok_ref, k_hbm, v_hbm,
-     o_ref, item_slot, item_chunk, kbuf, vbuf, ksem, vsem) = refs
+     o_ref, item_slot, item_chunk, kbuf, vbuf, ksem, vsem,
+     *more) = refs
     B, H, _ = q_ref.shape
     BS, CB = block_size, chunk_blocks
+    # where CB > 1: a whole chunk's first block an item, or -1
+    item_first = more[0] if more else None
+    MAXB = tables_ref.shape[1]
     layer = layer_ref[0]
 
     def n_blocks(b):
@@ -182,35 +208,57 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
 
     n_items = jax.lax.fori_loop(0, B, list_slot, 0)
 
-    def copies(i, buf):
-        """Item ``i``'s block copies into buffer ``buf``, each under
-        the condition it is issued and waited for."""
+    def chunk_ids(i):
+        """Item ``i``'s chunk, its slot's live blocks and the chunk's
+        ``CB`` table entries (clamped to the table)."""
         b, c = item_slot[i], item_chunk[i]
-        nb = n_blocks(b)
-        out = []
-        for j in range(CB):
-            blk = tables_ref[b, jnp.minimum(c * CB + j,
-                                            tables_ref.shape[1] - 1)]
-            out.append((c * CB + j < nb, (
-                pltpu.make_async_copy(k_hbm.at[layer, blk],
-                                      kbuf.at[buf, j], ksem.at[buf]),
-                pltpu.make_async_copy(v_hbm.at[layer, blk],
-                                      vbuf.at[buf, j], vsem.at[buf]))))
-        return out
+        return c, n_blocks(b), [
+            tables_ref[b, jnp.minimum(c * CB + j, MAXB - 1)]
+            for j in range(CB)]
+
+    def per_block(c, nb, ids, buf, act):
+        """A copy a live block into buffer ``buf``, each under the
+        condition it is started and waited for."""
+        for j, blk in enumerate(ids):
+            @pl.when(c * CB + j < nb)
+            def _():
+                act(pltpu.make_async_copy(k_hbm.at[layer, blk],
+                                          kbuf.at[buf, j], ksem.at[buf]))
+                act(pltpu.make_async_copy(v_hbm.at[layer, blk],
+                                          vbuf.at[buf, j], vsem.at[buf]))
+
+    def whole_chunk(first, buf, act):
+        """``CB`` blocks from ``first`` on in one copy for K and one
+        for V: the bytes the block copies would put at the same
+        offsets."""
+        act(pltpu.make_async_copy(k_hbm.at[layer, pl.ds(first, CB)],
+                                  kbuf.at[buf], ksem.at[buf]))
+        act(pltpu.make_async_copy(v_hbm.at[layer, pl.ds(first, CB)],
+                                  vbuf.at[buf], vsem.at[buf]))
 
     def start(i, buf):
-        for live, pair in copies(i, buf):
-            @pl.when(live)
-            def _():
-                for cp in pair:
-                    cp.start()
+        c, nb, ids = chunk_ids(i)
+        if CB == 1:
+            return per_block(c, nb, ids, buf, lambda cp: cp.start())
+        # whole: every block live and the ids consecutive, decided once
+        # an item here and kept for its wait
+        whole = (c + 1) * CB <= nb
+        for j in range(1, CB):
+            whole &= ids[j] == ids[0] + j
+        item_first[i] = jnp.where(whole, ids[0], -1)
+        pl.when(whole)(lambda: whole_chunk(ids[0], buf,
+                                           lambda cp: cp.start()))
+        pl.when(jnp.logical_not(whole))(lambda: per_block(
+            c, nb, ids, buf, lambda cp: cp.start()))
 
     def wait(i, buf):
-        for live, pair in copies(i, buf):
-            @pl.when(live)
-            def _():
-                for cp in pair:
-                    cp.wait()
+        if CB == 1:
+            return per_block(*chunk_ids(i), buf, lambda cp: cp.wait())
+        first = item_first[i]
+        pl.when(first >= 0)(lambda: whole_chunk(first, buf,
+                                                lambda cp: cp.wait()))
+        pl.when(first < 0)(lambda: per_block(*chunk_ids(i), buf,
+                                             lambda cp: cp.wait()))
 
     # a chunk's unfilled tail is never copied: its columns are masked,
     # and 0 x (whatever VMEM held) must still be 0 in the second matmul
@@ -289,8 +337,9 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
     L, NB, BS, KVH, _ = pool_k.shape
     G = H // KVH
     MAXB = block_tables.shape[1]
-    CB = max(1, min(CHUNK_TOKENS // BS, MAXB))
+    CB = chunk_blocks(BS, MAXB)
     R = BS * KVH                                       # rows a block
+    items = B * pl.cdiv(MAXB, CB)                      # the most a call
 
     # the rounding dot_product_attention gives the scaled query
     q = q * hd ** -0.5
@@ -332,12 +381,13 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
                       full(H, CB * R), full(1, CB * R), hbm, hbm],
             out_specs=full(B, H, hd),
             scratch_shapes=[
-                pltpu.SMEM((B * pl.cdiv(MAXB, CB),), jnp.int32),
-                pltpu.SMEM((B * pl.cdiv(MAXB, CB),), jnp.int32),
+                pltpu.SMEM((items,), jnp.int32),
+                pltpu.SMEM((items,), jnp.int32),
                 pltpu.VMEM((2, CB, *block), pool_k.dtype),
                 pltpu.VMEM((2, CB, *block), pool_v.dtype),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA((2,)),
+                *([pltpu.SMEM((items,), jnp.int32)] if CB > 1 else []),
             ]),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -206,6 +206,128 @@ def test_wide_head_kernel_matches_the_block_table_path(scene):
     np.testing.assert_allclose(kernel, xla, atol=1e-5, rtol=0)
 
 
+# -- a whole chunk in one copy -------------------------------------------
+
+#: chunks cut small: four blocks of four tokens (the test sets
+#: ``CHUNK_TOKENS``), three chunks a strip, every slot's blocks its own
+CBS, CCB, CMAXB, CNB = 4, 4, 12, 128
+
+
+def _run(first, n):
+    return list(range(first, first + n))
+
+
+#: a layout: (blocks, tokens cached, active) a slot, and the whole
+#: chunks the live slots walk — the rest go a block at a time
+CHUNK_LAYOUTS = {
+    "every_chunk_consecutive": ([
+        (_run(2, 12), 47, True), (_run(14, 9), 32, True),
+        (_run(23, 12), 44, True), (_run(35, 4), 15, True)], 3 + 2 + 2 + 1),
+    "break_inside_a_chunk": ([
+        (_run(2, 5) + [50] + _run(8, 6), 47, True),
+        (_run(20, 2) + [60] + _run(23, 9), 40, True),
+        (_run(32, 6), 21, True), (_run(38, 4), 13, True)], 2 + 1 + 1 + 1),
+    "run_across_a_chunk_boundary": ([
+        ([44, 45] + _run(2, 8) + [47, 48], 47, True),
+        (_run(20, 6) + _run(30, 6), 47, True),
+        (_run(10, 2) + _run(52, 6), 30, True),
+        (_run(60, 4), 15, True)], 1 + 2 + 1 + 1),
+    "partial_last_chunk": ([
+        (_run(2, 12), 37, True), (_run(14, 12), 20, True),
+        (_run(26, 12), 33, True), (_run(38, 6), 22, True)], 2 + 1 + 2 + 1),
+    "null_entries_past_the_strip": ([
+        (_run(2, 7) + [0] * 5, 25, True), (_run(10, 4) + [0] * 8, 15, True),
+        (_run(14, 9) + [0] * 3, 32, True), (_run(24, 1) + [0] * 11, 3, True),
+    ], 1 + 1 + 2 + 0),
+    "inactive_and_empty_slots": ([
+        (_run(2, 12), 47, False), (_run(14, 2), 0, True),
+        (_run(16, 12), 47, True), (_run(28, 1), 0, False)], 3),
+    "shorter_than_a_chunk": ([
+        (_run(2, 2), 5, True), (_run(4, 1), 1, True),
+        (_run(5, 3), 11, True), (_run(8, 4), 12, True)], 0),
+}
+
+#: (H, KVH, hd): Mistral's GQA, Trinity's, Qwen3-Next's wide head
+CHUNK_SHAPES = {"mistral-8kv": (32, 8, 128), "trinity-4kv": (32, 4, 128),
+                "wide-256": (16, 2, 256)}
+
+_attend_chunks = jax.jit(pa.paged_decode_attention, static_argnames=("impl",))
+
+
+def _scattered(x):
+    """A block's id in a pool where no two ids a table names are
+    consecutive (even ids, in reverse): every chunk a block at a time."""
+    return 0 if x == 0 else 2 * (CNB // 2 - x) + 2
+
+
+@pytest.mark.parametrize("shape", sorted(CHUNK_SHAPES))
+@pytest.mark.parametrize("layout", sorted(CHUNK_LAYOUTS))
+def test_whole_chunk_copies_read_what_the_block_copies_read(
+        monkeypatch, layout, shape):
+    """A chunk whose blocks are live and consecutive in the pool goes
+    in one copy for K and one for V: the same bytes at the same offsets
+    as a copy a block, so the output is bitwise the output over the
+    same strips laid out with no two ids consecutive, and the XLA
+    path's within float32 rounding. No block past a slot's cached
+    tokens is copied, whole chunk or not: poisoned with NaN, those
+    blocks leave the output as it was. ``whole_chunks`` counts the
+    chunks the kernel takes whole."""
+    monkeypatch.setattr(pa, "CHUNK_TOKENS", CBS * CCB)
+    assert pa.chunk_blocks(CBS, CMAXB) == CCB
+    H, kvh, hd = CHUNK_SHAPES[shape]
+    slots, want_whole = CHUNK_LAYOUTS[layout]
+    rng = np.random.default_rng(sorted(CHUNK_LAYOUTS).index(layout))
+    B = len(slots)
+    pool = rng.normal(size=(2, L, CNB, CBS, kvh, hd)).astype(np.float32)
+    pool[:, :, 0] = 0                                   # NULL
+    tables = np.zeros((B, CMAXB), np.int32)
+    for b, (blocks, n, _) in enumerate(slots):
+        assert -(-(n + 1) // CBS) <= len(blocks)        # its token's block
+        tables[b, :len(blocks)] = blocks
+    lengths = np.array([n for _, n, _ in slots], np.int32)
+    active = np.array([a for _, _, a in slots])
+
+    # the same strips where no table names two consecutive ids
+    spread = np.vectorize(_scattered)(tables).astype(np.int32)
+    pool_s = rng.normal(size=pool.shape).astype(np.float32)
+    used = np.unique(tables)
+    pool_s[:, :, np.vectorize(_scattered)(used)] = pool[:, :, used]
+
+    def walked_whole(t):
+        return sum(pa.whole_chunks(t[b], CCB)[-(-n // CBS) // CCB]
+                   for b, (_, n, a) in enumerate(slots) if a)
+
+    assert walked_whole(tables) == want_whole
+    assert walked_whole(spread) == 0
+
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), jnp.float32)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(B, kvh, hd)), jnp.float32)
+                    for _ in range(2))
+    positions_q = np.where(active, lengths, U)
+    col = np.arange(CMAXB * CBS)[None, :]
+    kv_positions = np.where(col <= lengths[:, None], col, U)
+
+    def attend(p, t, impl):
+        return np.asarray(_attend_chunks(
+            q, k_new, v_new, jnp.asarray(p[0]), jnp.asarray(p[1]),
+            jnp.int32(LAYER), jnp.asarray(t), jnp.asarray(lengths),
+            jnp.asarray(active), positions_q=jnp.asarray(positions_q),
+            kv_positions=jnp.asarray(kv_positions), impl=impl))
+
+    whole = attend(pool, tables, "pallas")
+    np.testing.assert_allclose(whole[active],
+                               attend(pool, tables, "xla")[active],
+                               atol=1e-5, rtol=0)
+    # every block a table names past its slot's cached tokens, NULL too
+    live = {int(x) for b, (_, n, a) in enumerate(slots) if a
+            for x in tables[b, :-(-n // CBS)]}
+    dead = np.array(sorted(set(used.tolist()) - live))
+    for p, ids in ((pool, dead), (pool_s, np.vectorize(_scattered)(dead))):
+        p[:, :, ids] = np.nan
+    np.testing.assert_array_equal(whole, attend(pool, tables, "pallas"))
+    np.testing.assert_array_equal(whole, attend(pool_s, spread, "pallas"))
+
+
 def test_choice_is_logged_and_auto_takes_xla_off_the_chip(caplog):
     """``auto`` on the CPU is the XLA path, and says so through
     ``ops.attention``'s logger — what ``chip_smoke.py`` collects."""
@@ -258,14 +380,18 @@ def _compiled_outside_the_cache(lowered):
 
 
 
-@pytest.mark.parametrize("H,kvh", [(32, 8), (16, 16), (32, 2)],
-                         ids=["mistral-gqa", "bench1b-mha",
-                              "nemotron-gqa16"])
-def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh):
+@pytest.mark.parametrize(
+    "H,kvh,B,layers,nb,maxb",
+    [(32, 8, 16, 2, 3074, 128), (16, 16, 16, 2, 3074, 128),
+     (32, 2, 16, 2, 3074, 128), (32, 4, 32, 4, 20482, 640)],
+    ids=["mistral-gqa", "bench1b-mha", "nemotron-gqa16", "trinity-full"])
+def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh, B,
+                                                     layers, nb, maxb):
     """Mosaic takes the kernel at the benchmark's and the smoke's
-    widths, and the pool goes in as it lies: no copy of it, no
-    temporary beside it."""
-    B, hd, layers, nb, bs, maxb = 16, 128, 2, 3074, 16, 128
+    widths, whole-chunk copies and block copies both, and the pool
+    goes in as it lies: no copy of it, no temporary beside it (at
+    Trinity's four full layers a pool of 671 MB a side)."""
+    hd, bs = 128, 16
     bf16 = jnp.bfloat16
 
     def sds(shape, dt):

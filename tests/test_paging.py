@@ -312,6 +312,83 @@ def test_kv_blocks_read_total_counts_what_the_lengths_say(model):
     assert st["decode_steps"] == max(budgets.values()) - 1
 
 
+def test_kv_chunks_count_what_the_decode_kernel_walks(model, monkeypatch):
+    """``kv_chunks_total`` and ``kv_chunks_whole_total`` add, each
+    decode step, the chunks the paged kernel walks for the live slots
+    and those whose blocks are all live with consecutive ids. Held
+    against a recount from the block tables and lengths each decode
+    step is handed: a fresh pool gives a long strip consecutive ids, so
+    every chunk it fills is whole; retire-and-admit churn with shared
+    prefixes breaks runs; and a step still takes one host sync."""
+    from kubeflow_rm_tpu.models import paging
+    from kubeflow_rm_tpu.ops import paged_attention as pa
+
+    cfg, params = model
+    block, cb = 4, 4
+    monkeypatch.setattr(pa, "CHUNK_TOKENS", block * cb)
+    seen = []
+    step = paging.paged_decode_step
+
+    def recorded(params, cfg, cache, tokens, active):
+        seen.append(jax.device_get((cache.block_tables, cache.write_idx))
+                    + (np.asarray(active),))
+        return step(params, cfg, cache, tokens, active)
+
+    monkeypatch.setattr(paging, "paged_decode_step", recorded)
+
+    def recount():
+        total = whole = 0
+        for tables, lengths, active in seen:
+            for row, n in zip(tables[active], lengths[active]):
+                nb = -(-int(n) // block)
+                total += -(-nb // cb)
+                whole += sum(
+                    all(row[c * cb + j] == row[c * cb] + j
+                        for j in range(cb)) for c in range(nb // cb))
+        return total, whole
+
+    def run(eng):
+        calls = 0
+        while eng.queue_depth or eng.active_slots:
+            eng.step()
+            calls += 1
+        return calls
+
+    eng = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=64,
+                                   block_size=block)
+    eng.submit(list(range(1, 31)), max_new_tokens=20)
+    calls = run(eng)
+    st = eng.stats()
+    # cached 30 .. 48 tokens over 19 steps: 8 .. 12 blocks, two or
+    # three chunks, every one of them filled whole
+    assert st["decode_steps"] == 19 and not eng.pool.cow_forks
+    assert st["kv_chunks_total"] == sum(
+        -(-(-(-n // block)) // cb) for n in range(30, 49))
+    assert st["kv_chunks_whole_total"] == sum(
+        -(-n // block) // cb for n in range(30, 49))
+    assert (st["kv_chunks_total"], st["kv_chunks_whole_total"]) == recount()
+    assert st["host_syncs_total"] == calls
+
+    seen.clear()
+    eng = ContinuousBatchingEngine(params, cfg, slots=3, slot_len=128,
+                                   block_size=block,
+                                   num_blocks=RESERVED_BLOCKS + 40)
+    rng = np.random.default_rng(5)
+    shared = [int(t) for t in rng.integers(1, cfg.vocab_size, 20)]
+    for r in range(12):
+        own = [int(t) for t in rng.integers(1, cfg.vocab_size,
+                                            int(rng.integers(3, 25)))]
+        eng.submit((shared if r % 3 == 0 else []) + own,
+                   max_new_tokens=int(rng.integers(2, 14)))
+    calls = run(eng)
+    st = eng.stats()
+    total, whole = recount()
+    assert (st["kv_chunks_total"], st["kv_chunks_whole_total"]) == (
+        total, whole)
+    assert 0 < whole < total and st["prefix_hit_tokens"] > 0
+    assert st["host_syncs_total"] == calls
+
+
 def test_models_import_one_way_trunk_paging_engine():
     """``decode`` (the trunk) <- ``paging`` (the pool) <- ``generate``
     (the engine): read from the sources, nothing executed. The two

@@ -229,9 +229,11 @@ def _run_blocks(params, cfg, tokens, positions, layer_xs, attend):
 
 
 def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
-                       attend, attend_window=None, head_at=None):
+                       attend, attend_window=None, head_at=None,
+                       routes=None):
     """The trunk of a config whose layers are parts of several kinds
-    (``models.nemotron_h``, ``models.qwen3_next``, ``models.afmoe``),
+    (``models.nemotron_h``, ``models.qwen3_next``, ``models.afmoe``,
+    ``models.lfm2``),
     beside ``_run_blocks`` and for the same callers: embed, the
     pattern's parts in order, each ``x <- x + part(norm(x))``, final
     norm, head.
@@ -241,15 +243,19 @@ def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
     role, its function), and ``cfg.norm(x, gain)`` is the family's
     pre-norm. A part whose layer holds a ``post_norm`` gain is normed
     after as well (``x <- x + norm'(part(norm(x)))``, sandwich norms),
-    and a config with an ``embed_scale`` multiplies the embedding by
-    it. Five roles:
+    a config with an ``embed_scale`` multiplies the embedding by it,
+    and a tree with no ``lm_head`` reads the embedding's rows as the
+    head (tied). Five roles:
 
     - ``"recurrent"``: ``fn(cfg, layer, h, ssm, conv, i, mask) -> (out,
       ssm', conv')`` advances the ``i``-th recurrent layer's state, a
       row at a time, in the whole stacked arrays (``ssm`` (Lr, B, ...)
-      float32, ``conv`` (Lr, B, kernel - 1, channels));
+      float32, or None for a family whose only state is the tail,
+      ``conv`` (Lr, B, kernel - 1, channels));
     - ``"experts"``: ``fn(cfg, layer, h, mask) -> (out, (assignments
-      held, held experts with a token))``;
+      held, held experts with a token))``, or with a third, the experts
+      each column chose (B, Tc, top_k), which a list ``routes`` gathers
+      in pattern order (a family that logs its choices, ``models.lfm2``);
     - ``"attention"``: ``fn(cfg, layer, h, positions) -> (q, k, v,
       finish)``; ``attend(q, k, v, i) -> (attn, ys)`` lands the
       ``i``-th attention layer's K/V and attends, as ``_run_blocks``'
@@ -293,8 +299,10 @@ def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
         if role == "recurrent":
             out, ssm, conv = part(cfg, layer, h, ssm, conv, i, mask)
         elif role == "experts":
-            out, held = part(cfg, layer, h, mask)
+            out, held, *chosen = part(cfg, layer, h, mask)
             counts = counts + jnp.stack(held)
+            if routes is not None:
+                routes.append(chosen[0])
         elif role == "mlp":
             out = part(cfg, layer, h)
         else:
@@ -309,6 +317,11 @@ def _run_hybrid_blocks(params, cfg, tokens, mask, positions, ssm, conv,
     if head_at is not None:
         x = jax.lax.dynamic_slice_in_dim(x, head_at, 1, axis=1)
     x = cfg.norm(x, params["norm_f"])
-    logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
+    if "lm_head" in params:
+        logits = (x @ params["lm_head"].astype(cdt)).astype(jnp.float32)
+    else:                       # the head tied to the embedding
+        logits = jnp.einsum("btd,vd->btv", x,
+                            params["embed"]["tokens"].astype(cdt)
+                            ).astype(jnp.float32)
     counts = jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
     return logits, (ssm, conv), ys, counts

@@ -1253,14 +1253,18 @@ class ContinuousBatchingEngine:
         ``decode_*`` are the decode steps' alone; empty for a family
         whose programs keep none. With window layers two more: the
         pairs one window layer and one full layer attended, each as a
-        count modulo 2^32 (a reader takes differences modulo 2^32)."""
+        count modulo 2^32 (a reader takes differences modulo 2^32); a
+        hybrid cache that counts pairs (``models.lfm2``) has the full
+        layer's alone."""
         if not hasattr(self.cache, "counters"):
             return {}
-        names = ("expert_assignments_held_total", "experts_active_total",
-                 "moe_steps_total", "window_positions_total",
-                 "full_positions_total")
         dec, pre = np.asarray(jax.device_get(self.cache.counters),
                               np.int64)
+        names = ("expert_assignments_held_total", "experts_active_total",
+                 "moe_steps_total")
+        names += {3: (), 4: ("full_positions_total",),
+                  5: ("window_positions_total", "full_positions_total")
+                  }[len(dec)]
         out = {n: int(d + p) for n, d, p in zip(names, dec, pre)}
         out.update({"decode_" + n: int(d) for n, d in zip(names, dec)})
         # the pairs' counts wrap: their residues, never a negative
@@ -1295,7 +1299,8 @@ class ContinuousBatchingEngine:
                 if self.prompt_tokens else 0.0),
             "prefix_hits_refused_total": self.prefix_hits_refused_total,
             "recurrent_state_bytes": (
-                self.cache.ssm.nbytes + self.cache.conv.nbytes
+                sum(a.nbytes for a in (self.cache.ssm, self.cache.conv)
+                    if a is not None)
                 if self._recurrent else 0),
             "window_state_bytes": (
                 self.cache.ring_k.nbytes + self.cache.ring_v.nbytes

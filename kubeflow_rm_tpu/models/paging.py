@@ -102,7 +102,9 @@ class HybridPagedCache:
     and recurrent layer each, the recurrent state and the convolution's
     tail, which are no strip of positions and live outside the pool;
     their shapes are the config's (``cfg.recurrent_shapes``: a Mamba-2
-    layer's, a Gated DeltaNet layer's delta-rule state).
+    layer's, a Gated DeltaNet layer's delta-rule state; ``ssm`` is None
+    for a family whose only state is the tail, ``models.lfm2``'s short
+    convolution).
     Admission writes a prefill's final state over whatever the slot
     held (``paged_install``); a decode step advances the live rows'
     and leaves the others' as it was. ``counters`` are the expert
@@ -111,11 +113,21 @@ class HybridPagedCache:
     install): token-expert assignments routed to held experts, held
     experts with at least one token summed over layers, and programs
     run. int32: they wrap after 2^31, days of traffic; a reader takes
-    differences."""
+    differences. A config that ``counts_pairs`` has a fourth column:
+    the (query, key) pairs one attention layer attended over the live
+    slots (``WindowPagedCache``'s full layer's), which wrap within
+    minutes of traffic: a reader takes differences modulo 2^32.
+
+    A config that ``logs_routes`` keeps ``routes`` besides: the experts
+    each column of each slot's strip chose, an expert layer each, as
+    the programs chose them (install writes a prompt's, a decode step
+    each live row's column), so that a check can follow the program's
+    own routing; None for the other families."""
     kv: PagedKVCache
-    ssm: jax.Array           # (Lr, SLOTS, heads, ...) f32
+    ssm: jax.Array | None    # (Lr, SLOTS, heads, ...) f32
     conv: jax.Array          # (Lr, SLOTS, kernel - 1, channels)
-    counters: jax.Array      # (2, 3) int32
+    counters: jax.Array      # (2, 3) or (2, 4) int32
+    routes: jax.Array | None = None   # (Le, SLOTS, S, top_k) int16
 
 
 @jax.tree_util.register_dataclass
@@ -189,9 +201,13 @@ def init_paged_cache(cfg: LlamaConfig, slots: int, slot_len: int,
         return HybridPagedCache(
             kv=_init_kv_pool(cfg, cfg.n_attention_layers, slots, slot_len,
                              num_blocks, block_size),
-            ssm=jnp.zeros(state, jnp.float32),
+            ssm=None if state is None else jnp.zeros(state, jnp.float32),
             conv=jnp.zeros(tail, cfg.dtype),
-            counters=jnp.zeros((2, 3), jnp.int32))
+            counters=jnp.zeros(
+                (2, 3 + getattr(cfg, "counts_pairs", False)), jnp.int32),
+            routes=(jnp.zeros((cfg.pattern.count("E"), slots, slot_len,
+                               cfg.top_k), jnp.int16)
+                    if getattr(cfg, "logs_routes", False) else None))
     return _init_kv_pool(cfg, cfg.n_layers, slots, slot_len, num_blocks,
                          block_size)
 
@@ -316,16 +332,31 @@ def _hybrid_decode_step(params, cfg, cache: HybridPagedCache, tokens,
     one step of its recurrence from the slot's state, live rows only
     (an inactive row's state and tail come out as they went in, not
     garbage: the slot may be reseated later, or stay retired); the
-    expert layers see the live rows only and add to the counters."""
+    expert layers see the live rows only and add to the counters, and
+    where the cache counts them so do the pairs one attention layer
+    attended (a live slot's cached tokens and its own), and where the
+    cache logs them each live row's choice of experts at its column."""
     kv = cache.kv
     positions, wi, blk, off, kv_positions = _decode_columns(kv, active)
+    routes = None if cache.routes is None else []
     logits, (ssm, conv), cols, counts = _run_hybrid_blocks(
         params, cfg, tokens[:, None], active[:, None], positions, cache.ssm,
-        cache.conv, _pool_attend(kv, positions, wi, active, kv_positions))
+        cache.conv, _pool_attend(kv, positions, wi, active, kv_positions),
+        routes=routes)
     col_k, col_v = (jnp.stack(c) for c in zip(*cols))
+    if cache.counters.shape[1] > 3:
+        pairs = jnp.sum(active.astype(jnp.int32) * (kv.write_idx + 1))
+        counts = jnp.concatenate([counts, pairs[None]])
+    log = cache.routes
+    if log is not None:
+        rows = jnp.arange(active.shape[0])
+        col = jnp.where(active, wi, log.shape[2])     # idle rows: dropped
+        log = log.at[:, rows, col].set(
+            jnp.stack(routes)[:, :, 0].astype(log.dtype), mode="drop")
     return logits[:, -1, :], HybridPagedCache(
         kv=_land_columns(kv, col_k, col_v, blk, off, active),
-        ssm=ssm, conv=conv, counters=cache.counters.at[0].add(counts))
+        ssm=ssm, conv=conv, counters=cache.counters.at[0].add(counts),
+        routes=log)
 
 
 def _by_kind(cfg, cols):
@@ -455,17 +486,28 @@ def _hybrid_prefill(params, cfg, cache: HybridPagedCache, load_row, n_hit,
     not addressable by token block, so the engine takes no prefix hit
     for this family); the pad columns of the bucket advance no state,
     so what comes back beside the strip is the state after the last
-    REAL token, with the expert layers' counts, for ``paged_install``."""
+    REAL token, with the expert layers' counts (and, where the cache
+    counts them, the prompt's causal pairs; where it logs them, the
+    experts each column chose, (Le, Tc, top_k)), for ``paged_install``."""
     Tc = tokens.shape[1]
     (gk, gv), positions, kv_positions, write_kv = _prefix_strip(
         cache.kv, load_row, n_hit, Tc, n_real)
     strip_attend = _cache_attend(write_kv, positions, kv_positions)
+    routes = None if cache.routes is None else []
     logits, (ssm, conv), strips, counts = _run_hybrid_blocks(
         params, cfg, tokens, jnp.arange(Tc)[None, :] < n_real, positions,
-        jnp.zeros_like(cache.ssm[:, :1]), jnp.zeros_like(cache.conv[:, :1]),
-        lambda q, k, v, i: strip_attend(q, k, v, (gk[i], gv[i])))
+        None if cache.ssm is None else jnp.zeros_like(cache.ssm[:, :1]),
+        jnp.zeros_like(cache.conv[:, :1]),
+        lambda q, k, v, i: strip_attend(q, k, v, (gk[i], gv[i])),
+        routes=routes)
     new_k, new_v = (jnp.stack(c) for c in zip(*strips))
+    if cache.counters.shape[1] > 3:
+        pairs = n_real * (n_real + 1) // 2
+        counts = jnp.concatenate([counts, pairs.astype(jnp.int32)[None]])
     last = logits[0, n_real - 1, :]
+    if routes is not None:
+        return last, new_k, new_v, kv_positions, (
+            ssm, conv, counts, jnp.stack(routes)[:, 0])
     return last, new_k, new_v, kv_positions, (ssm, conv, counts)
 
 
@@ -533,9 +575,10 @@ def paged_install(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
     column — the same offset solo ``generate_fused`` writes.
 
     For a ``HybridPagedCache``, ``prefilled`` is the prefill's (recurrent
-    state, convolution tail, expert counts): the first two replace
-    whatever the slot held (the wipe a reseated slot relies on), the
-    counts join the cache's counters. For a ``WindowPagedCache`` it is
+    state, convolution tail, expert counts[, expert choices]): the
+    first two replace whatever the slot held (the wipe a reseated slot
+    relies on), the counts join the cache's counters, and the choices,
+    where the cache logs them, land at the strip's first columns. For a ``WindowPagedCache`` it is
     the prefill's (rings, counts): each window layer's ring replaces
     the slot's."""
     if isinstance(cache, WindowPagedCache):
@@ -547,13 +590,19 @@ def paged_install(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
             ring_v=cache.ring_v.at[:, slot].set(ring_v[:, 0]),
             counters=cache.counters.at[1].add(counts))
     if isinstance(cache, HybridPagedCache):
-        ssm, conv, counts = prefilled
+        ssm, conv, counts, *chosen = prefilled
+        log = cache.routes
+        if log is not None:
+            # a bucket wider than the strip: its columns past it are pads
+            chosen = chosen[0][:, None, :log.shape[2]].astype(log.dtype)
+            log = jax.lax.dynamic_update_slice(log, chosen, (0, slot, 0, 0))
         return HybridPagedCache(
             kv=_install_strip(cache.kv, temp_k, temp_v, temp_pos, slot,
                               final_row, dest_row, write_idx0),
-            ssm=cache.ssm.at[:, slot].set(ssm[:, 0]),
+            ssm=(None if ssm is None
+                 else cache.ssm.at[:, slot].set(ssm[:, 0])),
             conv=cache.conv.at[:, slot].set(conv[:, 0]),
-            counters=cache.counters.at[1].add(counts))
+            counters=cache.counters.at[1].add(counts), routes=log)
     return _install_strip(cache, temp_k, temp_v, temp_pos, slot, final_row,
                           dest_row, write_idx0)
 

@@ -141,11 +141,13 @@ def moe_ffn(params: dict, x: jax.Array, cfg: MoeConfig,
 
 
 def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, bias: jax.Array,
-                       top_k: int, scale: float):
+                       top_k: int, scale: float, *, eps: float = 1e-20):
     """Sigmoid routing over every expert the router names: ``h`` (N, D)
     -> (expert ids (N, k) int32, weights (N, k) float32). The ``top_k``
     largest of ``sigmoid(h W) + bias`` are chosen (the bias steers the
-    choice only), weighted by their own scores normalised to sum to
+    choice only), weighted by their own scores divided by their sum plus
+    ``eps`` (a guard against a zero sum where the family's own is none;
+    ``models.lfm2``'s 1e-6 is part of its equations) and times
     ``scale``. Float32 at full precision: a routing decision should not
     flip with the compute dtype's rounding."""
     s = jax.nn.sigmoid(jnp.dot(h.astype(jnp.float32),
@@ -153,7 +155,7 @@ def route_sigmoid_topk(h: jax.Array, router_w: jax.Array, bias: jax.Array,
                                precision="highest"))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, idx, axis=-1)
-    w = chosen / (jnp.sum(chosen, -1, keepdims=True) + 1e-20) * scale
+    w = chosen / (jnp.sum(chosen, -1, keepdims=True) + eps) * scale
     return idx.astype(jnp.int32), w
 
 
@@ -176,15 +178,16 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
                      expert_in: jax.Array | None = None,
                      live: jax.Array | None = None,
                      gate: jax.Array | None = None,
-                     routing: str = "sigmoid"):
+                     routing: str = "sigmoid", eps: float = 1e-20):
     """The part of a dropless expert layer that the experts held here
     give: squared-ReLU experts ``up`` (held, d, f) and ``down``
     (held, f, d), or with ``gate`` (held, d, f) SwiGLU experts
     ``down(silu(x gate) * x up)``, which are experts ``first .. first +
     held`` of the ``router_w.shape[1]`` the router chooses among.
-    ``routing`` is ``"sigmoid"`` (``route_sigmoid_topk`` with ``bias``
-    and ``scale``) or ``"softmax"`` (``route_softmax_topk``; ``bias``
-    and ``scale`` unused). Both are static properties of the call.
+    ``routing`` is ``"sigmoid"`` (``route_sigmoid_topk`` with ``bias``,
+    ``scale`` and ``eps``) or ``"softmax"`` (``route_softmax_topk``;
+    ``bias``, ``scale`` and ``eps`` unused). Both are static properties
+    of the call.
 
     Every token of ``h`` (N, D) is routed over all experts; the
     assignments whose expert lies in the held range are computed, by
@@ -209,7 +212,8 @@ def held_experts_ffn(h: jax.Array, router_w: jax.Array, bias: jax.Array,
     x = h if expert_in is None else expert_in
     N, held = x.shape[0], up.shape[0]
     if routing == "sigmoid":
-        idx, w = route_sigmoid_topk(h, router_w, bias, top_k, scale)
+        idx, w = route_sigmoid_topk(h, router_w, bias, top_k, scale,
+                                    eps=eps)
     elif routing == "softmax":
         idx, w = route_softmax_topk(h, router_w, top_k)
     else:

@@ -749,7 +749,7 @@ class ContinuousBatchingEngine:
                              "go through adopt_chain")
         ck = chain["chunks_k"]
         if (ck.shape[0] != self.cache.k.shape[0]
-                or ck.shape[2:] != self.cache.k.shape[2:]):
+                or ck.shape[2:] != self.cache.block_shape):
             raise ValueError("chain chunk shape does not fit this "
                              "engine's cache")
         req = self.submit(chain["tokens"],
@@ -1035,15 +1035,18 @@ class ContinuousBatchingEngine:
             tail = jnp.asarray(fresh[nchain:], jnp.int32)
             positions = positions.at[tail].set(_UNFILLED)
         self.cache = paging.PagedKVCache(
-            k=cache.k.at[:, idx].set(
-                jnp.asarray(chain["chunks_k"], cache.k.dtype)),
-            v=cache.v.at[:, idx].set(
-                jnp.asarray(chain["chunks_v"], cache.v.dtype)),
+            k=cache.k.at[:, idx].set(jnp.asarray(
+                chain["chunks_k"], cache.k.dtype).reshape(
+                    -1, nchain, *cache.k.shape[2:])),
+            v=cache.v.at[:, idx].set(jnp.asarray(
+                chain["chunks_v"], cache.v.dtype).reshape(
+                    -1, nchain, *cache.v.shape[2:])),
             positions=positions,
             block_tables=cache.block_tables.at[i].set(
                 jnp.asarray(final_row, jnp.int32)),
             write_idx=cache.write_idx.at[i].set(Tp),
             pos_next=cache.pos_next.at[i].set(Tp),
+            kv_heads=cache.kv_heads,
         )
         self._register_chain(zip(chain["covers"], chain["keys"]),
                              final_row)
@@ -1085,8 +1088,8 @@ class ContinuousBatchingEngine:
         # slot table — prefill replicas never decode, the chain lives
         # purely in the pool + prefix index
         L = self.cache.k.shape[0]
-        ck = tk[:, 0].reshape(L, maxb, BS, *tk.shape[3:])
-        cv = tv[:, 0].reshape(L, maxb, BS, *tv.shape[3:])
+        ck = tk[:, 0].reshape(L, maxb, *self.cache.k.shape[2:])
+        cv = tv[:, 0].reshape(L, maxb, *self.cache.v.shape[2:])
         cp = tpos[0].reshape(maxb, BS)
         idx = jnp.asarray(fresh, jnp.int32)
         self.cache = paging.PagedKVCache(
@@ -1096,6 +1099,7 @@ class ContinuousBatchingEngine:
             block_tables=self.cache.block_tables,
             write_idx=self.cache.write_idx,
             pos_next=self.cache.pos_next,
+            kv_heads=self.cache.kv_heads,
         )
         if fork_src is not None:
             pool.decref([fork_src])

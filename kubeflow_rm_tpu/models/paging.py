@@ -51,7 +51,7 @@ from __future__ import annotations
 
 import hashlib
 from collections import OrderedDict, deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -64,7 +64,7 @@ from kubeflow_rm_tpu.models.decode import (
 from kubeflow_rm_tpu.models.llama import LlamaConfig
 from kubeflow_rm_tpu.ops import dot_product_attention
 from kubeflow_rm_tpu.ops.paged_attention import (
-    paged_decode_attention, window_decode_attention,
+    lane_dense, paged_decode_attention, window_decode_attention,
 )
 
 #: reserved block ids (see module docstring)
@@ -84,13 +84,30 @@ class PagedKVCache:
     """Pool-of-blocks KV state. ``block_tables[i]`` concatenated is
     slot *i*'s logical strip of ``slot_len = MAXB * BS`` positions;
     ``write_idx``/``pos_next`` are per-slot counters in logical-strip
-    offsets, so each slot advances on its own."""
+    offsets, so each slot advances on its own.
+
+    Where a head is narrower than the lanes and a token's heads fill
+    them (``ops.paged_attention.lane_dense``: LFM2's 8 x 64) the pool
+    keeps a token as one row of ``KVH * hd`` lanes, ``k`` and ``v``
+    (L, NB, BS, KVH * hd): a block is then one region of the pool, as
+    the decode kernel copies it, where the chip would keep a 5-D pool
+    of such a head with the block index minor. Code that wants heads
+    reads a block as ``block_shape`` and writes one as the pool keeps
+    it."""
     k: jax.Array             # (L, NB, BS, KVH, hd) compute dtype
     v: jax.Array             # (L, NB, BS, KVH, hd)
     positions: jax.Array     # (NB, BS) int32; _UNFILLED marks empty
     block_tables: jax.Array  # (SLOTS, MAXB) int32; NULL_BLOCK = unset
     write_idx: jax.Array     # (SLOTS,) int32: next logical write slot
     pos_next: jax.Array      # (SLOTS,) int32: next token position
+    kv_heads: int = field(metadata={"static": True})   # KVH
+
+    @property
+    def block_shape(self) -> tuple:
+        """A block as the code that wants heads reads it: (BS, KVH,
+        hd)."""
+        BS, *token = self.k.shape[2:]
+        return (BS, self.kv_heads, int(np.prod(token)) // self.kv_heads)
 
 
 @jax.tree_util.register_dataclass
@@ -222,14 +239,16 @@ def _init_kv_pool(cfg, L: int, slots: int, slot_len: int,
                          f"blocks ({RESERVED_BLOCKS} are reserved)")
     KVH, hd = cfg.n_kv_heads, cfg.head_dim
     maxb = slot_len // block_size
+    token = (KVH * hd,) if lane_dense(KVH, hd) else (KVH, hd)
     return PagedKVCache(
-        k=jnp.zeros((L, num_blocks, block_size, KVH, hd), cfg.dtype),
-        v=jnp.zeros((L, num_blocks, block_size, KVH, hd), cfg.dtype),
+        k=jnp.zeros((L, num_blocks, block_size, *token), cfg.dtype),
+        v=jnp.zeros((L, num_blocks, block_size, *token), cfg.dtype),
         positions=jnp.full((num_blocks, block_size), _UNFILLED,
                            jnp.int32),
         block_tables=jnp.full((slots, maxb), NULL_BLOCK, jnp.int32),
         write_idx=jnp.zeros((slots,), jnp.int32),
         pos_next=jnp.zeros((slots,), jnp.int32),
+        kv_heads=KVH,
     )
 
 
@@ -314,14 +333,24 @@ def _land_columns(cache: PagedKVCache, col_k, col_v, blk, off, active):
     (inactive rows land in SINK; duplicate sink hits are
     garbage-on-garbage) and advance the live rows' counters."""
     inc = active.astype(jnp.int32)
+    at = (slice(None), blk, off)
+    if cache.k.ndim == 4:
+        # a lane-dense pool's token is a row of a tile: with a window
+        # of (L, KVH * hd) the chip's scatter would relay the whole
+        # pool to put L minor; with the layer indexed too it writes
+        # the rows in place
+        at = (jnp.arange(cache.k.shape[0])[:, None], blk, off)
     return PagedKVCache(
-        k=cache.k.at[:, blk, off].set(col_k),
-        v=cache.v.at[:, blk, off].set(col_v),
+        k=cache.k.at[at].set(col_k.reshape(*col_k.shape[:2],
+                                           *cache.k.shape[3:])),
+        v=cache.v.at[at].set(col_v.reshape(*col_v.shape[:2],
+                                           *cache.v.shape[3:])),
         positions=cache.positions.at[blk, off].set(
             jnp.where(active, cache.pos_next, _UNFILLED)),
         block_tables=cache.block_tables,
         write_idx=cache.write_idx + inc,
         pos_next=cache.pos_next + inc,
+        kv_heads=cache.kv_heads,
     )
 
 
@@ -461,8 +490,8 @@ def _prefix_strip(cache: PagedKVCache, load_row, n_hit, Tc, n_real):
     MAXB, BS = load_row.shape[0], cache.positions.shape[1]
     S = MAXB * BS
 
-    gk = cache.k[:, load_row].reshape(L, 1, S, *cache.k.shape[3:])
-    gv = cache.v[:, load_row].reshape(L, 1, S, *cache.v.shape[3:])
+    gk = cache.k[:, load_row].reshape(L, 1, S, *cache.block_shape[1:])
+    gv = cache.v[:, load_row].reshape(L, 1, S, *cache.block_shape[1:])
     gpos = cache.positions[load_row].reshape(1, S)
     idx = jnp.arange(S, dtype=jnp.int32)[None, :]
     gpos = jnp.where(idx < n_hit, gpos, _UNFILLED)
@@ -524,7 +553,7 @@ def _window_prefill(params, cfg, cache: WindowPagedCache, tokens, n_real):
     last ``Wc`` positions (an offset the prompt never reached holds
     its first column: masked by position) with the counts."""
     Tc = tokens.shape[1]
-    L, NB, BS, KVH, hd = cache.kv.k.shape
+    BS, KVH, hd = cache.kv.block_shape
     S = cache.kv.block_tables.shape[1] * BS
     Wc = cache.ring_k.shape[2]
     W = cfg.sliding_window
@@ -611,8 +640,8 @@ def _install_strip(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
                    final_row, dest_row, write_idx0):
     L = cache.k.shape[0]
     MAXB, BS = dest_row.shape[0], cache.positions.shape[1]
-    chunks_k = temp_k[:, 0].reshape(L, MAXB, BS, *temp_k.shape[3:])
-    chunks_v = temp_v[:, 0].reshape(L, MAXB, BS, *temp_v.shape[3:])
+    chunks_k = temp_k[:, 0].reshape(L, MAXB, *cache.k.shape[2:])
+    chunks_v = temp_v[:, 0].reshape(L, MAXB, *cache.v.shape[2:])
     chunks_p = temp_pos[0].reshape(MAXB, BS)
     return PagedKVCache(
         k=cache.k.at[:, dest_row].set(chunks_k),
@@ -621,6 +650,7 @@ def _install_strip(cache: PagedKVCache, temp_k, temp_v, temp_pos, slot,
         block_tables=cache.block_tables.at[slot].set(final_row),
         write_idx=cache.write_idx.at[slot].set(write_idx0),
         pos_next=cache.pos_next.at[slot].set(write_idx0),
+        kv_heads=cache.kv_heads,
     )
 
 
@@ -634,8 +664,8 @@ def gather_slot_strip(cache: PagedKVCache, slot):  # kfrm: disable=KFRM008
     row = cache.block_tables[slot]
     L = cache.k.shape[0]
     MAXB, BS = row.shape[0], cache.positions.shape[1]
-    k = cache.k[:, row].reshape(L, MAXB * BS, *cache.k.shape[3:])
-    v = cache.v[:, row].reshape(L, MAXB * BS, *cache.v.shape[3:])
+    k = cache.k[:, row].reshape(L, MAXB * BS, *cache.block_shape[1:])
+    v = cache.v[:, row].reshape(L, MAXB * BS, *cache.block_shape[1:])
     pos = cache.positions[row].reshape(MAXB * BS)
     return k, v, pos
 
@@ -875,8 +905,9 @@ def export_block_chunk(cache: PagedKVCache, block: int,
     (zero K/V, ``_UNFILLED`` positions) so the bytes — and therefore
     the checksum — depend only on the prefix the block's key names,
     never on whatever a later request generated into the tail."""
-    ck = np.array(cache.k[:, block])           # (L, BS, KVH, hd)
-    cv = np.array(cache.v[:, block])
+    L = cache.k.shape[0]
+    ck = np.array(cache.k[:, block]).reshape(L, *cache.block_shape)
+    cv = np.array(cache.v[:, block]).reshape(L, *cache.block_shape)
     cp = np.array(cache.positions[block], np.int32)
     ck[:, valid:] = 0
     cv[:, valid:] = 0
@@ -898,8 +929,9 @@ def export_chain(cache: PagedKVCache, pool: BlockPool,
         return None
     BS = pool.block_size
     idx = jnp.asarray(blocks, jnp.int32)
-    ck = np.array(cache.k[:, idx])             # (L, NC, BS, KVH, hd)
-    cv = np.array(cache.v[:, idx])
+    L, NC = cache.k.shape[0], len(blocks)
+    ck = np.array(cache.k[:, idx]).reshape(L, NC, *cache.block_shape)
+    cv = np.array(cache.v[:, idx]).reshape(L, NC, *cache.block_shape)
     cp = np.array(cache.positions[idx], np.int32)
     for i, (covered, _key) in enumerate(keys):
         valid = covered - i * BS
@@ -970,22 +1002,25 @@ def import_chain(cache: PagedKVCache, pool: BlockPool,
             f"block_size {pool.block_size}")
     nc = len(chain["keys"])
     if chain["chunks_k"].shape[0] != cache.k.shape[0] \
-            or chain["chunks_k"].shape[2:] != cache.k.shape[2:]:
+            or chain["chunks_k"].shape[2:] != cache.block_shape:
         raise ValueError("chain chunk shape does not fit this cache")
     blocks = pool.alloc(nc)
     if blocks is None:
         return None
     idx = jnp.asarray(blocks, jnp.int32)
     cache = PagedKVCache(
-        k=cache.k.at[:, idx].set(
-            jnp.asarray(chain["chunks_k"], cache.k.dtype)),
-        v=cache.v.at[:, idx].set(
-            jnp.asarray(chain["chunks_v"], cache.v.dtype)),
+        k=cache.k.at[:, idx].set(jnp.asarray(
+            chain["chunks_k"], cache.k.dtype).reshape(
+                -1, nc, *cache.k.shape[2:])),
+        v=cache.v.at[:, idx].set(jnp.asarray(
+            chain["chunks_v"], cache.v.dtype).reshape(
+                -1, nc, *cache.v.shape[2:])),
         positions=cache.positions.at[idx].set(
             jnp.asarray(chain["chunks_pos"], jnp.int32)),
         block_tables=cache.block_tables,
         write_idx=cache.write_idx,
         pos_next=cache.pos_next,
+        kv_heads=cache.kv_heads,
     )
     parent = None
     for i, key in enumerate(chain["keys"]):

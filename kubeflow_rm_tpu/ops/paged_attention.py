@@ -33,6 +33,13 @@ bias keeps, for query head ``h``, only the rows of kv head ``h // G``
 necessary multiplies on a matrix whose cost is loading K either way;
 in exchange a block is one DMA, one ``(128, 128)`` tile at Mistral's
 widths, and the decode step's one-column scatter stays one whole tile.
+A head narrower than the lanes whose token's heads fill them (LFM2's 8
+x 64) is kept lane-dense (``lane_dense``): a block is ``(BS, KVH *
+hd)``, rows tokens and lanes (kv head, dim). There the query is spread
+outside the kernel to ``(H, KVH * hd)``, head ``h`` in the lanes of kv
+head ``h // G`` and zeros in the others, so ``q @ K^T`` is each head's
+own scores with no bias, and ``p @ V`` leaves head ``h``'s output in
+those lanes, picked out after the call.
 
 **The mask.** A key is seen iff its position is filled and not after
 the query's. The XLA path reads that from the pool's positions. The
@@ -104,13 +111,32 @@ def whole_chunks(blocks, cb: int) -> np.ndarray:
     return np.concatenate([[0], np.cumsum(whole)])
 
 
+def lane_dense(kv_heads: int, head_dim: int) -> bool:
+    """Does a block pool keep a token's heads as one row of ``kv_heads
+    * head_dim`` lanes, ``(L, NB, BS, KVH * hd)``? Where a head is
+    narrower than the 128 lanes but a token's heads fill them whole: a
+    5-D pool of such a head (LFM2's 8 x 64) the chip keeps with the
+    block index minor, to spare padding 64 lanes to 128, so that no
+    block of it is contiguous and every reader relays the whole pool
+    first; a block ``(BS, KVH * hd)`` is one region, unpadded."""
+    return head_dim % 128 != 0 and kv_heads * head_dim % 128 == 0
+
+
 def kernel_eligible(q, pool_k) -> bool:
     """Do the shapes tile the chip? The head dimension fills the 128
     lanes, a block's ``BS * KVH`` rows and the ``H`` query heads whole
-    sublane tiles of their (packed) dtype."""
+    sublane tiles of their (packed) dtype; or, for a lane-dense pool
+    (``lane_dense``), a token's heads fill the lanes and a block's
+    ``BS`` rows whole sublane tiles."""
     _, H, hd = q.shape
-    _, _, BS, KVH, _ = pool_k.shape
     sublanes = 8 * (4 // jnp.dtype(pool_k.dtype).itemsize)
+    if pool_k.ndim == 4:
+        _, _, BS, lanes = pool_k.shape
+        KVH = lanes // hd
+        return (lanes == KVH * hd and lane_dense(KVH, hd)
+                and BS % sublanes == 0 and H % sublanes == 0
+                and H % KVH == 0)
+    _, _, BS, KVH, _ = pool_k.shape
     return (hd % 128 == 0 and (BS * KVH) % sublanes == 0
             and H % sublanes == 0 and H % KVH == 0)
 
@@ -125,7 +151,9 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer,
       q: (B, H, hd) the token's queries, a slot each.
       k_new, v_new: (B, KVH, hd) its key and value: the strip's column
         at offset ``lengths``, not yet in the pool.
-      pool_k, pool_v: (L, NB, BS, KVH, hd) the block pool, all layers.
+      pool_k, pool_v: (L, NB, BS, KVH, hd) the block pool, all layers,
+        or (L, NB, BS, KVH * hd) where it is lane-dense
+        (``lane_dense``).
       layer: () int32, the layer to read.
       block_tables: (B, MAXB) int32; a row's blocks in strip order.
       lengths: (B,) int32 tokens the slot's strip already holds, which
@@ -166,7 +194,7 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer,
 
     def strip(pool, new):
         g = pool[layer, block_tables].reshape(B, MAXB * BS,
-                                              *pool.shape[3:])
+                                              *new.shape[1:])
         return g.at[rows, lengths].set(new)
 
     out = dot_product_attention(
@@ -178,11 +206,13 @@ def paged_decode_attention(q, k_new, v_new, pool_k, pool_v, layer,
 
 def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
             *refs, block_size: int, chunk_blocks: int,
-            ring: int | None = None):
+            ring: int | None = None, lanes: bool = False):
     if ring is None:
         ring_ref = None
     else:                   # a window's ring: one more scalar prefetch
         ring_ref, *refs = refs
+    if lanes:               # a lane-dense pool's scores need no bias
+        refs = (*refs[:3], None, *refs[3:])
     (q_ref, kn_ref, vn_ref, head_bias_ref, col_tok_ref, k_hbm, v_hbm,
      o_ref, item_slot, item_chunk, kbuf, vbuf, ksem, vsem,
      *more) = refs
@@ -264,13 +294,14 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
     # and 0 x (whatever VMEM held) must still be 0 in the second matmul
     vbuf[...] = jnp.zeros_like(vbuf)
     # a slot with nothing cached attends to its own token alone
-    o_ref[...] = vn_ref[...]
+    o_ref[...] = jnp.broadcast_to(vn_ref[...], o_ref.shape)
 
     @pl.when(n_items > 0)
     def _():
         start(0, 0)
 
-    head_bias = head_bias_ref[...]                     # (H, CB*R) f32
+    if head_bias_ref is not None:
+        head_bias = head_bias_ref[...]                 # (H, CB*R) f32
     col_tok = col_tok_ref[...]                         # (1, CB*R) i32
 
     def body(i, carry):
@@ -299,7 +330,8 @@ def _kernel(layer_ref, lengths_ref, tables_ref,        # scalar prefetch
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)        # (H, CB*R)
-        s = s + head_bias
+        if head_bias_ref is not None:
+            s = s + head_bias
         if ring_ref is None:
             s = jnp.where(col_tok < lengths_ref[b] - c * (CB * BS), s,
                           NEG_INF)
@@ -334,25 +366,43 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
     newest cached offset and the ages it sees) for a window's ring,
     whose ``lengths`` are then its filled offsets."""
     B, H, hd = q.shape
-    L, NB, BS, KVH, _ = pool_k.shape
+    KVH = k_new.shape[1]
+    L, NB, BS = pool_k.shape[:3]
     G = H // KVH
     MAXB = block_tables.shape[1]
     CB = chunk_blocks(BS, MAXB)
-    R = BS * KVH                                       # rows a block
+    lanes = pool_k.ndim == 4                           # lane_dense
+    R = BS if lanes else BS * KVH                      # rows a block
+    W = KVH * hd if lanes else hd                      # lanes a row
     items = B * pl.cdiv(MAXB, CB)                      # the most a call
 
     # the rounding dot_product_attention gives the scaled query
     q = q * hd ** -0.5
-    # a kv head's column under each of its G query heads
-    kn = jnp.repeat(k_new, G, axis=1)                  # (B, H, hd)
-    vn = jnp.repeat(v_new, G, axis=1)
-    # a chunk's columns are (block, token, kv head): query head h
-    # keeps kv head h // G; col_tok is the column's token in the chunk
-    col = np.arange(CB * R)
-    head_bias = np.where(
-        (np.arange(H)[:, None] // G) == (col % KVH)[None, :],
-        0.0, NEG_INF).astype(np.float32)
-    col_tok = (col // KVH).astype(np.int32)[None, :]
+    if lanes:
+        # a chunk's rows are tokens, its lanes (kv head, dim): query
+        # head h holds its scaled query in the lanes of kv head h // G
+        # and zeros in the others, so q @ K^T is each head's own scores
+        # and p @ V holds its output in those lanes; the token's own
+        # column, a row, is every head's
+        own = (np.arange(H)[:, None] // G) == np.arange(KVH)[None, :]
+        q = jnp.where(own[None, :, :, None], q[:, :, None, :],
+                      0).reshape(B, H, W)
+        kn = k_new.reshape(B, 1, W)
+        vn = v_new.reshape(B, 1, W)
+        bias = ()
+        col_tok = np.arange(CB * R, dtype=np.int32)[None, :]
+    else:
+        # a kv head's column under each of its G query heads
+        kn = jnp.repeat(k_new, G, axis=1)              # (B, H, hd)
+        vn = jnp.repeat(v_new, G, axis=1)
+        # a chunk's columns are (block, token, kv head): query head h
+        # keeps kv head h // G; col_tok is the column's token in the
+        # chunk
+        col = np.arange(CB * R)
+        bias = (np.where(
+            (np.arange(H)[:, None] // G) == (col % KVH)[None, :],
+            0.0, NEG_INF).astype(np.float32),)
+        col_tok = (col // KVH).astype(np.int32)[None, :]
 
     def full(*shape):
         return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
@@ -362,24 +412,28 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
     # a bitcast. A wider head lies in (KVH, 128) tiles, where that
     # reshape is a copy of the whole pool every step (537 MB at
     # Qwen3-Next's 2 x 256): the pool goes in as it lies, a block is
-    # copied as it lies and flattened in VMEM
+    # copied as it lies and flattened in VMEM. A lane-dense pool goes
+    # in as it lies, a block (BS, KVH * hd)
     wide = hd > 128
-    block = (BS, KVH, hd) if wide else (R, hd)
+    block = (BS, KVH, hd) if wide else (R, W)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     static, scalars, name = {}, (), "paged_decode_attention"
     if ring is not None:
         static = {"ring": ring[0]}
         scalars = (ring[1].astype(jnp.int32),)
         name = "window_decode_attention"
-    return pl.pallas_call(
+    if lanes:
+        static["lanes"] = True
+    out = pl.pallas_call(
         functools.partial(_kernel, block_size=BS, chunk_blocks=CB,
                           **static),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3 + len(scalars),
             grid=(1,),
-            in_specs=[full(B, H, hd), full(B, H, hd), full(B, H, hd),
-                      full(H, CB * R), full(1, CB * R), hbm, hbm],
-            out_specs=full(B, H, hd),
+            in_specs=[full(B, H, W), full(*kn.shape), full(*vn.shape),
+                      *[full(H, CB * R) for _ in bias], full(1, CB * R),
+                      hbm, hbm],
+            out_specs=full(B, H, W),
             scratch_shapes=[
                 pltpu.SMEM((items,), jnp.int32),
                 pltpu.SMEM((items,), jnp.int32),
@@ -389,16 +443,20 @@ def _paged_decode_kernel(q, k_new, v_new, pool_k, pool_v, layer,
                 pltpu.SemaphoreType.DMA((2,)),
                 *([pltpu.SMEM((items,), jnp.int32)] if CB > 1 else []),
             ]),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, W), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=name,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       lengths.astype(jnp.int32), block_tables.astype(jnp.int32), *scalars,
-      q, kn, vn, head_bias, col_tok,
-      *((pool_k, pool_v) if wide else
+      q, kn, vn, *bias, col_tok,
+      *((pool_k, pool_v) if wide or lanes else
         (pool_k.reshape(L, NB, R, hd), pool_v.reshape(L, NB, R, hd))))
+    if lanes:                  # head h's output, its kv head's lanes
+        heads = np.arange(H)
+        out = out.reshape(B, H, KVH, hd)[:, heads, heads // G]
+    return out
 
 
 def ring_block(ring_len: int) -> int:

@@ -14,6 +14,7 @@ is some hundred float32 roundings; a dropped gate, a dropped tail or an
 ignored bias moves a logit by 1e-2 and more.
 """
 
+import re
 import sys
 from dataclasses import replace
 from pathlib import Path
@@ -401,6 +402,92 @@ def test_the_family_is_not_loaded_by_the_package():
     assert out.stdout.strip() == "False"
 
 
+# -- a head narrower than the lanes: the lane-dense pool ---------------
+
+
+def wide64():
+    """The tiny config at head 64 with two kv heads: a token's heads
+    fill the 128 lanes, so the pool keeps them as one row."""
+    cfg = Config.tiny(dim=256, n_heads=4, n_kv_heads=2)
+    assert (cfg.head_dim, cfg.n_kv_heads) == (64, 2)
+    return cfg, lfm2.init_params(cfg, jax.random.key(5))
+
+
+@pytest.mark.parametrize("n", [1, 15])
+def test_a_head_64_pool_is_lane_dense_and_reads_what_a_5d_pool_reads(
+        monkeypatch, n):
+    """The pool keeps a token as one row of ``KVH * hd`` = 128 lanes.
+    Prefill, install and a run of decode steps through it give the
+    logits they give through the 5-D pool the shape rule would
+    otherwise build, bit for bit, and the reference's within the
+    tolerance; ``gather_slot_strip`` reads the slot's strips back as
+    (L, S, KVH, hd), the same as from the 5-D pool."""
+    cfg, params = wide64()
+    seq = np.random.default_rng(9 + n).integers(1, 256, 22).tolist()
+    want = ref.forward_logits(ref.init_weights(dims(cfg), 5, jnp.float32),
+                              np.asarray([seq]), dims(cfg))[0]
+
+    def serve():
+        cache = fresh_cache(cfg)
+        last, cache = seat(params, cfg, cache, 1, seq[:n], 16,
+                           [2, 3, 4, 5, 6, 7])
+        got = [last]
+        active = jnp.asarray([False, True, False])
+        for t in seq[n:-1]:
+            logits, cache = paging.paged_decode_step(
+                params, cfg, cache, jnp.asarray([0, t, 0], jnp.int32),
+                active)
+            got.append(logits[1])
+        return cache, jnp.stack(got), paging.gather_slot_strip(cache.kv, 1)
+
+    cache, lanes, strips = serve()
+    assert cache.kv.k.shape == cache.kv.v.shape == (1, 34, 4, 128)
+    assert cache.kv.block_shape == (4, 2, 64)
+    assert [a.shape for a in strips] == [(1, 32, 2, 64)] * 2 + [(32,)]
+    assert float(jnp.abs(lanes - want[n - 1:-1]).max()) < TOL
+    monkeypatch.setattr(paging, "lane_dense", lambda *a: False)
+    cache, heads, strips5 = serve()
+    assert cache.kv.k.shape == (1, 34, 4, 2, 64)
+    np.testing.assert_array_equal(np.asarray(lanes), np.asarray(heads))
+    for a, b in zip(strips, strips5):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_the_engine_reads_a_lane_dense_pool_through_the_kernel():
+    """Requests over two slots at head 64, the decode step's attention
+    forced through the paged kernel (interpreted here): each answer is
+    the greedy continuation of its prompt by the full forward, the
+    program traced the kernel for the lane-dense pool, and the engine
+    counts the chunks the kernel walked."""
+    from functools import partial
+    from unittest import mock
+
+    from kubeflow_rm_tpu.ops.attention import kernel_choices
+    from kubeflow_rm_tpu.ops.paged_attention import paged_decode_attention
+
+    cfg, params = wide64()
+    eng = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=64,
+                                   block_size=4)
+    assert eng.cache.kv.k.ndim == 4
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, 256, n).tolist() for n in (6, 1, 11)]
+    news = (5, 8, 4)
+    reqs = [eng.submit(p, max_new_tokens=m) for p, m in zip(prompts, news)]
+    with mock.patch.object(paging, "paged_decode_attention",
+                           partial(paged_decode_attention, impl="pallas")), \
+            kernel_choices() as chosen:
+        eng.run()
+    assert "paged_decode" in chosen and "paged_decode_xla" not in chosen
+    fwd = jax.jit(lambda p, t: lfm2.forward(p, t, cfg))
+    for prompt, req in zip(prompts, reqs):
+        logits = fwd(params, jnp.asarray([prompt + req.tokens]))[0]
+        assert req.tokens == jnp.argmax(logits[len(prompt) - 1:-1],
+                                        -1).tolist()
+    s = eng.stats()
+    assert s["kv_chunks_total"] > 0
+    assert 0 <= s["kv_chunks_whole_total"] <= s["kv_chunks_total"]
+
+
 # -- compiled for the chip, without one ---------------------------------
 
 
@@ -420,9 +507,11 @@ def test_decode_step_compiles_for_the_chip_at_published_widths(one_chip):
     """The cut's decode step at the benchmark's widths (16 layers, all
     32 experts, 64 slots of 2,048): the chip's compiler takes it; each
     expert layer's few-rows sum is a custom call (no while), the four
-    attention layers take the XLA path at head 64 (the paged kernel
-    wants a head of 128 lanes), the pool and the tails go out where
-    they came in, and no weight is copied."""
+    attention layers take the paged kernel at head 64 over the
+    lane-dense pool (8 x 64 lanes a token), which is neither relaid
+    nor gathered (the XLA path relaid it six times a step and gathered
+    every slot's strip), the pool and the tails go out where they came
+    in, and no weight is copied."""
     from unittest import mock
 
     from jax.experimental.compilation_cache import compilation_cache
@@ -464,9 +553,11 @@ def test_decode_step_compiles_for_the_chip_at_published_widths(one_chip):
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
     text = compiled.as_text()
-    assert sorted(chosen) == ["held_experts"] * 14 + ["paged_decode_xla"] * 4
-    assert text.count('custom_call_target="tpu_custom_call"') == 14
+    assert cache.kv.k.shape == (4, 2 + slots * maxb, bs, 8 * 64)
+    assert sorted(chosen) == ["held_experts"] * 14 + ["paged_decode"] * 4
+    assert text.count('custom_call_target="tpu_custom_call"') == 18
     assert "ragged-dot" not in text and " while(" not in text
+    assert _pool_copies(text) == []
     matrices = {tuple(a.shape) for a in jax.tree.leaves(params)
                 if a.ndim == 2 and min(a.shape) > 64}
     matrices |= {tuple(a.shape[1:]) for a in jax.tree.leaves(params)
@@ -475,3 +566,62 @@ def test_decode_step_compiles_for_the_chip_at_published_widths(one_chip):
     memory = compiled.memory_analysis()
     # the pool (1.07 GB) and the tails go out where they came in
     assert memory.alias_size_in_bytes > 1.08e9
+    # no strip of 2,048 positions a slot (the XLA path's 2.7 GB): what
+    # is left is the weights' staging
+    assert memory.temp_size_in_bytes < 3e8
+
+
+def _pool_copies(text):
+    """The top-level copies, relays and transposes of the cut's whole
+    pool, 5-D or lane-dense, in a compiled program's text."""
+    return re.findall(r"= bf16\[4,8194,16,(?:8,64|512)\]\S* "
+                      r"(?:copy|copy-start|transpose)\(", text)
+
+
+@pytest.mark.parametrize("program", ["prefill", "install"])
+def test_admission_programs_leave_the_lane_dense_pool_where_it_lies(
+        one_chip, program):
+    """An admission's two programs at the benchmark's widths, compiled
+    for the chip: the prefill gathers its prompt's strip from the
+    lane-dense pool and the install writes the prompt's blocks into it
+    in place; neither relays the whole pool first (a 5-D pool at head
+    64, kept with the block index minor, was relaid twice a side in
+    each)."""
+    from unittest import mock
+
+    from kubeflow_rm_tpu.ops import expert_ffn
+    from kubeflow_rm_tpu.ops import paged_attention as pa
+    from test_paged_attention import _compiled_outside_the_cache
+
+    cfg = Config(layer_types=Config().layer_types[:16],
+                 dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    slots, slot_len, bs, bucket = 64, 2048, 16, 128
+    maxb = slot_len // bs
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def scalar(shape=(), dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = shaped(jax.eval_shape(
+        lambda: lfm2.init_params(cfg, jax.random.key(0))))
+    cache = shaped(jax.eval_shape(lambda: paging.init_paged_cache(
+        cfg, slots, slot_len, 2 + slots * maxb, bs)))
+    prefill = (params, cfg, cache, scalar((maxb,)), scalar(),
+               scalar((1, bucket)), scalar())
+    on_chip = lambda *a, **k: ("tpu", 1)  # noqa: E731
+    with mock.patch.object(pa, "computation_devices", on_chip), \
+            mock.patch.object(expert_ffn, "computation_devices", on_chip):
+        if program == "prefill":
+            lowered = paging.paged_prefill.lower(*prefill)
+        else:
+            _, tk, tv, tpos, state = jax.eval_shape(paging.paged_prefill,
+                                                    *prefill)
+            lowered = paging.paged_install.lower(
+                cache, *shaped((tk, tv, tpos)), scalar(), scalar((maxb,)),
+                scalar((maxb,)), scalar(), shaped(state))
+    text = _compiled_outside_the_cache(lowered).as_text()
+    assert "bf16[4,8194,16,512]" in text
+    assert _pool_copies(text) == []

@@ -206,6 +206,99 @@ def test_wide_head_kernel_matches_the_block_table_path(scene):
     np.testing.assert_allclose(kernel, xla, atol=1e-5, rtol=0)
 
 
+#: LFM2's ratios at its block size: (H, KVH, hd, BS); a slot's strip
+#: four blocks
+DENSE_H, DENSE_KVH, DENSE_HD, DENSE_BS, DENSE_MAXB = 32, 8, 64, 16, 4
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_lane_dense_kernel_matches_the_xla_path_at_block_16(dtype):
+    """LFM2's head 64 with eight kv heads at a block of 16: the pool
+    keeps a token's heads as one row of 512 lanes, and the kernel,
+    interpreted, says what the XLA path says for a slot with nothing
+    cached, one at a block boundary, a full strip, a shared first
+    block, scattered ids, an inactive slot and an empty one; blocks
+    past every slot's cached tokens, poisoned with NaN, are never
+    read."""
+    H, kvh, hd, bs, maxb = (DENSE_H, DENSE_KVH, DENSE_HD, DENSE_BS,
+                            DENSE_MAXB)
+    assert pa.lane_dense(kvh, hd)
+    rng = np.random.default_rng(64)
+    nb = 32
+    pool = rng.normal(size=(2, L, nb, bs, kvh * hd)).astype(np.float32)
+    pool[:, :, 0] = 0                                   # NULL
+    # (blocks, cached tokens, active) a slot
+    slots = [([2], 0, True), ([3, 4], bs, True),
+             ([5, 6, 7, 8], maxb * bs - 1, True),
+             ([5, 9, 10], 2 * bs + 3, True),            # 5 is shared
+             ([30, 12, 26, 14], 3 * bs + 1, True),      # scattered
+             ([15, 16], 20, False), ([17], 0, False)]
+    B = len(slots)
+    tables = np.zeros((B, maxb), np.int32)
+    for b, (blocks, _, _) in enumerate(slots):
+        tables[b, :len(blocks)] = blocks
+    lengths = np.array([n for _, n, _ in slots], np.int32)
+    active = np.array([a for _, _, a in slots])
+    q = jnp.asarray(rng.normal(size=(B, H, hd)), dtype)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(B, kvh, hd)), dtype)
+                    for _ in range(2))
+    col = np.arange(maxb * bs)[None, :]
+    kw = dict(positions_q=jnp.asarray(np.where(active, lengths, U)),
+              kv_positions=jnp.asarray(np.where(col <= lengths[:, None],
+                                                col, U)))
+
+    def attend(p, impl):
+        return np.asarray(_attend(
+            q, k_new, v_new, jnp.asarray(p[0], dtype),
+            jnp.asarray(p[1], dtype), jnp.int32(LAYER),
+            jnp.asarray(tables), jnp.asarray(lengths), jnp.asarray(active),
+            impl=impl, **kw), np.float32)
+
+    kernel, xla = attend(pool, "pallas"), attend(pool, "xla")
+    assert kernel.shape == (B, H, hd)
+    assert np.isfinite(kernel[active]).all()
+    if dtype == "float32":
+        np.testing.assert_allclose(kernel[active], xla[active], atol=1e-5,
+                                   rtol=0)
+    else:
+        err = np.abs(kernel[active] - xla[active]).max()
+        assert err <= 5e-2 * np.abs(xla[active]).max()
+    live = {int(x) for b, (_, n, a) in enumerate(slots) if a
+            for x in tables[b, :-(-n // bs)]}
+    dead = sorted(set(range(1, nb)) - live)
+    pool[:, :, dead] = np.nan
+    np.testing.assert_array_equal(kernel, attend(pool, "pallas"))
+
+
+@pytest.mark.parametrize("H,kvh,hd,bs,lanes,takes", [
+    (32, 8, 64, 16, True, True),       # LFM2: 512 lanes a token
+    (4, 2, 64, 16, True, False),       # 128 lanes, but 4 query heads
+    (32, 2, 64, 16, True, True),       # 128 lanes a token
+    (32, 8, 64, 8, True, False),       # a block of 8 rows: half a tile
+    (32, 3, 64, 16, False, False),     # 192 lanes: 64 left over
+    (32, 1, 64, 16, False, False),     # 64 lanes: 64 left over
+    (32, 8, 128, 16, False, True),     # Mistral
+    (16, 2, 256, 16, False, True),     # Qwen3-Next
+], ids=["lfm2", "four-heads", "two-kv-heads", "block-8", "lanes-left-192",
+        "lanes-left-64", "mistral", "wide-256"])
+def test_kernel_eligible_takes_a_lane_dense_pool_and_no_lanes_left_over(
+        H, kvh, hd, bs, lanes, takes):
+    """The pool is lane-dense where a head narrower than the lanes
+    leaves none over across a token's heads, and the kernel takes
+    such a pool where its blocks and query heads tile; a head whose
+    ``KVH * hd`` leaves lanes over keeps the 5-D pool and the XLA
+    path, and no pool of it passed flat is taken either."""
+    bf16 = jnp.bfloat16
+    assert pa.lane_dense(kvh, hd) == lanes
+    token = (kvh * hd,) if lanes else (kvh, hd)
+    q = jax.ShapeDtypeStruct((8, H, hd), bf16)
+    pool = jax.ShapeDtypeStruct((2, 64, bs, *token), bf16)
+    assert pa.kernel_eligible(q, pool) == takes
+    if not lanes:
+        flat = jax.ShapeDtypeStruct((2, 64, bs, kvh * hd), bf16)
+        assert not pa.kernel_eligible(q, flat)
+
+
 # -- a whole chunk in one copy -------------------------------------------
 
 #: chunks cut small: four blocks of four tokens (the test sets
@@ -247,9 +340,11 @@ CHUNK_LAYOUTS = {
         (_run(5, 3), 11, True), (_run(8, 4), 12, True)], 0),
 }
 
-#: (H, KVH, hd): Mistral's GQA, Trinity's, Qwen3-Next's wide head
+#: (H, KVH, hd): Mistral's GQA, Trinity's, Qwen3-Next's wide head, and
+#: LFM2's head 64 and a tiny config's, whose pools are lane-dense
 CHUNK_SHAPES = {"mistral-8kv": (32, 8, 128), "trinity-4kv": (32, 4, 128),
-                "wide-256": (16, 2, 256)}
+                "wide-256": (16, 2, 256), "lfm2-8kv-64": (32, 8, 64),
+                "tiny-2kv-64": (4, 2, 64)}
 
 _attend_chunks = jax.jit(pa.paged_decode_attention, static_argnames=("impl",))
 
@@ -271,7 +366,8 @@ def test_whole_chunk_copies_read_what_the_block_copies_read(
     path's within float32 rounding. No block past a slot's cached
     tokens is copied, whole chunk or not: poisoned with NaN, those
     blocks leave the output as it was. ``whole_chunks`` counts the
-    chunks the kernel takes whole."""
+    chunks the kernel takes whole. A head of 64 goes in as the pool
+    keeps it, a token's heads one row of lanes (``lane_dense``)."""
     monkeypatch.setattr(pa, "CHUNK_TOKENS", CBS * CCB)
     assert pa.chunk_blocks(CBS, CMAXB) == CCB
     H, kvh, hd = CHUNK_SHAPES[shape]
@@ -307,9 +403,14 @@ def test_whole_chunk_copies_read_what_the_block_copies_read(
     col = np.arange(CMAXB * CBS)[None, :]
     kv_positions = np.where(col <= lengths[:, None], col, U)
 
+    def stored(x):
+        if pa.lane_dense(kvh, hd):
+            x = x.reshape(*x.shape[:3], kvh * hd)
+        return jnp.asarray(x)
+
     def attend(p, t, impl):
         return np.asarray(_attend_chunks(
-            q, k_new, v_new, jnp.asarray(p[0]), jnp.asarray(p[1]),
+            q, k_new, v_new, stored(p[0]), stored(p[1]),
             jnp.int32(LAYER), jnp.asarray(t), jnp.asarray(lengths),
             jnp.asarray(active), positions_q=jnp.asarray(positions_q),
             kv_positions=jnp.asarray(kv_positions), impl=impl))
@@ -410,6 +511,34 @@ def test_kernel_compiles_for_the_chip_at_real_widths(one_chip, H, kvh, B,
         sds((layers, nb, bs, kvh, hd), bf16), sds((), jnp.int32),
         sds((B, maxb), jnp.int32), sds((B,), jnp.int32)))
     assert "tpu_custom_call" in compiled.as_text()
+    one_block = bs * kvh * hd * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * one_block
+
+
+def test_lane_dense_kernel_compiles_for_the_chip_at_lfm2_widths(one_chip):
+    """Mosaic takes the kernel over LFM2's lane-dense pool at the
+    benchmark's widths (64 slots of 2,048, four layers, a block
+    ``(16, 512)``), whole-chunk copies and block copies both, and the
+    pool goes in as it lies: no copy of it, no temporary beside it."""
+    B, H, kvh, hd, bs, layers, nb, maxb = 64, 32, 8, 64, 16, 4, 8194, 128
+    bf16 = jnp.bfloat16
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def run(q, kn, vn, pk, pv, layer, tables, lengths):
+        return pa._paged_decode_kernel(q, kn, vn, pk, pv, layer, tables,
+                                       lengths, interpret=False)
+
+    pool = sds((layers, nb, bs, kvh * hd), bf16)
+    assert pa.kernel_eligible(sds((B, H, hd), bf16), pool)
+    compiled = _compiled_outside_the_cache(jax.jit(run).lower(
+        sds((B, H, hd), bf16), sds((B, kvh, hd), bf16),
+        sds((B, kvh, hd), bf16), pool, pool, sds((), jnp.int32),
+        sds((B, maxb), jnp.int32), sds((B,), jnp.int32)))
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.findall(r"= bf16\[4,8194,16,512\]\S* copy\(", text)
     one_block = bs * kvh * hd * 2
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * one_block
 

@@ -171,6 +171,81 @@ def test_cow_fork_on_first_write_preserves_source(model):
     assert gather_slot_strip(eng.cache, 0)[2].shape == (32,)
 
 
+def test_a_lane_dense_pool_serves_prefix_hits_forks_and_chains():
+    """A dense config at head 64 with two kv heads keeps its pool
+    lane-dense (a token's heads one row of 128 lanes): a prefix hit
+    with its copy-on-write fork still decodes bit-identically to solo
+    fused, the slot's strip reads back as (L, S, KVH, hd), and a chain
+    exported from one engine (its blocks as heads) and installed in
+    another decodes the same."""
+    from kubeflow_rm_tpu.models.paging import gather_slot_strip
+
+    cfg = LlamaConfig.tiny(dim=256, n_heads=4, n_kv_heads=2)
+    params = init_params(cfg, jax.random.key(4))
+    eng = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=32,
+                                   block_size=4)
+    assert eng.cache.k.shape == (2, eng.pool.num_blocks, 4, 128)
+    prompt = [5, 9, 2, 7, 1, 1, 3]
+    reqs = [eng.submit(list(prompt), max_new_tokens=b) for b in (6, 5)]
+    eng.run()
+    for r, b in zip(reqs, (6, 5)):
+        assert r.tokens == _solo(params, cfg, prompt, b)
+    st = eng.stats()
+    assert st["prefix_hit_tokens"] > 0 and st["cow_forks"] >= 1
+    k, v, pos = gather_slot_strip(eng.cache, 0)
+    assert k.shape == v.shape == (2, 32, 2, 64) and pos.shape == (32,)
+
+    other = ContinuousBatchingEngine(params, cfg, slots=2, slot_len=32,
+                                     block_size=4)
+    chain = other.prefill_chain(prompt + [4, 4])
+    assert chain["chunks_k"].shape == (2, 3, 4, 2, 64)
+    req = eng.install_chain(chain, max_new_tokens=5)
+    eng.run()
+    assert req.tokens == _solo(params, cfg, prompt + [4, 4], 5)
+
+
+@pytest.mark.parametrize("config,lanes", [
+    ("mistral-7b-serve-l12", False), ("nemotron3-super-serve-l11-e128", False),
+    ("qwen3-next-serve-l4-e256", False), ("trinity-mini-serve-l16-e16", False),
+    ("lfm2-8b-a1b-serve-l16", True)])
+def test_the_pool_is_lane_dense_only_where_a_head_leaves_lanes(config,
+                                                               lanes):
+    """The benchmark's serving configurations as built, their pools by
+    shape alone: heads of 128 and 256 keep the 5-D pool (and the
+    programs they had), LFM2's 8 heads of 64 the lane-dense one."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from kubeflow_rm_tpu.models.paging import init_paged_cache
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from perf.kinds import (serve, serve_afmoe, serve_hybrid, serve_lfm2,
+                            serve_qwen3_next)
+
+    build = {"mistral": serve.llama_config,
+             "nemotron3": serve_hybrid.nemotron_config,
+             "qwen3": serve_qwen3_next.qwen3_next_config,
+             "trinity": serve_afmoe.afmoe_config,
+             "lfm2": serve_lfm2.lfm2_config}[config.split("-")[0]]
+    published = json.loads((root / "perf" / "configs"
+                            / f"{config}.json").read_text())
+    cfg = build(published)
+    sv = published["serving"]
+    nb = sv.get("num_blocks",
+                RESERVED_BLOCKS + sv["slots"] * sv["slot_len"]
+                // sv["block_size"])
+    cache = jax.eval_shape(lambda: init_paged_cache(
+        cfg, sv["slots"], sv["slot_len"], nb, sv["block_size"]))
+    kv = getattr(cache, "kv", cache)
+    KVH, hd = cfg.n_kv_heads, cfg.head_dim
+    token = (KVH * hd,) if lanes else (KVH, hd)
+    assert kv.k.shape[1:] == (nb, sv["block_size"], *token)
+    assert kv.v.shape == kv.k.shape
+    assert kv.block_shape == (sv["block_size"], KVH, hd)
+
+
 def test_recycled_slot_never_reads_stale_prefix(model):
     """Evict a registered chain by pressure, then replay the original
     prompt: the chain must MISS (re-prefill) and the output must still
